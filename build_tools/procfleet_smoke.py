@@ -254,8 +254,7 @@ def elastic_child(pid, port):
     initialize_cluster(
         coordinator_address=f"localhost:{port}", num_processes=2,
         process_id=pid,
-        service_max_missing_heartbeats=1000,
-        client_max_missing_heartbeats=1000,
+        heartbeat_timeout_seconds=10_000,
     )
     print(f"CHILD {pid}: cluster up, {len(jax.devices())} devices",
           flush=True)

@@ -23,8 +23,8 @@ paths (models/host_linear.py — round-5; this row was 12.1s vs 1.3s
 when the local path still paid XLA-CPU prices), and forests run the
 host C engine (models/native_forest.py, hist_mode='native' via
 calibration), BEATING sklearn's Cython engine on the same cores. The
-accelerator is where the batched XLA path wins (57-82 fits/sec TPU
-runs, NOTES.md):
+accelerator path is not measured on the current code (see
+chip_smoke.py):
     -- workload: (20000, 54) features, 7 classes
     -- DistGridSearchCV LR (20 fits): 1.9s, CV f1 0.7486
     -- DistRandomForest (100 trees): 7.0s, train f1 0.7300
@@ -47,10 +47,6 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 
-# wedged-accelerator guard: use the TPU when it answers, else pin CPU
-from skdist_tpu.utils.tpu_probe import probe_platform_or_cpu
-
-probe_platform_or_cpu()
 import time
 
 import numpy as np

@@ -9,12 +9,12 @@ Here the same fit count rides the task axis of ONE compiled program:
 digits set. The "cluster" is whatever mesh the backend sees — the
 parallel-efficiency ratio is (total serial fit time) / wall.
 
-The full 150-candidate grid is the accelerator workload; on the CPU
-fallback the grid shrinks to 30 candidates (marked in the output) so
-the example stays interactive.
+The full 150-candidate grid is the accelerator workload; on a CPU the
+grid shrinks to 30 candidates (marked in the output) so the example
+stays interactive.
 
-Sample output (CPU fallback, 30-candidate grid):
-    Train time: 21.04s for 150 fits (7.1 fits/sec) [cpu-fallback grid]
+Sample output (CPU, 30-candidate grid):
+    Train time: 21.04s for 150 fits (7.1 fits/sec) [cpu grid]
     Best score: 0.9277
     -- top CV results --
         param_C  mean_test_score
@@ -32,10 +32,6 @@ import time
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 
-# wedged-accelerator guard: use the TPU when it answers, else pin CPU
-from skdist_tpu.utils.tpu_probe import probe_platform_or_cpu
-
-_platform = probe_platform_or_cpu()
 import numpy as np
 import pandas as pd
 from sklearn.datasets import load_digits
@@ -48,9 +44,11 @@ def main():
     X, y = load_digits(return_X_y=True)
     X = (X / 16.0).astype(np.float32)
 
-    on_accel = _platform not in ("cpu", "cpu-fallback")
+    import jax
+
+    on_accel = jax.default_backend() != "cpu"
     n_cand = 150 if on_accel else 30
-    tag = "" if on_accel else " [cpu-fallback grid]"
+    tag = "" if on_accel else " [cpu grid]"
     grid = {"C": list(np.logspace(-4, 2, n_cand))}
     n_fits = n_cand * 5
     t0 = time.time()

@@ -23,10 +23,6 @@ import sys
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", ".."))
 
-# wedged-accelerator guard: use the TPU when it answers, else pin CPU
-from skdist_tpu.utils.tpu_probe import probe_platform_or_cpu
-
-probe_platform_or_cpu()
 import threading
 import time
 

@@ -36,6 +36,7 @@ from .tree import (
     build_tree_kernel,
     classification_channels,
     feature_importances_from_tree,
+    matmul_lane_cap,
     n_tree_nodes,
     regression_channels,
     resolve_hist_config,
@@ -191,6 +192,8 @@ def _forest_kernel_cached(d, n_bins, channels, max_depth, max_features,
         min_impurity_decrease, extra, classification, bootstrap,
         hist_mode, hist_block,
     )
+    #: the RESOLVED engine this kernel runs (fit sizes its rounds by it)
+    kernel.hist_mode = hist_mode
     return kernel
 
 
@@ -392,6 +395,15 @@ class _BaseForest(BaseEstimator):
                         np.any(np.asarray(sw) != np.rint(sw))
                     ),
                 )
+                if kernel.hist_mode in ("matmul", "matmul_sib"):
+                    # the engine's one-hot right factor grows with the
+                    # round: bound the lanes a device grows at once
+                    # (tree.matmul_lane_cap says why)
+                    round_size = min(
+                        round_size or n_more,
+                        matmul_lane_cap(n, self.max_depth, channels)
+                        * max(1, getattr(backend, "n_task_slots", 1)),
+                    )
                 shared = {
                     "Xb": Xb,  # host-staged: batched_map places (and can
                     "y": np.asarray(y_enc),  # cache) the sharded replicas

@@ -53,6 +53,7 @@ __all__ = [
     "ExtraTreeRegressor",
     "build_tree_kernel",
     "histogram_node_scores",
+    "matmul_lane_cap",
     "newton_channels",
     "pick_level_splits",
     "tree_predict_kernel",
@@ -220,6 +221,28 @@ def resolve_hist_config(n_features, n_bins, hist_mode="auto",
     if hist_block is None:
         hist_block = calib.get("hist_block") or 8
     return hist_mode, int(hist_block)
+
+
+#: largest right factor ``(lanes, n, nl·C)`` the one-hot matmul engines
+#: may materialise in one program, in elements. Measured on the v5e
+#: (jax/jaxlib 0.9.0, libtpu 0.0.34): a round of 53 forest lanes at
+#: 200,000 rows and depth 8 — 4.07e9 elements at the last level, 4.3e9
+#: once the lane axis is padded to the tile, past 2^32 — compiled, ran
+#: without an error and returned histograms that left level 7 almost
+#: unsplit; rounds of 32 to 48 lanes (2.5e9 to 3.7e9) grew exactly the
+#: trees of small rounds. The bound is the conservative side of that
+#: reading: what a signed 32-bit offset can address.
+MATMUL_MAX_OPERAND_ELEMS = 2 ** 31
+
+
+def matmul_lane_cap(n_samples, max_depth, channels):
+    """How many lanes (trees of a round) the ``matmul``/``matmul_sib``
+    engines may grow in one program at this shape: the last level's
+    right factor is ``(lanes, n, 2^(D-1)·C)`` and must stay under
+    :data:`MATMUL_MAX_OPERAND_ELEMS`. At least 1 — a single lane past
+    the bound has no smaller round to fall back to."""
+    per_lane = int(n_samples) * 2 ** max(int(max_depth) - 1, 0) * int(channels)
+    return max(1, MATMUL_MAX_OPERAND_ELEMS // max(per_lane, 1))
 
 
 def build_tree_kernel(n_features, n_bins, channels, max_depth, max_features,
@@ -428,15 +451,11 @@ def build_tree_kernel(n_features, n_bins, channels, max_depth, max_features,
             elif hist_mode == "pallas":
                 # ---- same contraction, Pallas kernel: one-hot factors
                 # built in VMEM, nothing (n, d·B)-sized in HBM
-                from ..ops.pallas_hist import (
-                    level_histogram,
-                    pallas_supported,
-                )
+                from ..ops.pallas_hist import level_histogram
 
                 node_key = jnp.where(at_level, rel, nl).astype(jnp.int32)
                 hist = level_histogram(
-                    Xb, node_key, Ych, nl=nl, n_bins=B,
-                    interpret=not pallas_supported(),
+                    Xb, node_key, Ych, nl=nl, n_bins=B
                 )
             else:
                 # ---- histogram: scan over feature BLOCKS, one scatter

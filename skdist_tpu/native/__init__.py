@@ -11,6 +11,7 @@ package compiles ``fasthash.c`` with the system compiler on first use
 implementation when no compiler is available.
 """
 
+import hashlib
 import os
 import subprocess
 import sysconfig
@@ -19,61 +20,104 @@ import threading
 
 import numpy as np
 
+#: the C extensions of this package and their extra compiler flags
+_EXT_FLAGS = {
+    "fasthash": (),
+    "hist_tree": ("-pthread",),
+    "densify": ("-pthread",),
+}
 _EXTS = {}
+#: name -> {"loaded", "built", "error"}: what :func:`_load_ext` found,
+#: kept because it swallows the exception itself
+_EXT_STATUS = {}
 _LOAD_LOCK = threading.Lock()
 
 
-def _load_ext(name, extra_flags=()):
+def _load_ext(name):
     """Import the compiled module ``_<name>`` (from ``<name>.c``),
     building it on first use.
 
     Any failure anywhere (read-only tree, missing compiler, truncated
     artifact) returns None so callers take the pure-Python path — the
-    fallback contract must survive hostile installs. Builds go to a
-    temp file and are renamed into place (atomic on POSIX) so
-    concurrent processes never load a half-written .so.
+    fallback contract must survive hostile installs; the reason is
+    kept for :func:`ext_status`. Builds go to a temp file and are
+    renamed into place (atomic on POSIX) so concurrent processes never
+    load a half-written .so.
     """
     with _LOAD_LOCK:
         if name in _EXTS:
             return _EXTS[name]
+        status = {"loaded": False, "built": False, "error": None}
         try:
-            mod = _load_ext_inner(name, extra_flags)
-        except Exception:
+            mod = _load_ext_inner(name, status)
+            status["loaded"] = True
+        except Exception as exc:
             mod = None
+            detail = getattr(exc, "stderr", None)
+            status["error"] = f"{type(exc).__name__}: {exc}" + (
+                f" | {detail.decode(errors='replace')[-400:]}"
+                if detail else ""
+            )
         _EXTS[name] = mod
+        _EXT_STATUS[name] = status
         return mod
 
 
-def _load_ext_inner(name, extra_flags):
+def _load_ext_inner(name, status):
     import importlib.util
 
-    build_dir = os.path.join(os.path.dirname(__file__), "_build")
+    here = os.path.dirname(__file__)
+    build_dir = os.path.join(here, "_build")
     os.makedirs(build_dir, exist_ok=True)
     suffix = sysconfig.get_config_var("EXT_SUFFIX") or ".so"
-    so_path = os.path.join(build_dir, f"_{name}{suffix}")
-    src = os.path.join(os.path.dirname(__file__), f"{name}.c")
-    if not os.path.exists(so_path) or (
-        os.path.exists(src)
-        and os.path.getmtime(src) > os.path.getmtime(so_path)
-    ):
-        cc = os.environ.get("CC", "cc")
+    src = os.path.join(here, f"{name}.c")
+    cc = os.environ.get("CC", "cc")
+    flags = ["-O3", "-shared", "-fPIC", *_EXT_FLAGS[name]]
+    # the binary is keyed on a digest of what it is built FROM, so a
+    # _build/ copied along with an edited source (mtimes do not survive
+    # a copy) can never serve an old binary
+    with open(src, "rb") as f:
+        digest = hashlib.sha256(
+            f.read() + repr((cc, flags, suffix)).encode()
+        ).hexdigest()[:16]
+    so_path = os.path.join(build_dir, f"_{name}-{digest}{suffix}")
+    if not os.path.exists(so_path):
         include = sysconfig.get_paths()["include"]
         fd, tmp_path = tempfile.mkstemp(suffix=suffix, dir=build_dir)
         os.close(fd)
         try:
             subprocess.run(
-                [cc, "-O3", "-shared", "-fPIC", *extra_flags,
-                 f"-I{include}", src, "-o", tmp_path],
+                [cc, *flags, f"-I{include}", src, "-o", tmp_path],
                 check=True, capture_output=True, timeout=120,
             )
             os.replace(tmp_path, so_path)
         finally:
             if os.path.exists(tmp_path):
                 os.unlink(tmp_path)
+        status["built"] = True
+        for stale in os.listdir(build_dir):
+            if (stale.startswith((f"_{name}-", f"_{name}."))
+                    and stale != os.path.basename(so_path)):
+                try:
+                    os.unlink(os.path.join(build_dir, stale))
+                except OSError:
+                    pass
     spec = importlib.util.spec_from_file_location(f"_{name}", so_path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
     return mod
+
+
+def ext_status():
+    """Load (building where needed) every C extension of the package
+    and say what happened to each: ``{name: {"loaded", "built",
+    "error"}}`` — ``built`` is whether THIS process compiled it,
+    ``error`` why it did not load (a missing compiler, say). Library
+    callers keep their pure-Python fallbacks; an entry point that wants
+    to show what it ran on prints this."""
+    for name in _EXT_FLAGS:
+        _load_ext(name)
+    return {name: dict(_EXT_STATUS[name]) for name in _EXT_FLAGS}
 
 
 def _load_native():
@@ -216,7 +260,7 @@ def native_available():
 # ---------------------------------------------------------------------------
 
 def hist_tree_available():
-    return _load_ext("hist_tree", ("-pthread",)) is not None
+    return _load_ext("hist_tree") is not None
 
 
 def hist_level(hist, XbT, node_rel, W, cls=None, yv=None, act=None,
@@ -235,7 +279,7 @@ def hist_level(hist, XbT, node_rel, W, cls=None, yv=None, act=None,
     """
     Tb, d, nl, B, C = hist.shape
     n = XbT.shape[1]
-    mod = None if force_python else _load_ext("hist_tree", ("-pthread",))
+    mod = None if force_python else _load_ext("hist_tree")
     if mod is not None:
         if n_threads is None:
             n_threads = min(16, os.cpu_count() or 1)
@@ -282,7 +326,7 @@ def forest_walk_native(Xb, trees, max_depth, mode="predict",
     returns the (n, K) mean leaf vector; ``'apply'`` the (n, T) final
     node ids — matching ``models/forest.py::_forest_walker`` exactly
     (a node stays put once a non-split node is reached)."""
-    mod = _load_ext("hist_tree", ("-pthread",))
+    mod = _load_ext("hist_tree")
     if mod is None:
         return None
     feat = np.ascontiguousarray(trees["feat"], np.int32)
@@ -317,7 +361,7 @@ def best_splits_native(hist, fmask, urand, K, classification,
     kernel, or None when the kernel is unavailable / the channel count
     exceeds its accumulator cap (callers then run the numpy scoring
     path). Returns ``(gain, f, t, cnt_l, cnt_r)`` each (Tb, nl)."""
-    mod = _load_ext("hist_tree", ("-pthread",))
+    mod = _load_ext("hist_tree")
     Tb, d, nl, B, C = hist.shape
     if mod is None or C > 256 or K > 256:
         return None
@@ -354,7 +398,7 @@ def csr_to_dense_f32(X, force_python=False, n_threads=None):
     """
     csr = X.tocsr()
     n_rows, n_cols = csr.shape
-    mod = None if force_python else _load_ext("densify", ("-pthread",))
+    mod = None if force_python else _load_ext("densify")
     if mod is None or n_rows == 0 or n_cols == 0:
         return np.ascontiguousarray(csr.toarray(), dtype=np.float32)
     data = np.ascontiguousarray(csr.data, dtype=np.float32)

@@ -21,9 +21,9 @@ traffic is the raw inputs re-read ``nl·C/LB`` times. FLOPs are
 identical to 'matmul' (d·B·n·nl·C — no padding waste: the node axis
 rides the MXU lane dimension fused with channels).
 
-``interpret=True`` (automatic off-TPU) runs the kernel through the
-Pallas interpreter, so correctness is testable on the CPU mesh; the
-compiled path is selected on real TPU backends.
+``interpret=None`` defers to :func:`skdist_tpu.ops.pallas_interpret`:
+the Pallas interpreter off-TPU, so correctness is testable on the CPU
+mesh, and the compiled path whenever the default backend is a TPU.
 """
 
 import functools
@@ -33,15 +33,14 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from . import pallas_interpret
+
 
 def _ceil_to(x, m):
     return -(-x // m) * m
 
 
-@functools.partial(
-    jax.jit, static_argnames=("nl", "n_bins", "interpret", "S", "LB")
-)
-def level_histogram(Xb, node_key, Ych, *, nl, n_bins, interpret=False,
+def level_histogram(Xb, node_key, Ych, *, nl, n_bins, interpret=None,
                     S=512, LB=128):
     """Per-level histogram via a Pallas kernel.
 
@@ -55,6 +54,16 @@ def level_histogram(Xb, node_key, Ych, *, nl, n_bins, interpret=False,
 
     Returns (d, nl, B, C) f32.
     """
+    if interpret is None:
+        interpret = pallas_interpret()
+    return _level_histogram(Xb, node_key, Ych, nl=nl, n_bins=n_bins,
+                            interpret=interpret, S=S, LB=LB)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("nl", "n_bins", "interpret", "S", "LB")
+)
+def _level_histogram(Xb, node_key, Ych, *, nl, n_bins, interpret, S, LB):
     from jax.experimental import pallas as pl
 
     n, d = Xb.shape
@@ -136,11 +145,3 @@ def level_histogram(Xb, node_key, Ych, *, nl, n_bins, interpret=False,
 
     hist_bnc = out[:, :, :L].reshape(d, B, nl, C)
     return hist_bnc.transpose(0, 2, 1, 3)  # (d, nl, B, C)
-
-
-def pallas_supported():
-    """Whether the compiled Pallas path targets the current backend.
-
-    Off-TPU the kernel still runs (interpreter), just slowly — callers
-    use this to pick interpret mode."""
-    return jax.default_backend() == "tpu"

@@ -27,6 +27,13 @@ then contracts it:
     rmatvec: out[cols]  += Mᵀ @ r[chunk]              (accumulate over
                                                        sample chunks)
 
+The kernels hold the packed pair and the rebuilt block TRANSPOSED
+(``(m, S)`` and ``Mᵀ (DB, S)``): step j of the rebuild then reads row j
+of the pair through the ref — a dynamic index on the sublane axis,
+which the TPU lowering accepts, where a dynamic slice of a loaded value
+or a dynamic index on the lane axis is refused — and broadcasts it down
+the sublanes.
+
 Nothing of size (n, d) ever exists in HBM — HBM traffic is the packed
 pair re-read once per column block (matvec) or once total (rmatvec)
 plus the operand/result — and both contractions accumulate in f32 on
@@ -34,9 +41,9 @@ the MXU. Padding entries (``idx=0, val=0``) contribute exactly 0.0, so
 the kernels are EXACT on the packed representation, matching the XLA
 kernels' semantics bit-for-bit on integer data.
 
-``interpret=True`` (automatic off-TPU) runs the kernels through the
-Pallas interpreter so the CPU mesh tests them; the compiled path is
-selected on real TPU backends. Autodiff: ``pallas_call`` has no VJP
+``interpret=None`` defers to :func:`skdist_tpu.ops.pallas_interpret`:
+the Pallas interpreter off-TPU so the CPU mesh tests the kernels, the
+compiled path whenever the default backend is a TPU. Autodiff: ``pallas_call`` has no VJP
 rule, so the consumer (``sparse.LinearOperator`` in ``mode='pallas'``)
 wraps :func:`packed_matvec` in a ``jax.custom_vjp`` whose backward IS
 :func:`packed_rmatvec` (the true transpose) — see
@@ -49,12 +56,13 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from . import pallas_interpret
+
 __all__ = [
     "packed_matvec",
     "packed_rmatvec",
     "packed_weighted_gram",
     "matvec_with_vjp",
-    "pallas_sparse_supported",
 ]
 
 #: default sample-chunk rows (multiple of the f32 sublane tile)
@@ -67,30 +75,27 @@ def _ceil_to(x, m):
     return -(-x // m) * m
 
 
-def pallas_sparse_supported():
-    """Whether the compiled Pallas path targets the current backend.
-    Off-TPU the kernels still run (interpreter), just slowly — callers
-    use this to pick interpret mode."""
-    return jax.default_backend() == "tpu"
-
-
 def _resolve_interpret(interpret):
-    return not pallas_sparse_supported() if interpret is None else interpret
+    return pallas_interpret() if interpret is None else interpret
 
 
-def _dense_block(idx, val, col0, DB):
-    """Rebuild the dense (S, DB) sub-block of X in VMEM: an m-step
-    accumulation of one-hot-masked packed values. The loop carries only
-    the (S, DB) accumulator — the (S, m, DB) broadcast never exists."""
-    S, m = idx.shape
-    cols = col0 + lax.broadcasted_iota(jnp.int32, (S, DB), 1)
+def _dense_block_t(idx_ref, val_ref, col0, DB):
+    """Rebuild the TRANSPOSED dense (DB, S) sub-block of X in VMEM from
+    the transposed packed refs ``(m, S)``: an m-step accumulation of
+    one-hot-masked packed values, row j read through the refs. The loop
+    carries only the (DB, S) accumulator — the (m, DB, S) broadcast
+    never exists."""
+    from jax.experimental import pallas as pl
 
-    def body(j, M):
-        ij = lax.dynamic_slice_in_dim(idx, j, 1, axis=1)  # (S, 1)
-        vj = lax.dynamic_slice_in_dim(val, j, 1, axis=1)
-        return M + jnp.where(ij == cols, vj, 0.0)
+    m, S = idx_ref.shape
+    cols = col0 + lax.broadcasted_iota(jnp.int32, (DB, S), 0)
 
-    return lax.fori_loop(0, m, body, jnp.zeros((S, DB), val.dtype))
+    def body(j, MT):
+        ij = idx_ref[pl.ds(j, 1), :]  # (1, S)
+        vj = val_ref[pl.ds(j, 1), :]
+        return MT + jnp.where(ij == cols, vj, 0.0)
+
+    return lax.fori_loop(0, m, body, jnp.zeros((DB, S), val_ref.dtype))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "S", "DB"))
@@ -110,9 +115,9 @@ def _matvec_2d(idx, val, W, interpret, S, DB):
 
     def kernel(idx_ref, val_ref, w_ref, out_ref):
         di = pl.program_id(1)
-        M = _dense_block(idx_ref[...], val_ref[...], di * DB, DB)
+        MT = _dense_block_t(idx_ref, val_ref, di * DB, DB)
         part = lax.dot_general(
-            M, w_ref[...], (((1,), (0,)), ((), ())),
+            MT, w_ref[...], (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # (S, k)
 
@@ -131,14 +136,14 @@ def _matvec_2d(idx, val, W, interpret, S, DB):
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((S, m), lambda i, j: (i, 0)),
-            pl.BlockSpec((S, m), lambda i, j: (i, 0)),
+            pl.BlockSpec((m, S), lambda i, j: (0, i)),
+            pl.BlockSpec((m, S), lambda i, j: (0, i)),
             pl.BlockSpec((DB, k), lambda i, j: (j, 0)),
         ],
         out_specs=pl.BlockSpec((S, k), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((n_pad, k), jnp.float32),
         interpret=interpret,
-    )(idx, val, W)
+    )(idx.T, val.T, W)
     return out[:n]
 
 
@@ -160,9 +165,9 @@ def _rmatvec_2d(idx, val, r, n_cols, interpret, S, DB):
     def kernel(idx_ref, val_ref, r_ref, out_ref):
         di = pl.program_id(0)
         si = pl.program_id(1)
-        M = _dense_block(idx_ref[...], val_ref[...], di * DB, DB)
+        MT = _dense_block_t(idx_ref, val_ref, di * DB, DB)
         part = lax.dot_general(
-            M, r_ref[...], (((0,), (0,)), ((), ())),
+            MT, r_ref[...], (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # (DB, k)
 
@@ -181,14 +186,14 @@ def _rmatvec_2d(idx, val, r, n_cols, interpret, S, DB):
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((S, m), lambda d, s: (s, 0)),
-            pl.BlockSpec((S, m), lambda d, s: (s, 0)),
+            pl.BlockSpec((m, S), lambda d, s: (0, s)),
+            pl.BlockSpec((m, S), lambda d, s: (0, s)),
             pl.BlockSpec((S, k), lambda d, s: (s, 0)),
         ],
         out_specs=pl.BlockSpec((DB, k), lambda d, s: (d, 0)),
         out_shape=jax.ShapeDtypeStruct((p_pad, k), jnp.float32),
         interpret=interpret,
-    )(idx, val, r)
+    )(idx.T, val.T, r)
     return out[:n_cols]
 
 
@@ -197,7 +202,7 @@ def packed_matvec(idx, val, W, *, interpret=None, S=DEFAULT_S,
     """``X @ W`` on the packed pair via the Pallas rebuild-and-matmul
     kernel. Same contract as ``sparse.packed_matvec``: ``W`` is
     ``(p,)`` or ``(p, k)``; returns ``(n,)`` / ``(n, k)`` f32.
-    ``interpret=None`` auto-selects the interpreter off-TPU."""
+    ``interpret=None`` defers to ``ops.pallas_interpret``."""
     interpret = _resolve_interpret(interpret)
     W = jnp.asarray(W)
     if W.ndim == 1:
@@ -233,16 +238,16 @@ def _gram_2d(idx, val, sw, n_cols, interpret, S, DB):
         idx = jnp.pad(idx, ((0, n_pad - n), (0, 0)))
         val = jnp.pad(val, ((0, n_pad - n), (0, 0)))
         sw = jnp.pad(sw, ((0, n_pad - n),))
-    sw2 = sw[:, None]  # (n, 1): 2-D for TPU block tiling
+    sw2 = sw[None, :]  # (1, n): a row, to scale Mᵀ along its lanes
 
     def kernel(idx_ref, val_ref, sw_ref, out_ref):
         a = pl.program_id(0)
         b = pl.program_id(1)
         si = pl.program_id(2)
-        Ma = _dense_block(idx_ref[...], val_ref[...], a * DB, DB)
-        Mb = _dense_block(idx_ref[...], val_ref[...], b * DB, DB)
+        MTa = _dense_block_t(idx_ref, val_ref, a * DB, DB)
+        MTb = _dense_block_t(idx_ref, val_ref, b * DB, DB)
         part = lax.dot_general(
-            Ma * sw_ref[...], Mb, (((0,), (0,)), ((), ())),
+            MTa * sw_ref[...], MTb, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
         )  # (DB, DB)
 
@@ -264,14 +269,14 @@ def _gram_2d(idx, val, sw, n_cols, interpret, S, DB):
         kernel,
         grid=grid,
         in_specs=[
-            pl.BlockSpec((S, m), lambda a, b, s: (s, 0)),
-            pl.BlockSpec((S, m), lambda a, b, s: (s, 0)),
-            pl.BlockSpec((S, 1), lambda a, b, s: (s, 0)),
+            pl.BlockSpec((m, S), lambda a, b, s: (0, s)),
+            pl.BlockSpec((m, S), lambda a, b, s: (0, s)),
+            pl.BlockSpec((1, S), lambda a, b, s: (0, s)),
         ],
         out_specs=pl.BlockSpec((DB, DB), lambda a, b, s: (a, b)),
         out_shape=jax.ShapeDtypeStruct((p_pad, p_pad), jnp.float32),
         interpret=interpret,
-    )(idx, val, sw2)
+    )(idx.T, val.T, sw2)
     return out[:n_cols, :n_cols]
 
 
@@ -287,7 +292,7 @@ def packed_weighted_gram(idx, val, sw, n_cols, *, interpret=None,
     stays embarrassingly data-parallel — no cross-block mirroring).
     Same exactness contract as the other kernels: padding entries
     contribute exactly 0.0, bitwise-exact on integer data.
-    ``interpret=None`` auto-selects the interpreter off-TPU."""
+    ``interpret=None`` defers to ``ops.pallas_interpret``."""
     interpret = _resolve_interpret(interpret)
     return _gram_2d(idx, val, jnp.asarray(sw), int(n_cols), interpret,
                     S, DB)

@@ -1,10 +1,9 @@
 """
 Persistent cross-process compile cache + process-wide kernel caches.
 
-Compilation is the dominant non-compute cost of the fan-out hot path
-(BENCH_r05: quick shapes 3.99 s cold vs 0.42 s warm — ~90% of cold
-wall is XLA compilation; the full 96×5 grid pays ~12 s of it). This
-module concentrates every layer of compile reuse in one place:
+Compilation is the dominant non-compute cost of a cold fan-out (every
+grid shape, tree level and predict bucket compiles once per process).
+This module concentrates every layer of compile reuse in one place:
 
 1. **In-process memo caches** with *structural* keys. Kernel builders
    return fresh closures, and ``jax.jit`` keys its own cache on
@@ -22,15 +21,18 @@ module concentrates every layer of compile reuse in one place:
    - AOT memo (``aot_executable``): ``fn.lower(...).compile()``
      executables per (jit entry, shared shape signature, chunk).
 
-2. **On-disk XLA compilation cache** (``enable_disk_cache``): points
-   ``jax_compilation_cache_dir`` at a directory so *repeated service
-   processes* skip XLA compilation entirely — the cold-start killer
-   for short-lived workers. Opt in per backend
-   (``TPUBackend(compile_cache_dir=...)``) or process-wide via the
-   ``SKDIST_COMPILE_CACHE_DIR`` environment variable. Entries key on
-   the serialized HLO + compile flags + jaxlib version, so a cache
-   directory is safe to share between processes and survives code
-   edits that do not change the compiled program.
+2. **On-disk XLA compilation cache** (``enable_disk_cache``): JAX's
+   persistent cache plus this module's export tier (``aot_exports/``
+   beside it), so *repeated processes* skip XLA compilation and Python
+   tracing — the cold-start killer for short-lived workers. Always on;
+   :func:`resolve_cache_dir` is the ONE rule for where it lives: JAX's
+   own ``JAX_COMPILATION_CACHE_DIR`` where that is set, else an
+   explicit ``TPUBackend(compile_cache_dir=...)``, else the fixed
+   ``<checkout>/.jax_cache`` (the directory is part of the cache key,
+   so it is never a temporary name). Entries key on the serialized
+   HLO + compile flags + jaxlib version, so a cache directory is safe
+   to share between processes and survives code edits that do not
+   change the compiled program.
 
 3. **Counters** (``snapshot()``): hits/misses per tier plus cumulative
    lowering/compile wall time, so benchmarks and tests can *see* the
@@ -47,6 +49,7 @@ import time
 import warnings
 
 __all__ = [
+    "resolve_cache_dir",
     "enable_disk_cache",
     "disk_cache_dir",
     "structural_key",
@@ -54,6 +57,7 @@ __all__ = [
     "jit_vmapped",
     "aot_executable",
     "prewarm",
+    "aot_executables",
     "snapshot",
     "scoped_misses",
     "last_stats",
@@ -63,8 +67,15 @@ __all__ = [
 
 from ..obs import trace as _trace
 
-#: environment opt-in for the on-disk XLA compilation cache
-CACHE_DIR_ENV = "SKDIST_COMPILE_CACHE_DIR"
+#: JAX's own variable: where it is set, that directory IS the cache
+CACHE_DIR_ENV = "JAX_COMPILATION_CACHE_DIR"
+#: the fixed default, beside the package (git-ignored in a checkout)
+_DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)
+    ))),
+    ".jax_cache",
+)
 
 _LOCK = threading.RLock()
 
@@ -93,86 +104,73 @@ _JIT_CACHE = {}
 _AOT_CACHE = {}
 #: built kernel closures: namespaced semantic key -> closure
 _KERNEL_MEMO = {}
-#: jit fn -> (process-stable key string, donate) for entries built with
-#: a structural cache_key — the export disk layer's filename basis
+#: jit fn -> (process-stable key string, re-jit wrapper) for entries
+#: built with a structural cache_key — the export disk layer's filename
+#: basis, and how it jits the deserialized program like the original
 _JIT_EXPORT_KEY = {}
 
 _DISK_DIR = None
-_ENV_CHECKED = False
 
 
 # ---------------------------------------------------------------------------
 # on-disk XLA compilation cache
 # ---------------------------------------------------------------------------
 
-def enable_disk_cache(path=None):
-    """Point JAX's persistent compilation cache at ``path`` (or the
-    ``SKDIST_COMPILE_CACHE_DIR`` environment variable when ``path`` is
-    None). Returns the active directory, or None when neither is set.
+def resolve_cache_dir(path=None):
+    """Where the persistent compile cache (XLA entries and the export
+    tier's ``aot_exports/``) lives: ``JAX_COMPILATION_CACHE_DIR`` as it
+    is written, where that is set — an explicit ``path`` yields to it —
+    else ``path``, else ``<checkout>/.jax_cache``."""
+    env = os.environ.get(CACHE_DIR_ENV)
+    if env:
+        return env
+    return os.path.abspath(path) if path else _DEFAULT_DIR
 
-    Idempotent; the first caller wins for the lifetime of the process
-    (JAX's cache config is global — re-pointing it mid-process would
-    split warm state across directories, so a conflicting second path
-    raises). Thresholds are dropped to cache-everything: a service
-    process's cold start pays for EVERY kernel, not only the slow ones.
+
+def enable_disk_cache(path=None):
+    """Turn on JAX's persistent compilation cache at
+    :func:`resolve_cache_dir` and return the active directory.
+
+    Called from every compile path and backend constructor, so the
+    cache is on whatever the entry point. Idempotent; the first caller
+    wins for the lifetime of the process (JAX's cache config is global
+    — re-pointing it mid-process would split warm state across
+    directories, so a conflicting later ``path`` raises). Thresholds
+    are dropped to cache-everything: a service process's cold start
+    pays for EVERY kernel, not only the slow ones.
     """
     global _DISK_DIR
+    if path is None and _DISK_DIR is not None:
+        return _DISK_DIR
     with _LOCK:
-        if path is None:
-            path = os.environ.get(CACHE_DIR_ENV) or None
-        if path is None:
-            return _DISK_DIR
-        path = os.path.abspath(path)
+        target = resolve_cache_dir(path)
         if _DISK_DIR is not None:
-            if _DISK_DIR != path:
+            if _DISK_DIR != target:
                 raise ValueError(
                     "the persistent compile cache is already at "
                     f"{_DISK_DIR!r}; JAX's cache config is process-global "
-                    f"and cannot be re-pointed to {path!r}"
+                    f"and cannot be re-pointed to {target!r}"
                 )
             return _DISK_DIR
         import jax
+        # the export layer needs it; importing now keeps its ~0.3 s
+        # module-exec cost out of the first timed fit
+        from jax import export as _export  # noqa: F401
 
         # the cache backend skips a directory it cannot open; create it
         # up front so the very first compile already writes through
-        os.makedirs(path, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", path)
-        for knob, value in (
-            ("jax_persistent_cache_min_entry_size_bytes", -1),
-            ("jax_persistent_cache_min_compile_time_secs", 0.0),
-        ):
-            try:
-                jax.config.update(knob, value)
-            except Exception:  # pragma: no cover - older jax w/o the knob
-                pass
-        try:
-            # the export layer will need it; importing now keeps its
-            # ~0.3 s module-exec cost out of the first timed fit
-            from jax import export as _export  # noqa: F401
-        except Exception:  # pragma: no cover - jax without jax.export
-            pass
-        _DISK_DIR = path
+        os.makedirs(target, exist_ok=True)
+        if jax.config.jax_compilation_cache_dir != target:
+            jax.config.update("jax_compilation_cache_dir", target)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        _DISK_DIR = target
         return _DISK_DIR
-
-
-def maybe_enable_from_env():
-    """Lazily honour ``SKDIST_COMPILE_CACHE_DIR`` once per process —
-    called from the compile paths so a bare env var works without any
-    backend constructor argument (service launchers set env, not code).
-    """
-    global _ENV_CHECKED
-    if _ENV_CHECKED:
-        return _DISK_DIR
-    with _LOCK:
-        if not _ENV_CHECKED:
-            _ENV_CHECKED = True
-            if _DISK_DIR is None and os.environ.get(CACHE_DIR_ENV):
-                enable_disk_cache()
-    return _DISK_DIR
 
 
 def disk_cache_dir():
-    """The active on-disk cache directory, or None."""
+    """The active on-disk cache directory, or None before the first
+    backend or compile of the process."""
     return _DISK_DIR
 
 
@@ -388,7 +386,7 @@ def jit_vmapped(kernel, static_args, task_sharding=None,
     """
     import jax
 
-    maybe_enable_from_env()
+    enable_disk_cache()
     static_args = tuple(sorted((static_args or {}).items()))
     # NamedSharding hashes by (mesh, spec): distinct meshes/device sets
     # must never share a compiled fn. Sharding pytrees are flattened to
@@ -407,18 +405,24 @@ def jit_vmapped(kernel, static_args, task_sharding=None,
         return jax.vmap(lambda t: kernel(shared, t, **static))(tasks)
 
     jit_kwargs = {"donate_argnums": (1,)} if donate_tasks else {}
-    with _trace.span("compile",
-                     {"tier": "jit", "key": repr(cache_key)[:120]}
-                     if _trace.enabled() else None):
+
+    def wrap(f):
+        # also how the export tier re-jits a deserialized program: the
+        # SAME shardings and donation, or the executable would expect
+        # differently placed arguments than the round loop hands it
         if task_sharding is not None:
-            fn = jax.jit(
-                mapped,
+            return jax.jit(
+                f,
                 in_shardings=(shared_shardings, task_sharding),
                 out_shardings=task_sharding,
                 **jit_kwargs,
             )
-        else:
-            fn = jax.jit(mapped, **jit_kwargs)
+        return jax.jit(f, **jit_kwargs)
+
+    with _trace.span("compile",
+                     {"tier": "jit", "key": repr(cache_key)[:120]}
+                     if _trace.enabled() else None):
+        fn = wrap(mapped)
     _record("jit_misses", time.perf_counter() - t0)
     with _LOCK:
         fn = _JIT_CACHE.setdefault(key, fn)
@@ -433,7 +437,7 @@ def jit_vmapped(kernel, static_args, task_sharding=None,
                       _sharding_desc(task_sharding),
                       tuple(_sharding_desc(s) for s in shared_leaves),
                       bool(donate_tasks))),
-                bool(donate_tasks),
+                wrap,
             )
         return fn
 
@@ -538,6 +542,15 @@ def prewarm(fn, shared_args, task_like, n_chunk=None, shared_sig=None):
     )
 
 
+def aot_executables():
+    """The AOT executables this process holds, in compile order — for
+    diagnostics that read a compiled program itself
+    (``memory_analysis()``, ``as_text()``: which collectives and custom
+    calls the compiler put in)."""
+    with _LOCK:
+        return list(_AOT_CACHE.values())
+
+
 _SOURCE_DIGEST = None
 
 
@@ -597,14 +610,15 @@ def _exported_executable(fn, shared_args, structs, shared_sig, task_sig,
     EXPORTED form — both processes then execute byte-identical
     programs, and the exported form's XLA compile is what the disk
     cache holds, so the warm process's compile is a pure cache read.
-    Any failure (un-exportable program — e.g. some Pallas custom
-    calls — version skew, disk trouble) returns None and the caller
-    falls back to the direct lower+compile path.
+    A failure to export, persist or re-trace the program
+    (un-exportable program, version skew, disk trouble) returns None
+    and the caller falls back to the direct lower+compile path; a
+    failure of the COMPILE itself propagates.
     """
     ent = _JIT_EXPORT_KEY.get(fn)
     if _DISK_DIR is None or ent is None:
         return None
-    keystr, donate = ent
+    keystr, wrap = ent
     try:
         import jax
         from jax import export as jexport
@@ -625,11 +639,7 @@ def _exported_executable(fn, shared_args, structs, shared_sig, task_sig,
                 f.write(blob)
             os.replace(tmp, path)
             _record("aot_export_writes")
-        jit_kwargs = {"donate_argnums": (1,)} if donate else {}
-        return (
-            jax.jit(exp.call, **jit_kwargs)
-            .lower(shared_args, structs).compile()
-        )
+        lowered = wrap(exp.call).lower(shared_args, structs)
     except Exception as exc:
         warnings.warn(
             f"compile_cache export layer disabled for this program "
@@ -637,6 +647,11 @@ def _exported_executable(fn, shared_args, structs, shared_sig, task_sig,
             "compilation"
         )
         return None
+    # outside the fallback: what the compiler refuses in the exported
+    # form (a round that does not fit device memory, a kernel Mosaic
+    # rejects) it refuses in the direct form too, and the caller must
+    # see that, not a warning and a second refused compile
+    return lowered.compile()
 
 
 def shape_sig(tree):

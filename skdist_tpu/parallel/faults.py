@@ -100,7 +100,7 @@ class WatchdogTimeout(RuntimeError):
 #: errors surface as jaxlib XlaRuntimeError whose str() carries the
 #: absl status code; matching the code strings avoids importing jaxlib
 #: internals and also covers transport-level errors raised as plain
-#: RuntimeErrors by the tunnel.
+#: RuntimeErrors by a worker's socket.
 _TRANSIENT_MARKS = (
     "UNAVAILABLE",
     "ABORTED",
@@ -112,6 +112,12 @@ _TRANSIENT_MARKS = (
     "Broken pipe",
 )
 _PREEMPT_MARKS = ("preempt", "PREEMPT", "worker has been restarted")
+#: lower-cased fragments of a COMPILE-time failure. Mosaic and XLA word
+#: a kernel or program the compiler refuses as "INTERNAL: Mosaic failed
+#: to compile TPU kernel ..." / "INTERNAL: ... compilation ...": the
+#: same program fails the same way on every re-dispatch, so it is a
+#: code error, not a hiccup of the runtime.
+_COMPILE_MARKS = ("mosaic", "compil")
 
 
 def classify(exc):
@@ -120,7 +126,9 @@ def classify(exc):
     Order matters: RESOURCE_EXHAUSTED is checked first so the OOM
     resume machinery always wins (some runtimes phrase it
     "INTERNAL: ... RESOURCE_EXHAUSTED"), then preemption (its messages
-    often also carry UNAVAILABLE), then the transient marks.
+    often also carry UNAVAILABLE), then compile-time INTERNAL (fatal:
+    never retried), then the transient marks — a run-time INTERNAL
+    among them.
     """
     if isinstance(exc, WatchdogTimeout):
         return WATCHDOG
@@ -129,6 +137,10 @@ def classify(exc):
         return OOM
     if any(m in msg for m in _PREEMPT_MARKS):
         return PREEMPTED
+    if "INTERNAL" in msg:
+        low = msg.lower()
+        if any(m in low for m in _COMPILE_MARKS):
+            return FATAL
     if any(m in msg for m in _TRANSIENT_MARKS):
         return TRANSIENT
     return FATAL

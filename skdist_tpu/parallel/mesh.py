@@ -53,51 +53,28 @@ def initialize_cluster(coordinator_address=None, num_processes=None,
     initialised or single-host). Wrapper over jax.distributed.
 
     ``jax_kwargs`` pass through to ``jax.distributed.initialize`` —
-    on ELASTIC fleets raise ``service_max_missing_heartbeats`` (and
-    the client twin) well above the default: the coordination
-    service's fail-fast otherwise ABORTS every surviving process
-    ~100s after a peer dies, while the elastic layer's epoch
-    agreement is the membership authority that actually handles the
-    loss."""
+    on ELASTIC fleets raise ``heartbeat_timeout_seconds`` well above
+    the default: the coordination service's fail-fast otherwise ABORTS
+    every surviving process ~100s after a peer dies, while the elastic
+    layer's epoch agreement is the membership authority that actually
+    handles the loss."""
     import jax
 
     if num_processes in (None, 0, 1):
         return
     # Multi-process collectives on the CPU backend need an explicit
-    # cross-process transport (jax >= 0.4.34 ships gloo but defaults to
-    # 'none', and the first cross-process device_put then fails with
+    # cross-process transport (jax ships gloo but defaults to 'none',
+    # and the first cross-process device_put then fails with
     # "Multiprocess computations aren't implemented on the CPU
     # backend"). Harmless on TPU/GPU: the knob only shapes CPU client
     # construction. Must run before the backend is instantiated.
-    try:
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:  # pragma: no cover - jaxlib without the knob/gloo
-        pass
-    try:
-        jax.distributed.initialize(
-            coordinator_address=coordinator_address,
-            num_processes=num_processes,
-            process_id=process_id,
-            **jax_kwargs,
-        )
-    except TypeError:
-        # jax's PUBLIC wrapper lags the internal surface: the heartbeat
-        # tolerance knobs live on global_state.initialize (which the
-        # wrapper forwards to verbatim after a backends-uninitialized
-        # check we replicate here)
-        from jax._src import distributed as _dist
-        from jax._src import xla_bridge as _xb
-
-        if _xb.backends_are_initialized():
-            raise RuntimeError(
-                "initialize_cluster must run before any JAX computation"
-            ) from None
-        _dist.global_state.initialize(
-            coordinator_address=coordinator_address,
-            num_processes=num_processes,
-            process_id=process_id,
-            **jax_kwargs,
-        )
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
+    jax.distributed.initialize(
+        coordinator_address=coordinator_address,
+        num_processes=num_processes,
+        process_id=process_id,
+        **jax_kwargs,
+    )
 
 
 def task_data_mesh(devices=None, data_axis_size=1):
@@ -662,12 +639,9 @@ class ElasticMeshManager:
 def _kv_client():
     """The jax.distributed KV-store client, or None when the cluster
     was never initialized (single-controller runs)."""
-    try:
-        from jax._src import distributed
+    from jax._src import distributed
 
-        return distributed.global_state.client
-    except Exception:  # pragma: no cover - jax without the module
-        return None
+    return distributed.global_state.client
 
 
 # ---------------------------------------------------------------------------

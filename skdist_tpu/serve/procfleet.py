@@ -4,7 +4,7 @@ real fault domains behind a unix-domain-socket front door.
 
 :class:`~skdist_tpu.serve.replicaset.ReplicaSet` (PR 8) heals engines
 *inside one process*: a segfault in a kernel, an unkillable wedged
-device op (the reason ``utils/childproc.py`` exists), or an OOM-kill
+device op, or an OOM-kill
 still takes down every replica at once, because they share a process.
 The reference world never had this problem — Spark gave sk-dist
 executor JVMs as fault domains, with the driver surviving any worker

@@ -397,3 +397,58 @@ def test_proactive_round_sizing(tpu_backend):
         np.asarray(exec_fn(shared, sl)["s"]),
         np.asarray(fn(shared, sl)["s"]),
     )
+
+
+def test_proactive_round_sizing_when_the_round_does_not_compile(
+        tpu_backend, monkeypatch):
+    """On a TPU a round too big for the device is refused AT COMPILE
+    TIME (RESOURCE_EXHAUSTED from the compiler's allocation plan):
+    _aot_exec_fn then reads the footprint from a smaller round that
+    does compile and sizes the first round from it — no fault counter,
+    no reactive shrink. Any other compile failure raises where it
+    happened."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from skdist_tpu.parallel import backend as backend_mod
+    from skdist_tpu.parallel import compile_cache, faults
+
+    bk = tpu_backend
+    d = bk.n_devices
+    ts = NamedSharding(bk.mesh, P(bk.axis_name))
+    rs = NamedSharding(bk.mesh, P())
+
+    def kernel(shared, t):
+        return {"s": jnp.sum(shared["X"]) * t["c"]}
+
+    fn = backend_mod._jit_vmapped(kernel, None, ts, rs)
+    shared = jax.device_put({"X": np.ones((64, 8), np.float32)}, rs)
+    tasks = {"c": np.arange(64 * d, dtype=np.float32)}
+    real = compile_cache.aot_executable
+    refused = []
+
+    def picky(fn_, shared_, task_like, n_chunk, **kw):
+        if n_chunk > 16 * d:
+            refused.append(n_chunk)
+            raise RuntimeError(
+                "RESOURCE_EXHAUSTED: Allocation (size=19660800000) would "
+                "exceed memory (size=17179869184)")
+        return real(fn_, shared_, task_like, n_chunk, **kw)
+
+    monkeypatch.setattr(compile_cache, "aot_executable", picky)
+    faults.reset_stats()
+    with pytest.warns(UserWarning, match="does not compile into device"):
+        _, chunk = backend_mod._aot_exec_fn(
+            fn, shared, tasks, 64 * d, d, free_bytes=1 << 40)
+    assert refused == [64 * d]  # one refused compile, then an eighth
+    assert chunk < 64 * d and chunk >= 8 * d and chunk % d == 0
+    assert faults.snapshot()["suppressed"] == 0
+
+    def broken(*a, **kw):
+        raise RuntimeError("INTERNAL: Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(compile_cache, "aot_executable", broken)
+    with pytest.raises(RuntimeError, match="Mosaic"):
+        backend_mod._aot_exec_fn(fn, shared, tasks, 64 * d, d,
+                                 free_bytes=1 << 40)

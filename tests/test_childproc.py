@@ -1,5 +1,6 @@
-"""Tests for the shared wedge-isolation child runner
-(utils/childproc.py) used by bench.py and benchmarks/run_all.py."""
+"""Tests for the child-process containment recipe
+(utils/childproc.py): hard deadline, process-group kill, bounded
+post-kill wait."""
 
 import sys
 import time

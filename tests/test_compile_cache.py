@@ -135,7 +135,8 @@ dev = DistGridSearchCV(
     LogisticRegression(max_iter=10, engine="xla"), {"C": [0.5, 1.0]},
     backend=TPUBackend(), cv=3, scoring="accuracy",
 ).fit(X, y)
-assert compile_cache.disk_cache_dir() is not None
+import os
+assert compile_cache.disk_cache_dir() == os.environ["JAX_COMPILATION_CACHE_DIR"]
 # the device path ran through the export disk layer (or wrote it);
 # the plain-jit LocalBackend leg must agree — guards the exported
 # program's numerics
@@ -153,13 +154,13 @@ print("CHILD OK", compile_cache.snapshot())
 
 
 def test_disk_cache_reused_across_processes(tmp_path):
-    """Two FRESH processes with SKDIST_COMPILE_CACHE_DIR set: the first
+    """Two FRESH processes with JAX_COMPILATION_CACHE_DIR set: the first
     writes every compiled program to disk; the second runs the same
     workload and adds NO new cache entries — every XLA compile was
     served from disk. (The entry set is deterministic: fixed seeds,
     pinned engine, same flags.)"""
     env = dict(os.environ)
-    env["SKDIST_COMPILE_CACHE_DIR"] = str(tmp_path)
+    env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path)
     env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 
@@ -175,6 +176,8 @@ def test_disk_cache_reused_across_processes(tmp_path):
 
     files1 = run()
     assert files1, "first process must write compiled programs to disk"
+    # the export tier lives under the same directory
+    assert os.listdir(tmp_path / "aot_exports")
     files2 = run()
     assert files2 == files1, (
         "second process recompiled programs the disk cache should have "
@@ -182,13 +185,33 @@ def test_disk_cache_reused_across_processes(tmp_path):
     )
 
 
-def test_enable_disk_cache_conflicting_path_raises(tmp_path):
+def test_resolve_cache_dir_rule(monkeypatch, tmp_path):
+    """JAX's own variable wins, as written; an explicit path yields to
+    it; with neither, the fixed ``<checkout>/.jax_cache``."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert compile_cache.resolve_cache_dir() == os.path.join(
+        REPO, ".jax_cache"
+    )
+    assert compile_cache.resolve_cache_dir(str(tmp_path)) == str(tmp_path)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+    assert compile_cache.resolve_cache_dir() == "/some/dir"
+    assert compile_cache.resolve_cache_dir(str(tmp_path)) == "/some/dir"
+
+
+def test_enable_disk_cache_conflicting_path_raises(monkeypatch, tmp_path):
+    """conftest enabled the cache by the program's rule; with JAX's
+    variable unset a later explicit path cannot re-point it (and with
+    it set, the explicit path yields instead of conflicting)."""
+    import jax
+
     first = compile_cache.disk_cache_dir()
-    if first is None:
-        pytest.skip("no disk cache active in this process; the "
-                    "conflict guard is exercised by the subprocess test")
+    assert first == jax.config.jax_compilation_cache_dir
+    assert compile_cache.enable_disk_cache() == first
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     with pytest.raises(ValueError, match="already"):
         compile_cache.enable_disk_cache(str(tmp_path / "elsewhere"))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", first)
+    assert compile_cache.enable_disk_cache(str(tmp_path / "elsewhere")) == first
 
 
 def test_snapshot_and_reset():

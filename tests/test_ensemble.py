@@ -452,6 +452,44 @@ def test_hist_mode_reaches_kernel_through_dist_wrappers(clf_data):
     np.testing.assert_allclose(preds["scatter"], preds["matmul"], atol=1e-6)
 
 
+@pytest.mark.parametrize("hist_mode,capped", [
+    ("matmul", True), ("matmul_sib", True), ("scatter", False),
+])
+def test_matmul_forest_rounds_are_capped_by_operand_size(
+        clf_data, tpu_backend, monkeypatch, hist_mode, capped):
+    """The one-hot matmul engines materialise a (lanes, n, nl*C) right
+    factor, and on the v5e a round whose factor passed ~2^32 elements
+    returned wrong histograms without an error: forest.fit bounds the
+    lanes per device to what 2^31 elements hold. Engines that
+    materialise no such operand keep the caller's round."""
+    from skdist_tpu.models import tree as tree_mod
+
+    X, y = clf_data
+    n = X.shape[0]
+    # shrink the bound so THIS shape (depth 3, 4 channels) hits it at
+    # two lanes a device
+    monkeypatch.setattr(tree_mod, "MATMUL_MAX_OPERAND_ELEMS",
+                        2 * n * 4 * 4 + 1)
+    assert tree_mod.matmul_lane_cap(n, 3, 4) == 2
+    assert tree_mod.matmul_lane_cap(10 ** 9, 8, 3) == 1  # never zero
+    seen = {}
+    real = type(tpu_backend).batched_map
+
+    def spy(self, kernel, task_args, shared, round_size=None, **kw):
+        seen["round_size"] = round_size
+        return real(self, kernel, task_args, shared,
+                    round_size=round_size, **kw)
+
+    monkeypatch.setattr(type(tpu_backend), "batched_map", spy)
+    n_trees = 6 * tpu_backend.n_task_slots
+    DistRandomForestClassifier(
+        n_estimators=n_trees, max_depth=3, random_state=0,
+        hist_mode=hist_mode, backend=tpu_backend,
+    ).fit(X, y)
+    want = 2 * tpu_backend.n_task_slots if capped else n_trees
+    assert seen["round_size"] == want
+
+
 def test_hist_pallas_matches_scatter(clf_data):
     """hist_mode='pallas' (interpret mode on the CPU mesh) grows the
     identical tree to the scatter reference, including under vmap."""

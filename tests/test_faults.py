@@ -73,6 +73,12 @@ def grid_data():
     ("INTERNAL: allocator RESOURCE_EXHAUSTED", faults.OOM),
     ("ValueError: bad operand", faults.FATAL),
     ("", faults.FATAL),
+    # a program the compiler refuses fails the same way every time
+    ("INTERNAL: Mosaic failed to compile TPU kernel: cannot statically "
+     "prove that index in dimension 1 is a multiple of 128", faults.FATAL),
+    ("INTERNAL: during compilation of module jit_mapped", faults.FATAL),
+    ("INTERNAL: XLA:TPU compile permanent error. RESOURCE_EXHAUSTED: Ran "
+     "out of memory in memory space hbm", faults.OOM),
 ])
 def test_classify(msg, kind):
     assert faults.classify(RuntimeError(msg)) == kind

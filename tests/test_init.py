@@ -36,7 +36,10 @@ MODULES = [
     "skdist_tpu.native",
     "skdist_tpu.utils",
     "skdist_tpu.utils.validation",
-    "skdist_tpu.utils.tpu_probe",
+    "skdist_tpu.utils.childproc",
+    "skdist_tpu.ops.pallas_hist",
+    "skdist_tpu.ops.pallas_sparse",
+    "skdist_tpu.parallel.compile_cache",
 ]
 
 

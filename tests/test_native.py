@@ -167,3 +167,51 @@ def test_as_dense_f32_1d_sparse_array():
     out = as_dense_f32(v)
     assert out.shape == (5, 1) and out.dtype == np.float32
     np.testing.assert_array_equal(out.ravel(), np.arange(5, dtype=np.float32))
+
+
+_MINI_C = """
+#include <Python.h>
+static PyObject *answer(PyObject *self, PyObject *args) {
+    return PyLong_FromLong(%d);
+}
+static PyMethodDef methods[] = {
+    {"answer", answer, METH_NOARGS, ""}, {NULL, NULL, 0, NULL}};
+static struct PyModuleDef mod = {
+    PyModuleDef_HEAD_INIT, "_mini", "", -1, methods};
+PyMODINIT_FUNC PyInit__mini(void) { return PyModule_Create(&mod); }
+"""
+
+
+def test_build_is_keyed_on_source_digest(tmp_path, monkeypatch):
+    """A copied ``_build/`` must never serve an old binary: the .so is
+    named after a digest of its source and flags, so an edited source
+    rebuilds even when its mtime is OLDER than the stale binary's (what
+    a tree copy leaves behind), and the stale binary is removed."""
+    import os
+
+    import pytest
+
+    from skdist_tpu import native
+
+    monkeypatch.setattr(native, "__file__", str(tmp_path / "__init__.py"))
+    monkeypatch.setitem(native._EXT_FLAGS, "mini", ())
+    src = tmp_path / "mini.c"
+
+    def load():
+        native._EXTS.pop("mini", None)
+        mod = native._load_ext("mini")
+        if mod is None:
+            pytest.skip(f"no C compiler: {native._EXT_STATUS['mini']}")
+        return mod.answer(), dict(native._EXT_STATUS["mini"])
+
+    src.write_text(_MINI_C % 1)
+    assert load() == (1, {"loaded": True, "built": True, "error": None})
+    assert load()[1]["built"] is False  # same digest: no rebuild
+    (first,) = os.listdir(tmp_path / "_build")
+    src.write_text(_MINI_C % 2)
+    os.utime(src, (0, 0))  # older than the stale binary
+    assert load() == (2, {"loaded": True, "built": True, "error": None})
+    assert os.listdir(tmp_path / "_build") != [first]
+    assert len(os.listdir(tmp_path / "_build")) == 1
+    native._EXTS.pop("mini", None)
+    native._EXT_STATUS.pop("mini", None)

@@ -257,7 +257,20 @@ class TestStreamedFitParity:
                   shuffle=False, tol=None)
         s = SGDClassifier(**kw).fit(ds)
         r = SGDClassifier(**kw).fit(X, y)
-        assert np.array_equal(np.asarray(s.coef_), np.asarray(r.coef_))
+        # NOT bitwise, unlike its siblings: with 10 rows under a
+        # 64-row batch the two sides are differently SHAPED XLA
+        # programs (the streamed step sees the 10-row block cycled,
+        # the resident one a gathered (64, d) batch), the batch
+        # gradient's row reduction is tiled differently, and the
+        # installed XLA rounds the two orders 1-2 ulp apart in f32.
+        # Three epochs of that stay within a few ulp OF THE UPDATES'
+        # SCALE (the largest coefficient: a small coefficient is a sum
+        # of such updates and inherits their absolute rounding); where
+        # both sides reduce batches of one shape (the tests around
+        # this one) the equality stays exact.
+        sc, rc = np.asarray(s.coef_), np.asarray(r.coef_)
+        np.testing.assert_allclose(
+            sc, rc, rtol=0, atol=4 * np.spacing(np.abs(rc).max()))
 
     def test_sgd_single_block_wrap(self):
         # one full block whose row count is not a batch multiple: the

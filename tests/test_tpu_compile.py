@@ -1,0 +1,240 @@
+"""
+Compile rehearsals: the kernels of the main paths, at the widths
+``chip_smoke.py`` runs them, compiled for a DESCRIBED ``v5e:2x2`` with
+no chip attached — what the TPU's compiler refuses (an unsupported
+primitive in a Pallas kernel, a misaligned block, a program that does
+not fit 16 GB) it refuses here, at no chip time.
+
+A compile that passes is not a chip run: nothing executes, so these say
+nothing about results or times.
+
+The topology is described inside a module-scoped fixture (never at
+import, in a ``skipif`` or in ``parametrize``): only one process may
+load the TPU's library, so only the xdist worker that is GIVEN this
+file may make the call, and it compiles in its own process. JAX's
+persistent compilation cache is off around these tests — an entry
+compiled for a described chip is written but cannot be read back
+without one. Code that asks ``jax.default_backend()`` still sees the
+CPU here, so each test compiles the kernel or jitted step itself and
+names the engine (``hist_mode='matmul'``, ``interpret=False``) that the
+chip would resolve.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+#: one v5e chip's HBM
+HBM_BYTES = 16 * 1024 ** 3
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        desc = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def sds(one_chip):
+    """``sds(shape, dtype)``: a shape on the described chip."""
+
+    def make(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(tuple(shape), dtype, sharding=one_chip)
+
+    return make
+
+
+def _on_chip(tree, sds):
+    """The same shapes, placed on the described chip."""
+    return jax.tree_util.tree_map(lambda a: sds(a.shape, a.dtype), tree)
+
+
+def _device_bytes(compiled):
+    ma = compiled.memory_analysis()
+    return (ma.temp_size_in_bytes + ma.argument_size_in_bytes
+            + ma.output_size_in_bytes)
+
+
+# ---------------------------------------------------------------------------
+# Pallas kernels: must be IN the program as Mosaic custom calls
+# ---------------------------------------------------------------------------
+
+@pytest.mark.parametrize("n,d,B,nl,C", [
+    (200_000, 28, 64, 32, 2),   # chip_smoke's kernels phase
+    (100_000, 54, 64, 16, 3),   # covtype-shaped, three channels
+])
+def test_level_histogram_compiles(sds, n, d, B, nl, C):
+    from skdist_tpu.ops.pallas_hist import level_histogram
+
+    compiled = jax.jit(
+        lambda Xb, key, Ych: level_histogram(
+            Xb, key, Ych, nl=nl, n_bins=B, interpret=False)
+    ).lower(sds((n, d), jnp.int32), sds((n,), jnp.int32),
+            sds((n, C))).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("kernel", ["matvec", "rmatvec", "gram"])
+def test_pallas_sparse_compiles(sds, kernel):
+    """(11,314 rows, m=128, p=4,096, k=20). The rebuild loop reads the
+    transposed packed pair through the ref: a dynamic slice of a loaded
+    value — what these kernels did until the first rehearsal — has no
+    TPU lowering, and every interpret-mode test had passed."""
+    from skdist_tpu.ops import pallas_sparse as ps
+
+    n, m, p, k = 11_314, 128, 4096, 20
+    idx, val = sds((n, m), jnp.int32), sds((n, m))
+    fn, arg = {
+        "matvec": (lambda i, v, W: ps.packed_matvec(
+            i, v, W, interpret=False), sds((p, k))),
+        "rmatvec": (lambda i, v, r: ps.packed_rmatvec(
+            i, v, r, p, interpret=False), sds((n, k))),
+        "gram": (lambda i, v, sw: ps.packed_weighted_gram(
+            i, v, sw, p, interpret=False), sds((n,))),
+    }[kernel]
+    compiled = jax.jit(fn).lower(idx, val, arg).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+# ---------------------------------------------------------------------------
+# the jitted steps of the main paths, at the smoke's widths
+# ---------------------------------------------------------------------------
+
+def _cv_step_program(n, d, k, n_lanes):
+    """The vmapped L-BFGS step slice DistGridSearchCV builds for the
+    compacted path, as an un-sharded jit entry plus the shapes it
+    takes."""
+    from skdist_tpu.distribute.search import (
+        _cached_cv_kernel, _cv_iterative_spec, _cv_kernel_key,
+        _resolve_device_scoring,
+    )
+    from skdist_tpu.models import LogisticRegression
+    from skdist_tpu.models.linear import _freeze, extract_aux
+    from skdist_tpu.parallel.backend import (
+        _iterative_jit_entries, resolve_slice_iters,
+    )
+
+    est = LogisticRegression(max_iter=30, tol=1e-4)
+    rng = np.random.RandomState(0)
+    # meta depends on the width and the classes only: prep on few rows
+    data, meta = est._prep_fit_data(
+        rng.rand(4 * k, d).astype(np.float32), np.arange(4 * k) % k, None)
+    static = _freeze(est._static_config(meta))
+    specs = _resolve_device_scoring(est, "accuracy")
+    key = _cv_kernel_key(type(est), meta, static, specs, False)
+    classic = _cached_cv_kernel(type(est), meta, static, specs, False,
+                                key=key)
+    spec, _ = _cv_iterative_spec(
+        type(est), meta, static, specs, False, resolve_slice_iters(30),
+        fallback=classic, fallback_key=key)
+    init_fn, step_fn, _, _ = _iterative_jit_entries(
+        spec, None, None, None, None)
+    f32 = jax.ShapeDtypeStruct
+    shared = {
+        "X": f32((n, d), jnp.float32),
+        "y": f32((n,), data["y"].dtype),
+        "sw": f32((n,), jnp.float32),
+        "aux": extract_aux(data),
+        "train_masks": f32((5, n), jnp.float32),
+        "test_masks": f32((5, n), jnp.float32),
+    }
+    task = {
+        "hyper": {name: f32((n_lanes,), jnp.float32)
+                  for name in type(est)._hyper_names},
+        "split": f32((n_lanes,), jnp.int32),
+    }
+    carry = jax.eval_shape(init_fn, shared, task)
+    return step_fn, shared, task, carry
+
+
+def test_lbfgs_cv_step_compiles_and_fits_hbm(sds):
+    """11,314 x 4,096, 20 classes, at the round the backend picks for
+    the 480-fit headline on one device: ``iterative_chunk_size(480, 1)``
+    lanes. lanes x (n, k) softmax temporaries and the L-BFGS history
+    are the likely limit, so read the footprint against 16 GB."""
+    from skdist_tpu.parallel.backend import iterative_chunk_size
+
+    n_lanes = iterative_chunk_size(480, 1)
+    assert n_lanes == 60
+    step_fn, shared, task, carry = _cv_step_program(
+        11_314, 4096, 20, n_lanes)
+    shared, task, carry = (_on_chip(t, sds) for t in (shared, task, carry))
+    # the step slice is the program the search spends its time in; the
+    # init slice and the finalize were rehearsed with it and are smaller
+    compiled = step_fn.lower(
+        shared, {"task": task, "carry": carry}).compile()
+    assert _device_bytes(compiled) < 0.85 * HBM_BYTES
+
+
+def test_matmul_tree_level_step_compiles(sds):
+    """The forest tree kernel in ``hist_mode='matmul'`` (what a TPU
+    resolves with no calibration entry) at 200,000 x 28, 32 bins, depth
+    8, vmapped over a round of trees."""
+    from skdist_tpu.models.forest import make_forest_tree_kernel
+    from skdist_tpu.models.tree import resolve_max_features
+
+    n, d, n_trees = 200_000, 28, 8
+    kernel = make_forest_tree_kernel(
+        d=d, n_bins=32, channels=3, max_depth=8,
+        max_features=resolve_max_features("sqrt", d),
+        min_samples_split=2, min_samples_leaf=1,
+        min_impurity_decrease=0.0, extra=False, classification=True,
+        bootstrap=True, hist_mode="matmul", fractional_weights=False,
+    )
+    shared = {"Xb": sds((n, d), jnp.int32), "y": sds((n,), jnp.int32),
+              "sw": sds((n,))}
+    compiled = jax.jit(
+        lambda sh, t: jax.vmap(lambda one: kernel(sh, one))(t)
+    ).lower(shared, {"seed": sds((n_trees,), jnp.int32)}).compile()
+    assert _device_bytes(compiled) < 0.85 * HBM_BYTES
+
+
+def test_banked_predict_compiles(sds):
+    """One banked predict program of the serving registry: 1,024 int8
+    tenants of a 64-feature, 10-class linear model stacked in one bank,
+    a flush of 64 slots x 8 rows, each slot gathering its tenant's row
+    before the member kernel (in-program dequant included)."""
+    from skdist_tpu.distribute.predict import device_predict_plan
+    from skdist_tpu.models import LogisticRegression
+    from skdist_tpu.serve.bank import banked_kernel
+
+    rng = np.random.RandomState(0)
+    X = rng.rand(200, 64).astype(np.float32)
+    model = LogisticRegression(max_iter=5, engine="xla").fit(
+        X, np.arange(200) % 10)
+    plan = device_predict_plan(model, "predict_proba", serve_dtype="int8")
+    bank, slots, rows = 1024, 64, 8
+    stacked = jax.tree_util.tree_map(
+        lambda leaf: sds((bank,) + np.shape(leaf), np.asarray(leaf).dtype),
+        plan.params)
+    kernel = banked_kernel(plan.kernel)
+    compiled = jax.jit(
+        lambda sh, t: jax.vmap(lambda one: kernel(sh, one))(t)
+    ).lower(
+        {"params": stacked},
+        {"X": sds((slots, rows, 64)), "tid": sds((slots,), jnp.int32)},
+    ).compile()
+    assert "s8[" in compiled.as_text()  # the bank stays int8 in HBM
