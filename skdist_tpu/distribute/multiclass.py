@@ -315,7 +315,8 @@ def _iterative_fit_spec(est_cls, meta, static, n_slice, derive,
             return outputs(params, shared, task)
 
         parts = {"init": init, "step": step, "finalize": finalize,
-                 "keys": ks["finalize_keys"]}
+                 "keys": ks["finalize_keys"],
+                 "count_keys": ks.get("count_keys", ())}
         if rung_score is not None:
             f_live = maybe_exact_matmuls(
                 est_cls, ks.get("score_params", ks["finalize"])
@@ -333,7 +334,7 @@ def _iterative_fit_spec(est_cls, meta, static, n_slice, derive,
     return IterativeKernelSpec(
         parts["init"], parts["step"], parts["finalize"], parts["keys"],
         fallback=fallback_kernel, fallback_cache_key=fallback_key,
-        score=parts.get("score"),
+        score=parts.get("score"), count_keys=parts["count_keys"],
     )
 
 

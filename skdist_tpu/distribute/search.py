@@ -48,6 +48,7 @@ from ..metrics import (
     resolve_stream_rung,
     scorer_task_compatible,
 )
+from ..obs import trace as obs_trace
 from ..parallel import (
     RungController,
     faults,
@@ -721,7 +722,24 @@ class DistBaseSearchCV(BaseEstimator):
         into durable search checkpointing: completed (candidate x
         fold) results are journaled there, keyed by the structural
         grid signature, and a re-run of the SAME search after a
-        process kill resumes past its finished tasks."""
+        process kill resumes past its finished tasks.
+
+        With tracing on, the fit is ONE span tree: the ``search_fit``
+        root opens a trace context for its duration, so every span
+        recorded under it — here and in the backend's round loop —
+        carries the fit's ``trace_id`` and its parent's id."""
+        tracing = obs_trace.enabled()
+        span_args = {} if tracing else None
+        with obs_trace.use_context(
+            obs_trace.new_context() if tracing else None
+        ), obs_trace.span("search_fit", span_args):
+            return self._fit(X, y, groups, checkpoint_dir, fit_params,
+                             span_args)
+
+    def _fit(self, X, y, groups, checkpoint_dir, fit_params, span_args):
+        """The body of :meth:`fit`; ``span_args`` (None with tracing
+        off) is the root span's ``args``, filled in once the task
+        count is known."""
         from sklearn.model_selection import check_cv
 
         from ..data import is_chunked
@@ -741,23 +759,28 @@ class DistBaseSearchCV(BaseEstimator):
         backend = resolve_backend(self.backend, n_jobs=self.n_jobs)
         estimator = self.estimator
         is_classifier = getattr(estimator, "_estimator_type", None) == "classifier"
-        cv = check_cv(self.cv, y, classifier=is_classifier)
-        n_splits = cv.get_n_splits(X, y, groups)
+        with obs_trace.span("cv_split"):
+            cv = check_cv(self.cv, y, classifier=is_classifier)
+            n_splits = cv.get_n_splits(X, y, groups)
+            # splitters index rows, not features: chunked X is presented
+            # to them as an (n, 0) stand-in (0 bytes) — fold membership
+            # is a function of n/y/groups alone for every sklearn
+            # splitter
+            split_X = (
+                np.empty((len(X), 0), dtype=np.float32) if is_chunked(X)
+                else X
+            )
+            splits = list(cv.split(split_X, y, groups))
         candidate_params = list(self._get_param_iterator())
         n_candidates = len(candidate_params)
+        if span_args is not None:
+            span_args.update(n_tasks=n_candidates * n_splits,
+                             n_splits=n_splits)
         if self.verbose:
             print(
                 f"Fitting {n_splits} folds for each of {n_candidates} "
                 f"candidates, totalling {n_candidates * n_splits} fits"
             )
-        # splitters index rows, not features: chunked X is presented to
-        # them as an (n, 0) stand-in (0 bytes) — fold membership is a
-        # function of n/y/groups alone for every sklearn splitter
-        split_X = (
-            np.empty((len(X), 0), dtype=np.float32) if is_chunked(X)
-            else X
-        )
-        splits = list(cv.split(split_X, y, groups))
 
         scorers, multimetric = check_multimetric_scoring(estimator, self.scoring)
         self.multimetric_ = multimetric
@@ -789,9 +812,10 @@ class DistBaseSearchCV(BaseEstimator):
         if self.adaptive is not None and not self._adaptive_engaged_:
             warn_not_engaged("the search")
 
-        results = self._format_results(
-            candidate_params, scorers, n_splits, out
-        )
+        with obs_trace.span("format_results"):
+            results = self._format_results(
+                candidate_params, scorers, n_splits, out
+            )
         if self.adaptive is not None:
             # rung_ column: rung at which each candidate died (-1 = ran
             # to completion); killed candidates' scores carry
@@ -820,10 +844,11 @@ class DistBaseSearchCV(BaseEstimator):
         if self.refit:
             best = clone(estimator).set_params(**self.best_params_)
             refit_start = time.perf_counter()
-            if y is not None:
-                best.fit(X, y, **fit_params)
-            else:
-                best.fit(X, **fit_params)
+            with obs_trace.span("refit"):
+                if y is not None:
+                    best.fit(X, y, **fit_params)
+                else:
+                    best.fit(X, **fit_params)
             self.refit_time_ = time.perf_counter() - refit_start
             self.best_estimator_ = best
             if self.preds:
@@ -1098,22 +1123,23 @@ class DistBaseSearchCV(BaseEstimator):
             # indptr alone, so this bail runs BEFORE prepare_fit_X's
             # dense f32 copy is paid for host-routed input.
             return None
-        try:
-            # packable sparse input stays PACKED end to end: shared X
-            # ships as the (idx, val) pair, the fit problems run the
-            # O(nnz) contractions, and the finalize scoring runs the
-            # polymorphic decision kernels on the same packed tree
-            X_arr = prepare_fit_X(X, estimator)
-        except Exception:
-            return None
-
-        n = X_arr.shape[0]
         n_splits = len(splits)
-        train_masks = np.zeros((n_splits, n), dtype=np.float32)
-        test_masks = np.zeros((n_splits, n), dtype=np.float32)
-        for i, (train, test) in enumerate(splits):
-            train_masks[i, train] = 1.0
-            test_masks[i, test] = 1.0
+        with obs_trace.span("prepare_data"):
+            try:
+                # packable sparse input stays PACKED end to end: shared
+                # X ships as the (idx, val) pair, the fit problems run
+                # the O(nnz) contractions, and the finalize scoring
+                # runs the polymorphic decision kernels on the same
+                # packed tree
+                X_arr = prepare_fit_X(X, estimator)
+            except Exception:
+                return None
+            n = X_arr.shape[0]
+            train_masks = np.zeros((n_splits, n), dtype=np.float32)
+            test_masks = np.zeros((n_splits, n), dtype=np.float32)
+            for i, (train, test) in enumerate(splits):
+                train_masks[i, train] = 1.0
+                test_masks[i, test] = 1.0
 
         n_candidates = len(candidate_params)
         n_tasks_total = n_candidates * n_splits
@@ -1136,9 +1162,10 @@ class DistBaseSearchCV(BaseEstimator):
             if static_overrides:
                 bucket_est.set_params(**static_overrides)
             try:
-                data, meta = bucket_est._prep_fit_data(
-                    X_arr, y, sample_weight
-                )
+                with obs_trace.span("prepare_data"):
+                    data, meta = bucket_est._prep_fit_data(
+                        X_arr, y, sample_weight
+                    )
             except Exception:
                 # estimator-level input validation failures must flow
                 # through the host path so the error_score contract
@@ -1266,6 +1293,13 @@ class DistBaseSearchCV(BaseEstimator):
                     ),
                     rung=rung_ctrl,
                 )
+                if inv is not None:
+                    # the per-task work counts come back in dispatch
+                    # order: the caller's order, like the scores below
+                    stats = backend.last_round_stats or {}
+                    for name in ("iters", "fevals"):
+                        if stats.get(name) is not None:
+                            stats[name] = [stats[name][i] for i in inv]
                 if rung_ctrl is not None:
                     # engaged only if the compacted slice loop actually
                     # ran the rungs — a backend downgrade (multi-process

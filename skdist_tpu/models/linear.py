@@ -823,6 +823,9 @@ class _LbfgsFitMixin:
             # finalize touches only these carry leaves: retired lanes'
             # S/Y/rho history never needs to leave the device
             "finalize_keys": ("w", "it"),
+            # per-lane work counts the backend books into RoundStats
+            # (``iters`` / ``fevals``) as lanes retire
+            "count_keys": ("it", "nfev"),
             # score-from-carry: the current iterate is a valid model at
             # every slice boundary (solvers.carry_iterate), so the ASHA
             # rung evaluator shapes params from a LIVE carry with the
@@ -991,8 +994,11 @@ class LogisticRegression(_LbfgsFitMixin, _LinearClassifierBase):
                 ypm = (y_idx == (k - 1)).astype(op.dtype)  # {0,1}
 
                 def data_loss(w):
-                    z = matvec(w)
-                    return jnp.sum(sw * (jax.nn.softplus(z) - ypm * z))
+                    with jax.named_scope("lr/forward_loss"):
+                        z = matvec(w)
+                        return jnp.sum(
+                            sw * (jax.nn.softplus(z) - ypm * z)
+                        )
 
                 def reg_loss(w):
                     if unpenalized:  # penalty=None: sklearn's C=inf
@@ -1018,9 +1024,12 @@ class LogisticRegression(_LbfgsFitMixin, _LinearClassifierBase):
 
             def data_loss(wflat):
                 W = wflat.reshape(p, k)
-                logits = matvec(W)
-                lse = jax.nn.logsumexp(logits, axis=1)
-                return jnp.sum(sw * (lse - jnp.sum(onehot * logits, axis=1)))
+                with jax.named_scope("lr/forward_loss"):
+                    logits = matvec(W)
+                    lse = jax.nn.logsumexp(logits, axis=1)
+                    return jnp.sum(
+                        sw * (lse - jnp.sum(onehot * logits, axis=1))
+                    )
 
             def reg_loss(wflat):
                 if unpenalized:  # penalty=None: sklearn's C=inf

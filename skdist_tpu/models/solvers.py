@@ -50,7 +50,14 @@ from jax import lax
 _EPS = 1e-12
 
 #: order of the L-BFGS carry leaves (the ISSUE-pinned pytree contract)
-LBFGS_CARRY_KEYS = ("w", "f", "g", "S", "Y", "rho", "k", "it", "done")
+#: ``nfev`` counts the forward passes over the data the lane ASKED for
+#: (1 for the initial value-and-gradient; per iteration the trial at
+#: t0, one per line-search halving, and the value-and-gradient at the
+#: accepted point) — the work count that tells a change of speed from a
+#: change of work. Under ``vmap`` a round EXECUTES the halvings of its
+#: slowest lane; each lane still counts its own.
+LBFGS_CARRY_KEYS = ("w", "f", "g", "S", "Y", "rho", "k", "it", "nfev",
+                    "done")
 
 
 def carry_iterate(carry):
@@ -70,7 +77,7 @@ def carry_iterate(carry):
 
 def _lbfgs_body(fun, value_and_grad, max_iter, tol, history, max_ls):
     """One L-BFGS iteration on the tuple state
-    ``(w, f, g, S, Y, rho, k, it, done)`` — shared verbatim by the
+    ``(w, f, g, S, Y, rho, k, it, nfev, done)`` — shared verbatim by the
     unsliced solve and every resume slice, so their trajectories cannot
     diverge."""
     m = history
@@ -104,7 +111,8 @@ def _lbfgs_body(fun, value_and_grad, max_iter, tol, history, max_ls):
         return -lax.fori_loop(0, m, fwd, r)
 
     def line_search(w, f, g, d):
-        """Armijo backtracking; returns (step, f_new, accepted)."""
+        """Armijo backtracking; returns (step, f_new, accepted,
+        halvings)."""
         gd = jnp.dot(g, d)
 
         def cond(carry):
@@ -119,13 +127,14 @@ def _lbfgs_body(fun, value_and_grad, max_iter, tol, history, max_ls):
 
         t0 = 1.0
         f1 = fun(w + t0 * d)
-        t, f_new, _ = lax.while_loop(cond, body, (t0, f1, 0))
+        t, f_new, n_halved = lax.while_loop(cond, body, (t0, f1, 0))
         ok = f_new <= f + 1e-4 * t * gd
-        return t, f_new, ok
+        return t, f_new, ok, n_halved
 
     def body(state):
-        w, f, g, S, Y, rho, k, it, done = state
-        d = two_loop(g, S, Y, rho, k)
+        w, f, g, S, Y, rho, k, it, nfev, done = state
+        with jax.named_scope("lbfgs/two_loop"):
+            d = two_loop(g, S, Y, rho, k)
         # safeguard: fall back to steepest descent if d isn't a descent dir
         descent = jnp.dot(g, d) < 0
         d = jnp.where(descent, d, -g)
@@ -140,26 +149,34 @@ def _lbfgs_body(fun, value_and_grad, max_iter, tol, history, max_ls):
         d = jnp.where(
             raw_scale, d / (jnp.linalg.norm(d) + _EPS), d
         )
-        t, f_new, ok = line_search(w, f, g, d)
+        with jax.named_scope("lbfgs/line_search"):
+            t, f_new, ok, n_halved = line_search(w, f, g, d)
         w_new = w + t * d
-        f_new2, g_new = value_and_grad(w_new)
-        s = w_new - w
-        yv = g_new - g
-        sy = jnp.dot(s, yv)
-        # curvature check: only store pairs with s·y > 0
-        store = sy > 1e-10
-        idx = k % m
-        S = jnp.where(store, S.at[idx].set(s), S)
-        Y = jnp.where(store, Y.at[idx].set(yv), Y)
-        rho = jnp.where(store, rho.at[idx].set(1.0 / (sy + _EPS)), rho)
-        k_new = k + jnp.where(store, 1, 0)
+        with jax.named_scope("lbfgs/value_and_grad"):
+            f_new2, g_new = value_and_grad(w_new)
+        with jax.named_scope("lbfgs/history_update"):
+            s = w_new - w
+            yv = g_new - g
+            sy = jnp.dot(s, yv)
+            # curvature check: only store pairs with s·y > 0
+            store = sy > 1e-10
+            idx = k % m
+            S = jnp.where(store, S.at[idx].set(s), S)
+            Y = jnp.where(store, Y.at[idx].set(yv), Y)
+            rho = jnp.where(
+                store, rho.at[idx].set(1.0 / (sy + _EPS)), rho
+            )
+            k_new = k + jnp.where(store, 1, 0)
         converged = jnp.max(jnp.abs(g_new)) <= tol
         stalled = ~ok  # line search failed to find decrease
         # ``done`` also latches the iteration cap so the flag alone
         # answers "will more steps change this lane?" — what the
         # backend's flags-only compaction gather reads
         done_new = converged | stalled | (it + 1 >= max_iter)
-        return (w_new, f_new2, g_new, S, Y, rho, k_new, it + 1, done_new)
+        # trial at t0 + the halvings + the value-and-gradient above
+        nfev_new = nfev + n_halved + 2
+        return (w_new, f_new2, g_new, S, Y, rho, k_new, it + 1, nfev_new,
+                done_new)
 
     return body
 
@@ -181,7 +198,7 @@ def lbfgs_carry_init(fun, w0, max_iter=100, tol=1e-4, history=10):
         jnp.zeros((m, p), w0.dtype),
         jnp.zeros((m, p), w0.dtype),
         jnp.zeros(m, w0.dtype),
-        jnp.array(0), jnp.array(0), done0,
+        jnp.array(0), jnp.array(0), jnp.array(1), done0,
     )))
 
 
@@ -200,7 +217,7 @@ def lbfgs_resume(fun, carry, n_steps, max_iter=100, tol=1e-4, history=10,
     state = tuple(carry[k] for k in LBFGS_CARRY_KEYS)
 
     def cond_j(state_j):
-        (_, _, _, _, _, _, _, it, done), j = state_j
+        (_, _, _, _, _, _, _, it, _, done), j = state_j
         return (j < n_steps) & (it < max_iter) & ~done
 
     def body_j(state_j):

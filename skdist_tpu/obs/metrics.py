@@ -574,6 +574,15 @@ ROUND_STATS_REQUIRED = {
     "binned_bytes_cached": 0,
     "binned_bytes_streamed": 0,
     "rung_survivors": None,  # per-rung survivor counts, "12,4,2"
+    # work and occupancy counts of the compacted loop, None on every
+    # path (and for every spec) that has no ``count_keys``: per task
+    # the solver's iterations and the loss evaluations it asked for,
+    # and the lane slots dispatched against those that carried a real,
+    # still-running fit when their round was enqueued
+    "iters": None,
+    "fevals": None,
+    "lane_slots": None,
+    "live_lane_slots": None,
 }
 
 

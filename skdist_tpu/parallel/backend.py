@@ -262,14 +262,21 @@ class IterativeKernelSpec:
     it on backends without the slice loop, on multi-process meshes
     (per-slice host compaction decisions would need cross-process
     agreement), and when a compacted round exhausts device memory.
+
+    ``count_keys`` optionally names the carry's per-lane work counters,
+    ``(iterations, evaluations)``: the compacted loop gathers them with
+    the finalize leaves as lanes retire and books them, one int per
+    task, under ``iters`` / ``fevals`` of its RoundStats — together
+    with the lane-slot occupancy (``lane_slots`` / ``live_lane_slots``).
+    A spec without them leaves all four ``None``.
     """
 
     __slots__ = ("init", "step", "finalize", "finalize_keys", "done_key",
-                 "fallback", "fallback_cache_key", "score")
+                 "fallback", "fallback_cache_key", "score", "count_keys")
 
     def __init__(self, init, step, finalize, finalize_keys,
                  done_key="done", fallback=None, fallback_cache_key=None,
-                 score=None):
+                 score=None, count_keys=()):
         self.init = init
         self.step = step
         self.finalize = finalize
@@ -278,6 +285,7 @@ class IterativeKernelSpec:
         self.fallback = fallback
         self.fallback_cache_key = fallback_cache_key
         self.score = score
+        self.count_keys = tuple(count_keys)
 
 
 class IterativePlan:
@@ -896,7 +904,14 @@ class TPUBackend(TaskBackend):
         the task-axis and shared shardings, place the shared args
         (through the opt-in broadcast-reuse cache), and build the
         task-slice ``put``. Returns ``(task_sharding, shared_shardings,
-        shared_args_placed, put)``."""
+        shared_args_placed, put)``.
+
+        The placement runs under a ``place_shared`` span (``args``:
+        ``bytes``). With tracing ENABLED the span ends only after
+        ``jax.block_until_ready`` of the placed tree, so its duration
+        is the transfer's and not the enqueue's — the one place tracing
+        changes what the host does (untraced, the transfer overlaps the
+        host work that follows it)."""
         import jax
         from jax.sharding import NamedSharding, PartitionSpec as P
 
@@ -913,26 +928,34 @@ class TPUBackend(TaskBackend):
             )
         else:
             shared_shardings = rep_sharding
-        if isinstance(shared_shardings, NamedSharding):
-            # single sharding for the whole tree: leaf-wise put through
-            # the reuse cache (sharding-spec trees skip the cache — the
-            # 2D row-sharded case re-puts every fit)
-            shared_args = jax.tree_util.tree_map(
-                lambda a: _cached_device_put(
-                    a, shared_shardings, self.reuse_broadcast
-                ),
-                shared_args,
-            )
-        else:
-            # shardings form a PREFIX tree of shared_args (one sharding
-            # per top-level entry; entries may be sub-trees)
-            shared_args = jax.tree_util.tree_map(
-                lambda sh, sub: jax.tree_util.tree_map(
-                    lambda a: _put_mesh_scoped(a, sh), sub
-                ),
-                shared_shardings, shared_args,
-                is_leaf=lambda x: isinstance(x, NamedSharding),
-            )
+        tracing = obs_trace.enabled()
+        with obs_trace.span(
+            "place_shared",
+            {"bytes": tree_nbytes(shared_args)} if tracing else None,
+        ):
+            if isinstance(shared_shardings, NamedSharding):
+                # single sharding for the whole tree: leaf-wise put
+                # through the reuse cache (sharding-spec trees skip the
+                # cache — the 2D row-sharded case re-puts every fit)
+                shared_args = jax.tree_util.tree_map(
+                    lambda a: _cached_device_put(
+                        a, shared_shardings, self.reuse_broadcast
+                    ),
+                    shared_args,
+                )
+            else:
+                # shardings form a PREFIX tree of shared_args (one
+                # sharding per top-level entry; entries may be
+                # sub-trees)
+                shared_args = jax.tree_util.tree_map(
+                    lambda sh, sub: jax.tree_util.tree_map(
+                        lambda a: _put_mesh_scoped(a, sh), sub
+                    ),
+                    shared_shardings, shared_args,
+                    is_leaf=lambda x: isinstance(x, NamedSharding),
+                )
+            if tracing:
+                jax.block_until_ready(shared_args)
         put = lambda t: jax.tree_util.tree_map(
             lambda a: _put_mesh_scoped(a, task_sharding), t
         )
@@ -2365,8 +2388,6 @@ def _run_compacted(plan, spec, task_args, n_tasks, chunk, stats,
     computations, same as the classic loop. Raises whatever the device
     raises on OOM (the caller downgrades to the classic path).
     """
-    import jax
-
     depth = _MAX_ROUNDS_IN_FLIGHT if pipeline else 1
     put = plan.put
     shared = plan.shared
@@ -2393,6 +2414,34 @@ def _run_compacted(plan, spec, task_args, n_tasks, chunk, stats,
         if rung is not None and plan.score_fn is not None else None
     )
 
+    with obs_trace.span("round_loop"):
+        fin_carry = _compacted_slice_loop(
+            spec, put, init_exec, step_exec, score_exec, task_args,
+            n_tasks, chunk, stats, depth, rung,
+        )
+    # phase 2: finalize everything in ORIGINAL task order through the
+    # ordinary round loop (same chunk shape -> same compiled program
+    # for every finalize round, tail padded by _run_in_rounds)
+    fin_stats = {}
+    with obs_trace.span("finalize"):
+        out = _run_in_rounds(
+            lambda sh, sl: fin_exec(sl),
+            {"task": task_args, "carry": fin_carry},
+            shared, n_tasks, chunk, put=put, concat=True,
+            pipeline=pipeline, stats=fin_stats, on_round=on_round,
+        )
+    stats["finalize"] = fin_stats
+    return out
+
+
+def _compacted_slice_loop(spec, put, init_exec, step_exec, score_exec,
+                          task_args, n_tasks, chunk, stats, depth, rung):
+    """Phase 1 of :func:`_run_compacted` (its ``round_loop`` span): the
+    slices, the flags gathers, the rungs and the compactions. Books the
+    loop's accounting into ``stats`` and returns the per-task store of
+    the ``finalize_keys`` carry leaves, in task order."""
+    import jax
+
     rounds = []
     for start in range(0, n_tasks, chunk):
         stop = min(start + chunk, n_tasks)
@@ -2410,9 +2459,19 @@ def _run_compacted(plan, spec, task_args, n_tasks, chunk, stats,
         "retired_rung": 0, "retired_convergence": 0, "rung_history": [],
         "rung_wait_s": 0.0,
     })
+    counting = bool(spec.count_keys)
+    if counting:
+        # lane-slot occupancy: slots dispatched against slots that
+        # carried a real, still-running fit when their round was
+        # enqueued (the rest is padding and lanes riding on after done)
+        stats.update({"lane_slots": 0, "live_lane_slots": 0})
 
-    # per-task store of the finalize-subset carry leaves, filled as
-    # lanes retire; allocated lazily from the first retired leaf
+    # per-task store of the carry leaves that leave the device as lanes
+    # retire (the finalize subset, plus the spec's work counters);
+    # allocated lazily from the first retired leaf
+    retire_keys = spec.finalize_keys + tuple(
+        k for k in spec.count_keys if k not in spec.finalize_keys
+    )
     fin_store = {}
 
     # rung kills are a HOST-side verdict: the device carry's done leaf
@@ -2431,7 +2490,7 @@ def _run_compacted(plan, spec, task_args, n_tasks, chunk, stats,
                 r.done = done
 
     def retire(idx_arr, subset):
-        for key in spec.finalize_keys:
+        for key in retire_keys:
             leaf = np.asarray(subset[key])
             arr = fin_store.get(key)
             if arr is None:
@@ -2448,11 +2507,19 @@ def _run_compacted(plan, spec, task_args, n_tasks, chunk, stats,
         def flags_pop():
             r = pending.pop(0)
             t_g = time.perf_counter()
-            r.done = _flags_only_gather(r.dev_carry[spec.done_key])
+            with obs_trace.span("flags_wait"):
+                r.done = _flags_only_gather(r.dev_carry[spec.done_key])
             stats["flags_wait_s"] += time.perf_counter() - t_g
 
         for r in rounds:
             t_d = time.perf_counter()
+            if counting:
+                keep = len(r.idx)
+                stats["lane_slots"] += int(chunk)
+                stats["live_lane_slots"] += keep - (
+                    0 if r.done is None
+                    else int(np.count_nonzero(r.done[:keep]))
+                )
             with obs_trace.span("round_dispatch"):
                 if r.dev_task is None:
                     # task args never change between slices: place once
@@ -2545,7 +2612,7 @@ def _run_compacted(plan, spec, task_args, n_tasks, chunk, stats,
             if done_lanes.all():
                 retire(r.idx, {
                     k: _flags_only_gather(r.dev_carry[k])[:keep]
-                    for k in spec.finalize_keys
+                    for k in retire_keys
                 })
                 r.dev_carry = None
             else:
@@ -2578,7 +2645,7 @@ def _run_compacted(plan, spec, task_args, n_tasks, chunk, stats,
                 if not alive.all():
                     retire(r.idx[~alive], {
                         k: np.asarray(host_c[k])[:keep][~alive]
-                        for k in spec.finalize_keys
+                        for k in retire_keys
                     })
                 id_parts.append(r.idx[alive])
                 carry_parts.append(jax.tree_util.tree_map(
@@ -2616,19 +2683,10 @@ def _run_compacted(plan, spec, task_args, n_tasks, chunk, stats,
         stats["retired_rung"] = len(rung.killed)
         stats["rung_history"] = [dict(h) for h in rung.history]
     stats["retired_convergence"] = n_tasks - stats["retired_rung"]
-
-    # phase 2: finalize everything in ORIGINAL task order through the
-    # ordinary round loop (same chunk shape -> same compiled program
-    # for every finalize round, tail padded by _run_in_rounds)
-    fin_stats = {}
-    out = _run_in_rounds(
-        lambda sh, sl: fin_exec(sl),
-        {"task": task_args, "carry": dict(fin_store)},
-        shared, n_tasks, chunk, put=put, concat=True,
-        pipeline=pipeline, stats=fin_stats, on_round=on_round,
-    )
-    stats["finalize"] = fin_stats
-    return out
+    # per-task work counts, in the task axis's order
+    for name, key in zip(("iters", "fevals"), spec.count_keys):
+        stats[name] = fin_store[key].tolist()
+    return {k: fin_store[k] for k in spec.finalize_keys}
 
 
 #: AOT executables live in compile_cache (keyed by (jit fn, shared

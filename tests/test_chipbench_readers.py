@@ -1,0 +1,272 @@
+"""
+The benchmark's readers of what the program measures inside a fit:
+``round_counts`` (the round loop's counters) and ``span_seconds`` (the
+program's span tree), on hand-built windows and hand-built ring events,
+and every metric file against the reader and the ``BENCHMARK.json``
+entry it needs.
+"""
+
+import glob
+import importlib
+import json
+import os
+import sys
+
+import pytest
+
+REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
+sys.path.insert(0, REPO)
+
+from chipbench.readers import round_counts, span_seconds  # noqa: E402
+from skdist_tpu.obs import trace as obs_trace  # noqa: E402
+
+NEW_METRICS = (
+    "loss_evals_per_fit.search", "lbfgs_iters_per_fit.search",
+    "live_lane_share_pct.search", "place_s_per_fit.search",
+    "refit_s_per_fit.search", "search_host_s_per_fit.search",
+)
+
+
+# ---------------------------------------------------------------------------
+# round_counts
+# ---------------------------------------------------------------------------
+
+def _fit(stats, units=4, failed=0):
+    return {"stats": stats, "units": units, "failed": failed}
+
+
+def _ctx(fits):
+    return {"fits": fits,
+            "units_done": sum(f["units"] - f["failed"] for f in fits)}
+
+
+COUNTED = {"iters": [10, 20, 30, 40], "fevals": [21, 45, 70, 100],
+           "lane_slots": 16, "live_lane_slots": 12,
+           "finalize": {"rounds": 1, "dispatch_s": 0.1}}
+
+
+@pytest.mark.parametrize("args, want", [
+    ({"key": "iters"}, 100 / 4),
+    ({"key": "fevals"}, 236 / 4),
+    ({"num": "live_lane_slots", "den": "lane_slots"}, 75.0),
+])
+def test_round_counts_one_fit(args, want):
+    assert round_counts.read(_ctx([_fit(COUNTED)]), **args) == want
+
+
+def test_round_counts_sum_over_fits_and_finalize_parts():
+    second = dict(COUNTED, iters=[1, 1, 1, 1], lane_slots=8,
+                  live_lane_slots=8,
+                  finalize={"lane_slots": 8, "live_lane_slots": 0})
+    ctx = _ctx([_fit(COUNTED), _fit(second)])
+    assert round_counts.read(ctx, key="iters") == 104 / 8
+    assert round_counts.read(
+        ctx, num="live_lane_slots", den="lane_slots") == 100 * 20 / 32
+
+
+@pytest.mark.parametrize("stats", [
+    {"rounds": 3, "dispatch_s": 0.5},                      # the parent
+    {"iters": None, "fevals": None, "lane_slots": None,
+     "live_lane_slots": None},                             # no count_keys
+    None,                                                  # the fit raised
+])
+def test_round_counts_nothing_to_read(stats):
+    ctx = _ctx([_fit(stats)])
+    assert round_counts.read(ctx, key="fevals") is None
+    assert round_counts.read(
+        ctx, num="live_lane_slots", den="lane_slots") is None
+
+
+def test_round_counts_no_unit_completed():
+    ctx = _ctx([_fit(COUNTED, units=4, failed=4)])
+    assert round_counts.read(ctx, key="iters") is None
+
+
+# ---------------------------------------------------------------------------
+# span_seconds
+# ---------------------------------------------------------------------------
+
+def _span(name, t0, dur, trace, span, parent):
+    return (name, "X", float(t0), float(dur), 1,
+            {"trace_id": trace, "span_id": span, "parent_id": parent})
+
+
+def _tree(trace, t0, scale=1.0):
+    """One fit's ring events, children before their parent (the ring
+    appends a span when it exits). Durations times ``scale``: root 10,
+    cv_split 0.5, place_shared 1, round_loop 5 (holding a dispatch and
+    a wait), finalize 1, refit 2 (holding a nested place_shared of
+    0.5): 0.5 of the root is nobody's."""
+    root = trace + "-root"
+    s = scale
+
+    def sp(name, at, dur, span, parent=root):
+        return _span(name, t0 + at * s, dur * s, trace, trace + span,
+                     parent)
+
+    return [
+        sp("cv_split", 0, 0.5, "-a"),
+        sp("place_shared", 0.5, 1, "-b"),
+        sp("round_dispatch", 1.5, 0.1, "-c1", trace + "-c"),
+        sp("flags_wait", 1.6, 4.9, "-c2", trace + "-c"),
+        sp("round_loop", 1.5, 5, "-c"),
+        sp("finalize", 6.5, 1, "-d"),
+        sp("place_shared", 7.5, 0.5, "-e1", trace + "-e"),
+        sp("refit", 7.5, 2, "-e"),
+        _span("search_fit", t0, 10 * s, trace, root, trace + "-caller"),
+    ]
+
+
+MINUS = ("place_shared", "round_loop", "finalize", "refit")
+
+
+def test_span_seconds_one_root():
+    events = _tree("t1", 100.0)
+    assert span_seconds.per_fit(events, 1, "search_fit") == 10.0
+    assert span_seconds.per_fit(events, 1, "refit") == 2.0
+    # summed over the whole tree: the refit's nested placement counts
+    assert span_seconds.per_fit(events, 1, "place_shared") == 1.5
+    # ... but is taken off the root once, inside `refit`
+    assert span_seconds.per_fit(
+        events, 1, "search_fit", MINUS) == pytest.approx(1.0)
+
+
+def test_span_seconds_skips_the_warm_up_fit():
+    loose = ("compile", "X", 50.0, 3.0, 1, {"tier": "aot"})
+    instant = ("lane_retire", "i", 120.0, 0.0, 1,
+               {"trace_id": "t2", "parent_id": "t2-c"})
+    events = ([loose] + _tree("warm", 0.0, scale=3.0) + _tree("t1", 100.0)
+              + [instant] + _tree("t2", 200.0, scale=2.0))
+    assert span_seconds.per_fit(events, 2, "refit") == (2.0 + 4.0) / 2
+    assert span_seconds.per_fit(events, 1, "refit") == 4.0
+    assert span_seconds.per_fit(events, 3, "refit") == (6 + 2 + 4) / 3
+    assert span_seconds.per_fit(
+        events, 2, "search_fit", MINUS) == pytest.approx(1.5)
+
+
+def test_span_seconds_too_few_roots():
+    events = _tree("t1", 0.0)
+    assert span_seconds.per_fit(events, 2, "refit") is None
+    assert span_seconds.per_fit([], 1, "refit") is None
+    assert span_seconds.per_fit(events, 0, "refit") is None
+    # the parent's ring: its loose spans carry no ids and name no root
+    parent = [("round_dispatch", "X", 1.0, 0.1, 1, None),
+              ("compile", "X", 2.0, 0.1, 1, {"tier": "jit"})]
+    assert span_seconds.per_fit(parent, 1, "place_shared") is None
+
+
+@pytest.fixture
+def ring():
+    """The program's ring, enabled and empty, with what the test
+    appends; restored to off and empty."""
+    obs_trace.set_enabled(True)
+    obs_trace.clear()
+    yield obs_trace._append
+    obs_trace.set_enabled(False)
+    obs_trace.set_ring_size(65536)
+
+
+def test_span_seconds_reads_the_programs_ring(ring):
+    for ev in _tree("warm", 0.0) + _tree("t1", 100.0, scale=2.0):
+        ring(ev)
+    ctx = {"fits": [{}]}
+    assert span_seconds.read(ctx, span="refit") == 4.0
+    assert span_seconds.read(
+        ctx, span="search_fit", minus=list(MINUS)) == pytest.approx(2.0)
+    assert span_seconds.read({"fits": [{}, {}, {}]}, span="refit") is None
+
+
+def test_span_seconds_none_when_tracing_is_off(ring):
+    for ev in _tree("t1", 0.0):
+        ring(ev)
+    obs_trace.set_enabled(False)
+    assert span_seconds.read({"fits": [{}]}, span="refit") is None
+
+
+def test_span_seconds_none_on_a_dropped_ring(ring):
+    obs_trace.set_ring_size(9)
+    for ev in _tree("warm", 0.0) + _tree("t1", 100.0):
+        ring(ev)
+    assert obs_trace.dropped() == 9
+    assert len(obs_trace.events()) == 9  # t1's tree is whole
+    assert span_seconds.read({"fits": [{}]}, span="refit") is None
+
+
+def test_span_seconds_on_a_real_traced_fit(ring):
+    """The reader against the program itself: the three span metrics of
+    one small traced search add up inside its root."""
+    import jax
+    import numpy as np
+
+    from skdist_tpu.distribute.search import DistGridSearchCV
+    from skdist_tpu.models import LogisticRegression
+    from skdist_tpu.parallel import TPUBackend
+
+    rng = np.random.RandomState(0)
+    X = rng.normal(size=(300, 8)).astype(np.float32)
+    y = (X[:, 0] > 0).astype(np.int64)
+    backend = TPUBackend(devices=jax.devices()[:1])
+    for _ in range(2):  # a warm-up fit, then the window's
+        DistGridSearchCV(
+            LogisticRegression(max_iter=40, engine="xla"),
+            {"C": [float(c) for c in np.logspace(-2, 2, 8)]},
+            backend=backend, cv=4,
+        ).fit(X, y)
+    stats = dict(backend.last_round_stats)
+    ctx = {"fits": [{"stats": stats, "units": 32, "failed": 0}],
+           "units_done": 32}
+    root = span_seconds.read(ctx, span="search_fit")
+    parts = [span_seconds.read(ctx, span="place_shared"),
+             span_seconds.read(ctx, span="refit"),
+             span_seconds.read(ctx, span="search_fit", minus=list(MINUS))]
+    assert all(p is not None and p > 0 for p in parts)
+    assert sum(parts) < root
+    assert round_counts.read(ctx, key="iters") <= 40
+    assert round_counts.read(ctx, key="fevals") >= (
+        2 * round_counts.read(ctx, key="iters") + 1)
+    assert 0 < round_counts.read(
+        ctx, num="live_lane_slots", den="lane_slots") <= 100
+
+
+# ---------------------------------------------------------------------------
+# the metric files
+# ---------------------------------------------------------------------------
+
+def _bench():
+    with open(os.path.join(REPO, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+@pytest.mark.parametrize("name", NEW_METRICS)
+def test_new_metric_resolves_to_a_reader_and_an_entry(name):
+    with open(os.path.join(REPO, "chipbench", "metrics",
+                           name + ".json")) as f:
+        spec = json.load(f)
+    reader = importlib.import_module("chipbench.readers." + spec["reader"])
+    assert reader in (round_counts, span_seconds)
+    entry = {m["name"]: m for m in _bench()["per_layer"]}[name]
+    assert set(entry) == {"name", "unit", "better", "source", "layer",
+                          "moves", "workloads"}
+    assert entry["workloads"] == ["search-epsilon"]
+    assert entry["moves"] == "search_fits_per_s"
+    assert entry["source"] == ("program_counter" if reader is round_counts
+                               else "program_span")
+    # the reader takes the file's arguments, and finds nothing to read
+    # in a window of a program that measures none of this
+    empty = {"fits": [{"stats": {"rounds": 1}, "units": 1, "failed": 0}],
+             "units_done": 1}
+    assert reader.read(empty, **spec.get("args", {})) is None
+
+
+def test_every_metric_file_has_its_entry_and_reader():
+    bench = _bench()
+    named = {m["name"] for m in bench["end_to_end"] + bench["per_layer"]}
+    files = glob.glob(os.path.join(REPO, "chipbench", "metrics", "*.json"))
+    assert {os.path.basename(p)[:-len(".json")] for p in files} == named
+    for path in files:
+        with open(path) as f:
+            spec = json.load(f)
+        assert os.path.exists(os.path.join(
+            REPO, "chipbench", "readers", spec["reader"] + ".py"))
+    # the new entries were appended: the accepted ones keep their places
+    assert [m["name"] for m in bench["per_layer"]][-6:] == list(NEW_METRICS)

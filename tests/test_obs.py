@@ -385,12 +385,20 @@ class TestRegistryViews:
 # RoundStats: the converged last_round_stats schema, pinned per path
 # ---------------------------------------------------------------------------
 
-def _assert_round_schema(stats, mode=None):
+#: the compacted loop's work and occupancy counts: present on every
+#: path, None wherever the spec names no ``count_keys``
+COUNT_KEYS = ("iters", "fevals", "lane_slots", "live_lane_slots")
+
+
+def _assert_round_schema(stats, mode=None, counted=False):
     assert isinstance(stats, dict)
     missing = [k for k in ROUND_STATS_REQUIRED if k not in stats]
     assert not missing, f"missing RoundStats keys: {missing}"
     if mode is not None:
         assert stats["mode"] == mode
+    for key in COUNT_KEYS:
+        assert key in ROUND_STATS_REQUIRED
+        assert (stats[key] is not None) == counted, (key, stats[key])
 
 
 class TestRoundStatsSchema:
@@ -459,6 +467,29 @@ class TestRoundStatsSchema:
         assert st["tasks"] == 30
         assert st["retired_convergence"] == 30
         assert st["retired_rung"] == 0
+
+    def test_compacted_path_counted(self, tpu_backend):
+        """The L-BFGS family names its counters: a search on the
+        compacted path fills all four keys, one count per task."""
+        from skdist_tpu.distribute.search import DistGridSearchCV
+        from skdist_tpu.models import LogisticRegression
+
+        rng = np.random.RandomState(0)
+        X = rng.normal(size=(200, 6)).astype(np.float32)
+        y = (X[:, 0] > 0).astype(np.int64)
+        DistGridSearchCV(
+            LogisticRegression(max_iter=40, engine="xla"),
+            {"C": [float(c) for c in np.logspace(-2, 2, 8)]},
+            backend=tpu_backend, cv=4,
+        ).fit(X, y)
+        st = tpu_backend.last_round_stats
+        _assert_round_schema(st, "compacted", counted=True)
+        assert len(st["iters"]) == len(st["fevals"]) == st["tasks"] == 32
+        assert all(f >= 2 * i + 1
+                   for i, f in zip(st["iters"], st["fevals"]))
+        assert max(st["iters"]) <= 40
+        assert 0 < st["live_lane_slots"] <= st["lane_slots"]
+        assert st["lane_slots"] == st["chunk"] * st["rounds"]
 
     def test_streamed_path(self):
         from skdist_tpu.data import ChunkedDataset
