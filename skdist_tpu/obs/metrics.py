@@ -583,6 +583,13 @@ ROUND_STATS_REQUIRED = {
     "fevals": None,
     "lane_slots": None,
     "live_lane_slots": None,
+    # how the compacted path sized its rounds, None on every other
+    # path: the rule that set ``chunk`` ("round_size", "all_tasks",
+    # "amortised", "memory" or "target_rounds":
+    # ``backend.iterative_chunk_size``) and the cap device memory put
+    # on a round's lanes (None where the device reports no memory)
+    "chunk_basis": None,
+    "lanes_fit": None,
 }
 
 

@@ -471,10 +471,10 @@ class RungController:
 
 
 #: smallest task set the convergence-compacted path engages for — below
-#: this the workload fits in one or two rounds and live-task compaction
-#: has nothing to merge, while the three slice-loop programs would
-#: still have to compile (the classic fused kernel also stays the
-#: bitwise-pinned reference path for the small parity tests)
+#: this so few lanes converge apart that neither retiring them early
+#: nor merging their rounds buys back the three slice-loop programs
+#: that would still have to compile (the classic fused kernel also
+#: stays the bitwise-pinned reference path for the small parity tests)
 MIN_ITER_TASKS = 24
 
 
@@ -490,8 +490,10 @@ def compaction_enabled():
 
 def resolve_slice_iters(max_iter):
     """Iterations per slice of the compacted path: ``SKDIST_SLICE_ITERS``
-    when set, else ~1/8 of the iteration budget (floor 4 — slices much
-    shorter than that pay more dispatch than they save on a CPU mesh).
+    when set, else ~1/8 of the iteration budget (floor 4 — a slice
+    boundary costs a dispatch and a flags gather for every live round,
+    2.5 ms a round on the v5e, and shorter slices pay that more often
+    than lanes finish).
     """
     env = os.environ.get("SKDIST_SLICE_ITERS", "").strip()
     if env:
@@ -530,13 +532,52 @@ def iterative_fit_supported(backend, est_cls, n_tasks, max_iter):
     return n_slice
 
 
-def iterative_chunk_size(n_tasks, n_slots, target_rounds=8):
-    """Default round size of the compacted path: aim for about
-    ``target_rounds`` slot-aligned rounds so live-task compaction has
-    rounds to merge (one big round can never shrink), without paying
-    per-round dispatch overhead for hundreds of tiny rounds."""
-    chunk = max(n_slots, -(-n_tasks // target_rounds))
-    return int(math.ceil(chunk / n_slots) * n_slots)
+def _iterative_chunk(n_tasks, n_slots, shared_bytes, lane_bytes, lanes_fit,
+                     target_rounds=8):
+    """``(chunk, basis)`` of :func:`iterative_chunk_size`: the round
+    size and the name of the rule that set it."""
+    per_slot = -(-n_tasks // n_slots)
+    amortise = -(-int(shared_bytes) // max(1, int(lane_bytes)))
+    merge = -(-(-(-n_tasks // target_rounds)) // n_slots)
+    want = max(1, amortise, merge)
+    fit = per_slot if lanes_fit is None else max(1, lanes_fit // n_slots)
+    if fit < min(per_slot, want):
+        basis = "memory"
+    elif want >= per_slot:
+        basis = "all_tasks"
+    elif amortise > merge:
+        basis = "amortised"
+    else:
+        basis = "target_rounds"
+    width = min(per_slot, fit, want)
+    # as many rounds as that width asks for, evenly filled
+    width = -(-per_slot // -(-per_slot // width))
+    return int(width * n_slots), basis
+
+
+def iterative_chunk_size(n_tasks, n_slots, shared_bytes=0, lane_bytes=0,
+                         lanes_fit=None, target_rounds=8):
+    """Default round size of the compacted path, from the dispatch's
+    own shapes.
+
+    Every pass of a solver step reads the shared operands once per
+    ROUND, whatever the round's width, so cutting a task set into more
+    rounds is free only where a round's cost follows its lanes: a round
+    grows until its lanes' own bytes (``lane_bytes`` each: task slice,
+    carry, temporaries) match ``shared_bytes``, or it holds every task.
+    ``lanes_fit`` — the lanes device memory holds, None where the
+    device reports none — caps it. Where lanes outweigh the shared
+    operands the answer is what it always was: about ``target_rounds``
+    rounds, so live-task compaction has rounds to merge without paying
+    dispatch for hundreds of tiny ones. Rounds are multiples of
+    ``n_slots``.
+
+    A round that holds every task never shrinks as its lanes finish —
+    there is no other round to merge it with — and where the shared
+    read bounds a pass that costs nothing: 41 live lanes of 50 take the
+    time 50 take."""
+    return _iterative_chunk(n_tasks, n_slots, shared_bytes, lane_bytes,
+                            lanes_fit, target_rounds)[0]
 
 
 class LocalBackend(TaskBackend):
@@ -627,17 +668,15 @@ class LocalBackend(TaskBackend):
         slice/compact/finalize loop as the mesh backend, single task
         slot."""
         n_tasks = _leading_dim(task_args)
-        chunk = (
-            min(n_tasks, round_size) if round_size
-            else iterative_chunk_size(n_tasks, 1)
-        )
         plan = self.prepare_batched_iterative(
             spec, shared_args, static_args, shared_specs, cache_key
         )
         return _dispatch_iterative(
             self, plan, spec, task_args, shared_args, static_args,
-            shared_specs, n_tasks, chunk, return_timings, cache_key,
-            on_round=on_round, rung=rung,
+            shared_specs, n_tasks,
+            _size_iterative_round(self, plan, task_args, n_tasks,
+                                  round_size),
+            return_timings, cache_key, on_round=on_round, rung=rung,
         )
 
     def batched_map(self, kernel, task_args, shared_args=(), static_args=None,
@@ -1074,7 +1113,6 @@ class TPUBackend(TaskBackend):
         exhaustive: the rung is reset, never applied)."""
         self.elastic_regrow_check()
         n_tasks = _leading_dim(task_args)
-        d = self.n_devices
         if self._spans_processes():
             return TaskBackend.batched_map_iterative(
                 self, spec, task_args, shared_args,
@@ -1082,17 +1120,15 @@ class TPUBackend(TaskBackend):
                 shared_specs=shared_specs, return_timings=return_timings,
                 cache_key=cache_key, on_round=on_round, rung=rung,
             )
-        if round_size:
-            chunk = int(math.ceil(min(n_tasks, round_size) / d) * d)
-        else:
-            chunk = iterative_chunk_size(n_tasks, d)
         plan = self.prepare_batched_iterative(
             spec, shared_args, static_args, shared_specs, cache_key
         )
         return _dispatch_iterative(
             self, plan, spec, task_args, shared_args, static_args,
-            shared_specs, n_tasks, chunk, return_timings, cache_key,
-            on_round=on_round, rung=rung,
+            shared_specs, n_tasks,
+            _size_iterative_round(self, plan, task_args, n_tasks,
+                                  round_size),
+            return_timings, cache_key, on_round=on_round, rung=rung,
         )
 
     def _mesh_min_int(self, value):
@@ -2222,10 +2258,12 @@ def _pad_tail(tree, pad):
 
 
 def _dispatch_iterative(backend, plan, spec, task_args, shared_args,
-                        static_args, shared_specs, n_tasks, chunk,
+                        static_args, shared_specs, n_tasks, sizing,
                         return_timings, cache_key, on_round=None,
                         rung=None):
-    """Run the compacted loop with two safety nets. A
+    """Run the compacted loop at ``sizing`` (the ``(chunk, chunk_basis,
+    lanes_fit)`` of :func:`_size_iterative_round`, the last two booked
+    into the stats as they are) with two safety nets. A
     RESOURCE_EXHAUSTED anywhere (a compacted round's carries do not fit,
     or the finalize pass trips the round loop's OOM machinery) downgrades
     to a plain ``batched_map`` of the spec's fallback kernel at the same
@@ -2238,9 +2276,11 @@ def _dispatch_iterative(backend, plan, spec, task_args, shared_args,
     it is bitwise identical (the slice loop is deterministic). When the
     budget is spent, the classic fallback kernel (which retries per
     round) is the last resort before failing loud."""
+    chunk, chunk_basis, lanes_fit = sizing
     stats = backend.last_round_stats = obs_metrics.new_round_stats(
         tasks=int(n_tasks),
         shared_bytes=int(backend.last_shared_bytes or 0),
+        chunk_basis=chunk_basis, lanes_fit=lanes_fit,
     )
     t0 = time.perf_counter()
     retry = _RetryState()
@@ -2687,6 +2727,129 @@ def _compacted_slice_loop(spec, put, init_exec, step_exec, score_exec,
     for name, key in zip(("iters", "fevals"), spec.count_keys):
         stats[name] = fin_store[key].tolist()
     return {k: fin_store[k] for k in spec.finalize_keys}
+
+
+#: width the init program is traced at to read a lane's footprint: a
+#: prime no data dimension is likely to share, so a traced value is a
+#: lane's own exactly when one of its dimensions is a multiple of it
+_PROBE_LANES = 8191
+
+#: ``_lane_footprint`` results per (init entry, shared shapes, task
+#: shapes, slots): a fit after the first pays a dict lookup
+_LANE_FOOTPRINTS = {}
+
+
+def _lane_footprint(plan, task_args):
+    """``(resident, transient, fixed)`` bytes of the compacted path's
+    programs, read from the dispatch's own trees before anything is
+    compiled: what ONE lane keeps on its device between slices (its
+    task slice and its carry), what it adds while its round runs (the
+    carry a step writes beside the one it reads, and the largest value
+    computed from the lane's data, twice: some operation reads one that
+    size and writes another), and the largest value the program derives
+    from the shared operands alone (a copy with a ones column, say),
+    which it holds once whatever the round's width.
+
+    The carry and the two largest values come from one abstract trace
+    of the init program (it runs the same solver slice the step
+    program does) at :data:`_PROBE_LANES` lanes a slot; no data moves
+    and nothing is lowered. An entry that cannot be traced (a test
+    double) or whose trace fails leaves the task slice alone."""
+    import jax
+
+    task_sig = tuple((tuple(l.shape[1:]), str(l.dtype))
+                     for l in jax.tree_util.tree_leaves(task_args))
+    key = (plan.init_fn, plan._shared_sig, task_sig, plan.n_task_slots)
+    found = _LANE_FOOTPRINTS.get(key)
+    if found is None:
+        task_bytes = tree_nbytes(task_args) // max(
+            1, _leading_dim(task_args))
+        found = (task_bytes, 0, 0)
+        if hasattr(plan.init_fn, "trace"):
+            try:
+                carry, lane_top, shared_top = _traced_lane_bytes(
+                    plan.init_fn, plan.shared, task_args,
+                    _PROBE_LANES * plan.n_task_slots,
+                )
+                found = (task_bytes + carry, carry + 2 * lane_top,
+                         shared_top)
+            except Exception as exc:
+                faults.log_suppressed("_lane_footprint", exc)
+        _LANE_FOOTPRINTS[key] = found
+    return found
+
+
+def _traced_lane_bytes(init_fn, shared, task_args, width):
+    """One abstract trace of ``init_fn`` at ``width`` lanes: the bytes
+    of one lane's carry (the program's output), of the largest value
+    with a lane axis (a dimension that is a multiple of ``width``), per
+    lane, and of the largest value without one."""
+    import jax
+
+    def nbytes(aval):
+        return math.prod(aval.shape) * getattr(aval.dtype, "itemsize", 4)
+
+    traced = init_fn.trace(shared, jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(
+            (width,) + tuple(a.shape[1:]), a.dtype),
+        task_args,
+    ))
+    carry = sum(
+        nbytes(o) for o in jax.tree_util.tree_leaves(traced.out_info)
+    ) // width
+    lane_top = shared_top = 0
+    todo = [traced.jaxpr.jaxpr]
+    while todo:
+        for eqn in todo.pop().eqns:
+            for var in eqn.outvars:
+                if not hasattr(var.aval, "shape"):
+                    continue
+                if any(n and n % width == 0 for n in var.aval.shape):
+                    lane_top = max(lane_top, nbytes(var.aval) // width)
+                else:
+                    shared_top = max(shared_top, nbytes(var.aval))
+            # the bodies of loops, conditionals and calls
+            for param in eqn.params.values():
+                for sub in (param if isinstance(param, (tuple, list))
+                            else (param,)):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        todo.append(sub)
+    return carry, lane_top, shared_top
+
+
+def _size_iterative_round(backend, plan, task_args, n_tasks, round_size,
+                          headroom=0.85):
+    """``(chunk, chunk_basis, lanes_fit)`` of one compacted dispatch:
+    an explicit ``round_size`` slot-aligned as it always was, else
+    :func:`iterative_chunk_size` on the bytes ``plan`` placed
+    (``backend.last_shared_bytes``) and :func:`_lane_footprint`.
+    ``lanes_fit`` is the memory cap in lanes a round, read BEFORE the
+    first compile (a refused compile costs ~40 s and the compacted
+    path's OOM route ends in the classic fallback): what is free on the
+    device, at the headroom :func:`_aot_exec_fn` uses, less the
+    program's own copy of shared data and every lane's resident bytes —
+    all rounds' task slices and carries stay on the device between
+    slices, whatever the round size — over what the lanes of the
+    rounds in flight add. None where the device reports no memory
+    stats (CPU): the shapes alone decide there."""
+    d = plan.n_task_slots
+    if round_size:
+        chunk = int(math.ceil(min(n_tasks, round_size) / d) * d)
+        return chunk, "round_size", None
+    resident, transient, fixed = _lane_footprint(plan, task_args)
+    lanes_fit = None
+    free = backend._free_device_bytes()
+    if free is not None and free > 0:
+        room = int(free * headroom) - fixed - -(-n_tasks // d) * resident
+        lanes_fit = d * max(
+            1, room // max(1, _MAX_ROUNDS_IN_FLIGHT * transient)
+        )
+    chunk, basis = _iterative_chunk(
+        n_tasks, d, backend.last_shared_bytes or 0, resident + transient,
+        lanes_fit,
+    )
+    return chunk, basis, lanes_fit
 
 
 #: AOT executables live in compile_cache (keyed by (jit fn, shared

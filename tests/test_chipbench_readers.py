@@ -17,13 +17,16 @@ import pytest
 REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 sys.path.insert(0, REPO)
 
-from chipbench.readers import round_counts, span_seconds  # noqa: E402
+from chipbench.readers import (  # noqa: E402
+    lanes_per_round, round_counts, span_seconds,
+)
 from skdist_tpu.obs import trace as obs_trace  # noqa: E402
 
 NEW_METRICS = (
     "loss_evals_per_fit.search", "lbfgs_iters_per_fit.search",
     "live_lane_share_pct.search", "place_s_per_fit.search",
     "refit_s_per_fit.search", "search_host_s_per_fit.search",
+    "lanes_per_round.search",
 )
 
 
@@ -75,6 +78,17 @@ def test_round_counts_nothing_to_read(stats):
     assert round_counts.read(ctx, key="fevals") is None
     assert round_counts.read(
         ctx, num="live_lane_slots", den="lane_slots") is None
+
+
+@pytest.mark.parametrize("stats, want", [
+    (dict(COUNTED, rounds=2), 8.0),            # finalize's round left out
+    ({"lane_slots": 378, "rounds": 54}, 7.0),  # the parent: rounds of 7
+    ({"rounds": 3, "dispatch_s": 0.5}, None),  # a program without it
+    ({"lane_slots": None, "rounds": 3}, None),
+    (None, None),
+])
+def test_lanes_per_round(stats, want):
+    assert lanes_per_round.read(_ctx([_fit(stats)])) == want
 
 
 def test_round_counts_no_unit_completed():
@@ -226,6 +240,7 @@ def test_span_seconds_on_a_real_traced_fit(ring):
         2 * round_counts.read(ctx, key="iters") + 1)
     assert 0 < round_counts.read(
         ctx, num="live_lane_slots", den="lane_slots") <= 100
+    assert lanes_per_round.read(ctx) == stats["chunk"]
 
 
 # ---------------------------------------------------------------------------
@@ -243,14 +258,14 @@ def test_new_metric_resolves_to_a_reader_and_an_entry(name):
                            name + ".json")) as f:
         spec = json.load(f)
     reader = importlib.import_module("chipbench.readers." + spec["reader"])
-    assert reader in (round_counts, span_seconds)
+    assert reader in (round_counts, span_seconds, lanes_per_round)
     entry = {m["name"]: m for m in _bench()["per_layer"]}[name]
     assert set(entry) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
     assert entry["workloads"] == ["search-epsilon"]
     assert entry["moves"] == "search_fits_per_s"
-    assert entry["source"] == ("program_counter" if reader is round_counts
-                               else "program_span")
+    assert entry["source"] == ("program_span" if reader is span_seconds
+                               else "program_counter")
     # the reader takes the file's arguments, and finds nothing to read
     # in a window of a program that measures none of this
     empty = {"fits": [{"stats": {"rounds": 1}, "units": 1, "failed": 0}],
@@ -269,4 +284,5 @@ def test_every_metric_file_has_its_entry_and_reader():
         assert os.path.exists(os.path.join(
             REPO, "chipbench", "readers", spec["reader"] + ".py"))
     # the new entries were appended: the accepted ones keep their places
-    assert [m["name"] for m in bench["per_layer"]][-6:] == list(NEW_METRICS)
+    assert [m["name"] for m in bench["per_layer"]][
+        -len(NEW_METRICS):] == list(NEW_METRICS)

@@ -245,16 +245,114 @@ def test_iterative_compacts_rounds(tpu_backend):
     """On a convergence-skewed task set the round count must shrink as
     lanes retire (the whole point of live-task compaction)."""
     spec, _fallback, shared, tasks = _toy_spec_and_tasks()
-    # default chunk for 37 tasks on 8 slots is also 8, so this reuses
-    # the programs test_iterative_bitwise_at_equal_chunk compiled
+    # rounds of 8, stated (the rule's own answer is
+    # test_round_size_rule's matter): this reuses the programs
+    # test_iterative_bitwise_at_equal_chunk compiled
     tpu_backend.batched_map_iterative(
-        spec, tasks, shared, cache_key=("tc", "iter", "TPUBackend"),
+        spec, tasks, shared, round_size=8,
+        cache_key=("tc", "iter", "TPUBackend"),
     )
     stats = tpu_backend.last_round_stats
     rps = stats["rounds_per_slice"]
     assert stats["compactions"] >= 1
     assert rps[-1] < rps[0]
     assert sum(stats["retired_per_slice"]) == 37
+
+
+# the cell of the benchmark: 50 fits over a 3.2 GB matrix, a lane 3.5 MB
+_WIDE = dict(shared_bytes=3_219_200_000, lane_bytes=3_552_302)
+
+
+@pytest.mark.parametrize("n_tasks, n_slots, sizes, want", [
+    # a wide shared operand under few small lanes: one read for all
+    (50, 1, _WIDE, (50, "all_tasks")),
+    (50, 4, _WIDE, (52, "all_tasks")),
+    (3, 8, {}, (8, "all_tasks")),
+    # ... under many: as many lanes as weigh what the operand weighs
+    # (907), in rounds evenly filled
+    (2000, 1, _WIDE, (667, "amortised")),
+    (2000, 8, _WIDE, (2000, "all_tasks")),  # every slot reads its copy
+    # lanes whose own bytes dominate: about eight rounds, value for
+    # value what the rule gave before it read any bytes
+    (37, 8, {}, (8, "target_rounds")),
+    (480, 1, {}, (60, "target_rounds")),
+    (480, 1, dict(shared_bytes=185_911_648, lane_bytes=20_976_766),
+     (60, "target_rounds")),
+    (480, 8, dict(shared_bytes=185_911_648, lane_bytes=20_976_766),
+     (72, "amortised")),  # 9 lanes a slot weigh the matrix; 8 would merge
+    (24, 1, dict(shared_bytes=1000, lane_bytes=4_000_000),
+     (3, "target_rounds")),
+    # a memory cap under all of these: slot-aligned rounds below it
+    (50, 1, dict(_WIDE, lanes_fit=20), (17, "memory")),
+    (50, 4, dict(_WIDE, lanes_fit=22), (20, "memory")),
+    (480, 1, dict(lanes_fit=33), (32, "memory")),
+    (480, 8, dict(lanes_fit=3), (8, "memory")),
+    # ... and a cap that does not bind leaves the answer alone
+    (50, 4, dict(_WIDE, lanes_fit=1237), (52, "all_tasks")),
+    (480, 1, dict(lanes_fit=383), (60, "target_rounds")),
+])
+def test_round_size_rule(n_tasks, n_slots, sizes, want):
+    """``iterative_chunk_size``: a round as wide as amortises the read
+    of the shared operands, capped by memory, and otherwise the eight
+    rounds compaction merges (ISSUE 27)."""
+    from skdist_tpu.parallel import iterative_chunk_size
+    from skdist_tpu.parallel.backend import _iterative_chunk
+
+    chunk, basis = _iterative_chunk(
+        n_tasks, n_slots, sizes.get("shared_bytes", 0),
+        sizes.get("lane_bytes", 0), sizes.get("lanes_fit"))
+    assert (chunk, basis) == want
+    assert chunk % n_slots == 0
+    assert iterative_chunk_size(n_tasks, n_slots, **sizes) == chunk
+    # the old rule, where no bytes are read
+    assert iterative_chunk_size(n_tasks, n_slots) == int(
+        np.ceil(max(n_slots, -(-n_tasks // 8)) / n_slots) * n_slots)
+
+
+@pytest.mark.parametrize("how, want", [
+    # 37 lanes over 90 x 7 floats, a lane's carry and row-sized
+    # temporaries about 2 KB: two lanes a slot weigh the shared 3 KB,
+    # which on eight slots passes the eight-round answer (8) and on one
+    # slot does not (5)
+    ("rule", {8: (16, "amortised", None), 1: (5, "target_rounds", None)}),
+    ("round_size", {8: (24, "round_size", None),
+                    1: (20, "round_size", None)}),
+    # a device that says what it has free: the cap is booked, and binds
+    # where the program's footprint passes it
+    ("roomy", {8: (16, "amortised", "some"),
+               1: (5, "target_rounds", "some")}),
+    ("tight", {8: (8, "memory", 8), 1: (1, "memory", 1)}),
+])
+def test_backend_books_round_sizing(how, want, monkeypatch):
+    """Both backends size a compacted dispatch through
+    ``_size_iterative_round`` and book ``chunk_basis`` / ``lanes_fit``
+    beside ``chunk``; the answers do not depend on the round size
+    beyond f32 noise."""
+    spec, _fallback, shared, tasks = _toy_spec_and_tasks()
+    ref = None
+    for make_backend in (TPUBackend, LocalBackend):
+        bk = make_backend()
+        if how in ("roomy", "tight"):
+            monkeypatch.setattr(
+                bk, "_free_device_bytes",
+                lambda: 1 << 30 if how == "roomy" else 4096,
+                raising=False)
+        out = bk.batched_map_iterative(
+            spec, tasks, shared,
+            round_size=20 if how == "round_size" else None,
+            cache_key=("tc", "iter", make_backend.__name__),
+        )
+        stats = bk.last_round_stats
+        chunk, basis, fit = want[bk.n_task_slots]
+        assert stats["mode"] == "compacted"
+        assert (stats["chunk"], stats["chunk_basis"]) == (chunk, basis)
+        if fit == "some":
+            assert stats["lanes_fit"] > 37
+        else:
+            assert stats["lanes_fit"] == fit
+        if ref is None:
+            ref = out
+        np.testing.assert_allclose(ref["W"], out["W"], atol=1e-5)
 
 
 def test_iterative_oom_falls_back_to_classic(monkeypatch):
@@ -356,6 +454,51 @@ def test_search_compacted_matches_classic_and_generic(clf_data, monkeypatch):
         atol=1e-5,
     )
     assert compacted.best_params_ == classic.best_params_
+
+
+def test_search_one_round_matches_eight():
+    """A search whose shared matrix outweighs its lanes runs as ONE
+    round holding every fit (ISSUE 27) and answers what the same search
+    answers in eight rounds; the stats say which rule sized the round."""
+    from skdist_tpu.distribute.search import DistGridSearchCV
+    from skdist_tpu.models import LogisticRegression
+
+    rng = np.random.RandomState(3)
+    X = rng.normal(size=(4000, 96)).astype(np.float32)
+    y = (X @ rng.normal(size=96) + rng.normal(size=4000) > 0).astype(int)
+
+    def search(backend, partitions):
+        return DistGridSearchCV(
+            LogisticRegression(max_iter=40, engine="xla"),
+            {"C": [float(c) for c in np.logspace(-3, 2, 8)]},
+            backend=backend, cv=3, scoring="neg_log_loss",
+            partitions=partitions,
+        ).fit(X, y)
+
+    one_device = jax.devices()[:1]
+    bk = TPUBackend(devices=one_device)
+    one = search(bk, "auto")
+    stats = bk.last_round_stats
+    assert stats["mode"] == "compacted"
+    assert (stats["chunk"], stats["chunk_basis"]) == (24, "all_tasks")
+    assert stats["lanes_fit"] is None  # the CPU reports no memory
+    assert stats["rounds"] == stats["slices"]
+    assert stats["compactions"] == 0
+    assert stats["lane_slots"] == stats["chunk"] * stats["rounds"]
+    assert 0 < stats["live_lane_slots"] < stats["lane_slots"]
+
+    bk8 = TPUBackend(devices=one_device)
+    eight = search(bk8, 8)
+    stats8 = bk8.last_round_stats
+    assert (stats8["chunk"], stats8["chunk_basis"]) == (3, "round_size")
+    assert stats8["lane_slots"] == 3 * stats8["rounds"]
+    for key in ("mean_test_score", "split0_test_score",
+                "split2_test_score"):
+        np.testing.assert_allclose(
+            one.cv_results_[key], eight.cv_results_[key], atol=1e-5)
+    assert one.best_params_ == eight.best_params_
+    # the lanes did the same work in either shape
+    assert sum(stats["iters"]) == pytest.approx(sum(stats8["iters"]), rel=0.05)
 
 
 def test_cost_permutation_round_trip_pins_row_order(clf_data):

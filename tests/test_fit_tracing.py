@@ -198,9 +198,11 @@ def _search_data(seed=0, n=400, d=12):
 
 
 def _small_search(backend, Cs, cv=4, max_iter=50):
+    # eight rounds, stated: these tests are about what a fit books, not
+    # about how the backend sizes a round by itself
     return DistGridSearchCV(
         LogisticRegression(max_iter=max_iter, engine="xla"), {"C": Cs},
-        backend=backend, cv=cv, scoring="neg_log_loss",
+        backend=backend, cv=cv, scoring="neg_log_loss", partitions=8,
     )
 
 

@@ -167,26 +167,104 @@ def _cv_step_program(n, d, k, n_lanes):
         "split": f32((n_lanes,), jnp.int32),
     }
     carry = jax.eval_shape(init_fn, shared, task)
-    return step_fn, shared, task, carry
+    return step_fn, shared, task, carry, init_fn
 
 
-def test_lbfgs_cv_step_compiles_and_fits_hbm(sds):
-    """11,314 x 4,096, 20 classes, at the round the backend picks for
-    the 480-fit headline on one device: ``iterative_chunk_size(480, 1)``
-    lanes. lanes x (n, k) softmax temporaries and the L-BFGS history
-    are the likely limit, so read the footprint against 16 GB."""
-    from skdist_tpu.parallel.backend import iterative_chunk_size
+@pytest.mark.parametrize("n, d, k, n_tasks, want_lanes", [
+    # the 480-fit text proxy on one device: eight rounds of 60 lanes;
+    # lanes x (n, k) softmax temporaries and the L-BFGS history are the
+    # likely limit
+    (11_314, 4096, 20, 480, 60),
+    # the benchmark's cell: all 50 lanes in one round over the 3.2 GB
+    # matrix, and the program's own copy of it with the ones column
+    (400_000, 2000, 2, 50, 50),
+])
+def test_lbfgs_cv_step_compiles_and_fits_hbm(sds, n, d, k, n_tasks,
+                                             want_lanes):
+    """The step slice of the compacted search at the round the backend
+    picks on one device, read against 16 GB."""
+    from skdist_tpu.parallel.backend import (
+        IterativePlan, _size_iterative_round, tree_nbytes,
+    )
 
-    n_lanes = iterative_chunk_size(480, 1)
-    assert n_lanes == 60
-    step_fn, shared, task, carry = _cv_step_program(
-        11_314, 4096, 20, n_lanes)
+    step_fn, shared, task, carry, init_fn = _cv_step_program(
+        n, d, k, n_tasks)
+
+    class Chip:
+        last_shared_bytes = tree_nbytes(shared)
+
+        def _free_device_bytes(self):
+            return HBM_BYTES - self.last_shared_bytes
+
+    n_lanes, _, lanes_fit = _size_iterative_round(
+        Chip(), IterativePlan(init_fn, step_fn, None, None, shared, None),
+        task, n_tasks, None)
+    assert n_lanes == want_lanes <= lanes_fit
+    task, carry = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct((n_lanes,) + a.shape[1:], a.dtype),
+        (task, carry))
     shared, task, carry = (_on_chip(t, sds) for t in (shared, task, carry))
     # the step slice is the program the search spends its time in; the
     # init slice and the finalize were rehearsed with it and are smaller
     compiled = step_fn.lower(
         shared, {"task": task, "carry": carry}).compile()
     assert _device_bytes(compiled) < 0.85 * HBM_BYTES
+
+
+@pytest.mark.parametrize("n, d, k, n_tasks, free, want", [
+    # epsilon (the benchmark's cell): 3.2 GB shared, a lane a few MB
+    (400_000, 2000, 2, 50, None, (50, "all_tasks")),
+    (400_000, 2000, 2, 50, HBM_BYTES - 3_300_000_000, (50, "all_tasks")),
+    # the same matrix under a grid of 2,000 fits: as many lanes as
+    # weigh what the matrix weighs, not all, and not 250
+    (400_000, 2000, 2, 2000, None, (None, "amortised")),
+    # the 480-fit text proxy: a lane's history outweighs its share of
+    # the 185 MB matrix, so eight rounds to merge, as ever
+    (11_314, 4096, 20, 480, None, (60, "target_rounds")),
+    (11_314, 4096, 20, 480, HBM_BYTES - 200_000_000,
+     (60, "target_rounds")),
+    # ... and on a device with 6 GB left, what fits beside the 480
+    # carries that stay resident whatever the round size
+    (11_314, 4096, 20, 480, 6_000_000_000, (None, "memory")),
+])
+def test_round_size_rule_on_real_programs(n, d, k, n_tasks, free, want):
+    """The compacted path's round size, from an abstract trace of the
+    search's own init program at the two shapes ISSUE 27 pins (nothing
+    compiles, so no topology is needed)."""
+    from skdist_tpu.parallel.backend import (
+        IterativePlan, _lane_footprint, _size_iterative_round, tree_nbytes,
+    )
+
+    step_fn, shared, task, _, init_fn = _cv_step_program(n, d, k, n_tasks)
+    plan = IterativePlan(init_fn, step_fn, None, None, shared, None)
+
+    class Device:
+        last_shared_bytes = tree_nbytes(shared)
+
+        def _free_device_bytes(self):
+            return free
+
+    chunk, basis, lanes_fit = _size_iterative_round(
+        Device(), plan, task, n_tasks, None)
+    assert basis == want[1]
+    assert chunk == (want[0] or chunk)
+    assert (lanes_fit is None) == (free is None)
+    resident, transient, fixed = _lane_footprint(plan, task)
+    # the carry: two histories of ten vectors; the temporaries: a lane's
+    # row-sized values; the fixed part: X with its ones column
+    width = (d + 1) * (1 if k == 2 else k)
+    assert 80 * width <= resident <= 120 * width
+    assert transient - resident >= 4 * n * (1 if k == 2 else k)
+    assert fixed == 4 * n * (d + 1)
+    if basis == "amortised":
+        # as many rounds as lanes weighing the matrix would make,
+        # evenly filled
+        assert n_tasks // 8 < chunk < n_tasks
+        lanes = -(-Device.last_shared_bytes // (resident + transient))
+        rounds = -(-n_tasks // lanes)
+        assert chunk == -(-n_tasks // rounds)
+    if basis == "memory":
+        assert 8 <= chunk <= lanes_fit < 60
 
 
 def test_matmul_tree_level_step_compiles(sds):
