@@ -755,6 +755,9 @@ class DistBaseSearchCV(BaseEstimator):
         # the artifact is finalized)
         self._adaptive_engaged_ = False
         self._rung_killed_gids_ = {}
+        # the packed form of X, where the batched path packed one: the
+        # refit takes it instead of packing the same matrix again
+        self._packed_X_ = None
         check_estimator_backend(self, self.verbose)
         backend = resolve_backend(self.backend, n_jobs=self.n_jobs)
         estimator = self.estimator
@@ -844,11 +847,12 @@ class DistBaseSearchCV(BaseEstimator):
         if self.refit:
             best = clone(estimator).set_params(**self.best_params_)
             refit_start = time.perf_counter()
+            refit_X = X if self._packed_X_ is None else self._packed_X_
             with obs_trace.span("refit"):
                 if y is not None:
-                    best.fit(X, y, **fit_params)
+                    best.fit(refit_X, y, **fit_params)
                 else:
-                    best.fit(X, **fit_params)
+                    best.fit(refit_X, **fit_params)
             self.refit_time_ = time.perf_counter() - refit_start
             self.best_estimator_ = best
             if self.preds:
@@ -860,6 +864,7 @@ class DistBaseSearchCV(BaseEstimator):
         # estimator.sc`, search.py:568-570 — a footgun we avoid: the
         # user's own estimator object keeps its backend)
         self.estimator = clone(self.estimator)
+        del self._packed_X_
         strip_runtime(self)
         return self
 
@@ -1105,6 +1110,7 @@ class DistBaseSearchCV(BaseEstimator):
             _freeze, annotate_round_kernel_mode, extract_aux,
             fit_would_pack, hyper_float, prepare_fit_X,
         )
+        from ..sparse import is_packed
         import jax.numpy as jnp
 
         if prefers_host_engine(backend, estimator) and (
@@ -1134,6 +1140,8 @@ class DistBaseSearchCV(BaseEstimator):
                 X_arr = prepare_fit_X(X, estimator)
             except Exception:
                 return None
+            if is_packed(X_arr):
+                self._packed_X_ = X_arr
             n = X_arr.shape[0]
             train_masks = np.zeros((n_splits, n), dtype=np.float32)
             test_masks = np.zeros((n_splits, n), dtype=np.float32)

@@ -29,6 +29,7 @@ from ..base import BaseEstimator, ClassifierMixin, RegressorMixin
 from ..sparse import (
     LinearOperator,
     PackedX,
+    is_packed,
     matvec_any,
     pack_for_fit,
     resolve_matvec_mode,
@@ -89,6 +90,9 @@ def prepare_fit_X(X, est=None):
         est if isinstance(est, type)
         else (type(est) if est is not None else None)
     )
+    if is_packed(X):
+        # already packed (a search hands its refit the X it packed)
+        return X
     if cls is None or getattr(cls, "_supports_packed_X", False):
         packed = pack_for_fit(X)
         if packed is not None:
@@ -123,8 +127,8 @@ def host_stage(x):
     transfer to the placement layer, where sharding and the opt-in
     reuse cache live.
     """
-    if isinstance(x, PackedX):
-        return PackedX(host_stage(x.idx), host_stage(x.val), x.n_cols)
+    if is_packed(x):
+        return jax.tree_util.tree_map(host_stage, x)
     if hasattr(x, "sharding"):  # already a jax array: leave it be
         return x
     return np.asarray(x)
@@ -270,9 +274,18 @@ def _annotate_x_meta(meta, X):
     """Record the fit-data representation in ``meta`` — consumed by the
     kernel builders (packed vs dense problems) and by
     :func:`_meta_signature` (structural compile keys)."""
-    if isinstance(X, PackedX):
+    if is_packed(X):
+        # a bucketed X is its own treedef, so its programs never share
+        # a cache entry with a padded pair's; the counts are for the
+        # round stats (:func:`annotate_round_kernel_mode`)
         meta["x_format"] = "packed"
         meta["x_matvec"] = resolve_matvec_mode()
+        if isinstance(X, PackedX):
+            meta["x_nnz"] = int(np.count_nonzero(np.asarray(X.val)))
+            meta["x_slots"] = int(np.prod(X.idx.shape))
+        else:
+            # both orientations of the buckets are placed, and counted
+            meta["x_nnz"], meta["x_slots"] = X.placed, X.slots
     return meta
 
 
@@ -313,6 +326,9 @@ def annotate_round_kernel_mode(backend, meta):
     stats = getattr(backend, "last_round_stats", None)
     if isinstance(stats, dict):
         mode = stats["kernel_mode"] = kernel_mode_of(meta)
+        for key in ("x_nnz", "x_slots", "x_matvec"):
+            if key in meta:
+                stats[key] = meta[key]
         from ..obs import metrics as obs_metrics
 
         obs_metrics.counter("rounds.kernel_mode").inc(
@@ -428,7 +444,7 @@ class _LinearModelBase(BaseEstimator):
         else:
             X = prepare_fit_X(X, type(self))
         warm = coef_init is not None or intercept_init is not None
-        if not isinstance(X, PackedX) and self._resolve_host_engine():
+        if not is_packed(X) and self._resolve_host_engine():
             if warm:
                 # the host engines already honour a flat `_warm_w0`
                 # seed (the warm C-path runner's seam); scoped so a
@@ -1042,6 +1058,31 @@ class LogisticRegression(_LbfgsFitMixin, _LinearClassifierBase):
                 if unpenalized:
                     return ce
                 return ce + reg_loss(wflat)
+
+            if op.bx is not None:
+                def ray(wflat, dflat):
+                    """``t -> loss(wflat + t * dflat)`` from two packed
+                    products: the logits are linear in the weights, so
+                    a trial step costs a pass over (n, k) logits, not
+                    over the matrix (a round of lanes runs the halvings
+                    of its slowest lane: at 130,107 columns each was a
+                    gather of every stored element)."""
+                    with jax.named_scope("lr/ray"):
+                        z0 = matvec(wflat.reshape(p, k))
+                        dz = matvec(dflat.reshape(p, k))
+
+                    def along(t):
+                        logits = z0 + t * dz
+                        lse = jax.nn.logsumexp(logits, axis=1)
+                        ce = jnp.sum(
+                            sw * (lse - jnp.sum(onehot * logits, axis=1)))
+                        if unpenalized:
+                            return ce
+                        return ce + reg_loss(wflat + t * dflat)
+
+                    return along
+
+                loss.ray = ray
 
             w0 = jnp.zeros(p * k, op.dtype)
 

@@ -55,7 +55,10 @@ _EPS = 1e-12
 #: t0, one per line-search halving, and the value-and-gradient at the
 #: accepted point) — the work count that tells a change of speed from a
 #: change of work. Under ``vmap`` a round EXECUTES the halvings of its
-#: slowest lane; each lane still counts its own.
+#: slowest lane; each lane still counts its own. Where the loss offers
+#: ``ray(w, d)`` a halving is no pass over the data and an iteration
+#: counts 3 (the ray's two products, the value-and-gradient): passes
+#: still, but no longer the evaluations the line search asked for.
 LBFGS_CARRY_KEYS = ("w", "f", "g", "S", "Y", "rho", "k", "it", "nfev",
                     "done")
 
@@ -110,10 +113,17 @@ def _lbfgs_body(fun, value_and_grad, max_iter, tol, history, max_ls):
 
         return -lax.fori_loop(0, m, fwd, r)
 
+    # a problem whose loss is a function of LINEAR products of w
+    # (``fun.ray(w, d) -> phi`` with ``phi(t) == fun(w + t * d)``) pays
+    # its products once a direction, not once a trial step
+    ray = getattr(fun, "ray", None)
+
     def line_search(w, f, g, d):
         """Armijo backtracking; returns (step, f_new, accepted,
         halvings)."""
         gd = jnp.dot(g, d)
+        along = ray(w, d) if ray is not None else (
+            lambda t: fun(w + t * d))
 
         def cond(carry):
             t, f_new, it = carry
@@ -123,10 +133,10 @@ def _lbfgs_body(fun, value_and_grad, max_iter, tol, history, max_ls):
         def body(carry):
             t, _, it = carry
             t = t * 0.5
-            return t, fun(w + t * d), it + 1
+            return t, along(t), it + 1
 
         t0 = 1.0
-        f1 = fun(w + t0 * d)
+        f1 = along(t0)
         t, f_new, n_halved = lax.while_loop(cond, body, (t0, f1, 0))
         ok = f_new <= f + 1e-4 * t * gd
         return t, f_new, ok, n_halved
@@ -173,8 +183,9 @@ def _lbfgs_body(fun, value_and_grad, max_iter, tol, history, max_ls):
         # answers "will more steps change this lane?" — what the
         # backend's flags-only compaction gather reads
         done_new = converged | stalled | (it + 1 >= max_iter)
-        # trial at t0 + the halvings + the value-and-gradient above
-        nfev_new = nfev + n_halved + 2
+        # trial at t0 + the halvings + the value-and-gradient above —
+        # or, along a ray, the two products of the ray and that one
+        nfev_new = nfev + (n_halved + 2 if ray is None else 3)
         return (w_new, f_new2, g_new, S, Y, rho, k_new, it + 1, nfev_new,
                 done_new)
 

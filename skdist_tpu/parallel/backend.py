@@ -2277,11 +2277,15 @@ def _dispatch_iterative(backend, plan, spec, task_args, shared_args,
     budget is spent, the classic fallback kernel (which retries per
     round) is the last resort before failing loud."""
     chunk, chunk_basis, lanes_fit = sizing
+    resident, transient = _lane_footprint(plan, task_args)[:2]
     stats = backend.last_round_stats = obs_metrics.new_round_stats(
         tasks=int(n_tasks),
         shared_bytes=int(backend.last_shared_bytes or 0),
+        lane_bytes=int(resident + transient),
         chunk_basis=chunk_basis, lanes_fit=lanes_fit,
     )
+    # where device memory set the size, one round's carry is resident
+    live_rounds = 1 if chunk_basis == "memory" else None
     t0 = time.perf_counter()
     retry = _RetryState()
     while True:
@@ -2294,7 +2298,7 @@ def _dispatch_iterative(backend, plan, spec, task_args, shared_args,
             out = _run_compacted(
                 plan, spec, task_args, n_tasks, chunk, stats,
                 pipeline=not backend.sync_rounds, on_round=on_round,
-                rung=rung,
+                rung=rung, live_rounds=live_rounds,
             )
             stats["retries"] = retry.total
             obs_metrics.publish_round_stats(stats)
@@ -2397,7 +2401,8 @@ def _flags_only_gather(leaf):
 
 
 def _run_compacted(plan, spec, task_args, n_tasks, chunk, stats,
-                   pipeline=True, on_round=None, rung=None):
+                   pipeline=True, on_round=None, rung=None,
+                   live_rounds=None):
     """The convergence-compacted slice loop.
 
     Phase 1 (iterate): partition the task axis into chunk-shaped rounds
@@ -2427,6 +2432,12 @@ def _run_compacted(plan, spec, task_args, n_tasks, chunk, stats,
     Dispatch depth is bounded at :data:`_MAX_ROUNDS_IN_FLIGHT` queued
     computations, same as the classic loop. Raises whatever the device
     raises on OOM (the caller downgrades to the classic path).
+
+    ``live_rounds`` caps the rounds whose carries are on the device at
+    once (None: all of them, the slice-major loop above). Where the
+    carries outweigh the device — 50 lanes of 229 MB — the rounds past
+    the cap wait their turn unstarted and enter as earlier ones retire,
+    so a round runs its slices to its end before the next begins.
     """
     depth = _MAX_ROUNDS_IN_FLIGHT if pipeline else 1
     put = plan.put
@@ -2457,7 +2468,9 @@ def _run_compacted(plan, spec, task_args, n_tasks, chunk, stats,
     with obs_trace.span("round_loop"):
         fin_carry = _compacted_slice_loop(
             spec, put, init_exec, step_exec, score_exec, task_args,
-            n_tasks, chunk, stats, depth, rung,
+            n_tasks, chunk, stats,
+            depth if live_rounds is None else min(depth, live_rounds),
+            rung, live_rounds,
         )
     # phase 2: finalize everything in ORIGINAL task order through the
     # ordinary round loop (same chunk shape -> same compiled program
@@ -2475,7 +2488,8 @@ def _run_compacted(plan, spec, task_args, n_tasks, chunk, stats,
 
 
 def _compacted_slice_loop(spec, put, init_exec, step_exec, score_exec,
-                          task_args, n_tasks, chunk, stats, depth, rung):
+                          task_args, n_tasks, chunk, stats, depth, rung,
+                          live_rounds=None):
     """Phase 1 of :func:`_run_compacted` (its ``round_loop`` span): the
     slices, the flags gathers, the rungs and the compactions. Books the
     loop's accounting into ``stats`` and returns the per-task store of
@@ -2489,6 +2503,11 @@ def _compacted_slice_loop(spec, put, init_exec, step_exec, score_exec,
         rounds.append(_LiveRound(
             np.arange(start, stop), _pad_tail(sl, chunk - (stop - start))
         ))
+
+    # rounds past the residency cap wait, unstarted and off the device
+    waiting = []
+    if live_rounds and len(rounds) > live_rounds:
+        rounds, waiting = rounds[:live_rounds], rounds[live_rounds:]
 
     stats.update({
         "mode": "compacted", "chunk": int(chunk), "slices": 0,
@@ -2658,16 +2677,18 @@ def _compacted_slice_loop(spec, put, init_exec, step_exec, score_exec,
             else:
                 still.append(r)
         # newly-finished lanes this slice (lanes already compacted out
-        # of the rounds were counted when they finished)
-        newly_retired = (n_tasks - n_alive) - n_done_prev
+        # of the rounds were counted when they finished; the lanes of
+        # waiting rounds are alive)
+        n_waiting = sum(len(r.idx) for r in waiting)
+        newly_retired = (n_tasks - n_alive - n_waiting) - n_done_prev
         stats["retired_per_slice"].append(newly_retired)
         if newly_retired and obs_trace.enabled():
             obs_trace.instant(
                 "lane_retire",
                 {"slice": int(stats["slices"]), "n": int(newly_retired)},
             )
-        n_done_prev = n_tasks - n_alive
-        if not still:
+        n_done_prev = n_tasks - n_alive - n_waiting
+        if not still and not waiting:
             break
         needed = -(-n_alive // chunk)
         if needed < len(still):
@@ -2711,6 +2732,8 @@ def _compacted_slice_loop(spec, put, init_exec, step_exec, score_exec,
                 rounds.append(r)
         else:
             rounds = still
+        while waiting and len(rounds) < live_rounds:
+            rounds.append(waiting.pop(0))
 
     # the converged schema's "rounds": the slice loop's actual device
     # dispatches (one per live round per slice; the finalize phase's
@@ -2832,23 +2855,38 @@ def _size_iterative_round(backend, plan, task_args, n_tasks, round_size,
     all rounds' task slices and carries stay on the device between
     slices, whatever the round size — over what the lanes of the
     rounds in flight add. None where the device reports no memory
-    stats (CPU): the shapes alone decide there."""
+    stats (CPU): the shapes alone decide there.
+
+    Where that cap binds (basis ``memory``) the carries of the rounds
+    that wait are what fills the device, so they stay off it: the loop
+    runs ONE round at a time, to its end (:func:`_compacted_slice_loop`'s
+    ``live_rounds``), and the same rule is asked again with the cap of
+    that loop — what ONE running round may hold, ``lanes_fit`` as
+    booked. On the v5e the 130,107-column text search (50 lanes of
+    229 MB) so runs eight rounds of 7, the ``target_rounds`` answer,
+    under a cap of 20. Whether fewer, wider rounds would be faster
+    there is not settled (five rounds of 10 and eight of 7 were never
+    read on one program and one data set: PERF.md, PR 28); nothing a
+    round does there is shared by its lanes, so the rule has no reason
+    to widen it. A running lane held 0.85 GB where ``resident +
+    transient`` reads 0.67 GB, so a cap that binds at its edge is
+    untried."""
     d = plan.n_task_slots
     if round_size:
         chunk = int(math.ceil(min(n_tasks, round_size) / d) * d)
         return chunk, "round_size", None
     resident, transient, fixed = _lane_footprint(plan, task_args)
-    lanes_fit = None
+    shared, lane = backend.last_shared_bytes or 0, resident + transient
     free = backend._free_device_bytes()
-    if free is not None and free > 0:
-        room = int(free * headroom) - fixed - -(-n_tasks // d) * resident
-        lanes_fit = d * max(
-            1, room // max(1, _MAX_ROUNDS_IN_FLIGHT * transient)
-        )
-    chunk, basis = _iterative_chunk(
-        n_tasks, d, backend.last_shared_bytes or 0, resident + transient,
-        lanes_fit,
-    )
+    if free is None or free <= 0:
+        return _iterative_chunk(n_tasks, d, shared, lane, None) + (None,)
+    room = int(free * headroom) - fixed
+    lanes_fit = d * max(1, (room - -(-n_tasks // d) * resident)
+                        // max(1, _MAX_ROUNDS_IN_FLIGHT * transient))
+    chunk, basis = _iterative_chunk(n_tasks, d, shared, lane, lanes_fit)
+    if basis == "memory":
+        lanes_fit = d * max(1, room // max(1, lane))
+        chunk, _ = _iterative_chunk(n_tasks, d, shared, lane, lanes_fit)
     return chunk, basis, lanes_fit
 
 

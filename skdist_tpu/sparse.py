@@ -29,14 +29,29 @@ batch prediction alike:
   block, then the MXU runs ordinary matmuls; H2D still ships only the
   packed pair) and :func:`packed_weighted_gram` (``XᵀSX`` via the m²
   scatter, for the closed-form ridge family).
+- :class:`BucketedX` — the representation of a matrix whose row
+  lengths are SKEWED (a vectorised text corpus: most documents near a
+  hundred distinct terms, a few in the thousands), where max-row
+  padding would bill every row for a handful of heavy ones: rows
+  bucketed by length into a few blocks of their own width, both
+  orientations placed (so ``X @ W`` and ``X.T @ r`` are the same
+  gather-and-row-sum, :func:`bucketed_matvec` / :func:`bucketed_rmatvec`,
+  and no scatter runs), the densest columns held as a dense head. The
+  buckets' cost follows nnz; the head's is ``n x h`` dense floats,
+  zeros and all (at 11,314 x 130,107 with 1.79 M stored elements:
+  15,229 columns, 689 MB, for the 78 % of the elements they hold). A
+  batch of weight matrices (``vmap``) rides on the gathers' contiguous
+  axis, not on an axis of its own. One contraction serves it in every
+  matvec mode but ``dense``: the Pallas rebuild kernels' work follows
+  ``n x d``, ten times the gathers' at that shape on a v5e.
 - routing (:func:`pack_for_fit`): pack exactly when packing wins.
   The padded pair costs ``n·m·8`` bytes vs ``n·d·4`` dense, so the
   decision is byte-driven (``d >= 2·m·savings``; savings default 4x,
-  see :data:`PACK_MIN_SAVINGS`) with an nnz-OUTLIER guard: a few rows
-  with huge nnz inflate ``m`` — and the padding bill — for every row,
-  so heavily skewed inputs fall back to the densify path rather than
-  pay max-row padding. ``SKDIST_SPARSE_FIT=0`` disables packing
-  entirely; ``=1``/``force`` packs any 2-D sparse input.
+  see :data:`PACK_MIN_SAVINGS`); rows of skewed length
+  (:data:`OUTLIER_FACTOR`) pack bucketed — there is no densify
+  fallback for skew, which at the widths where it arises cannot fit.
+  ``SKDIST_SPARSE_FIT=0`` disables packing entirely; ``=1``/``force``
+  packs any 2-D sparse input.
 - matvec-mode selection (:func:`resolve_matvec_mode`): ``gather`` vs
   ``dense`` (dense-matmul-on-packed) vs ``pallas`` (the on-chip
   kernels of ``ops/pallas_sparse.py``: both contractions recast as
@@ -59,6 +74,7 @@ The 1-tuple-shape special case of scipy's 1-D sparse arrays
 exactly as the dense path treats a 1-D ndarray.
 """
 
+import functools
 import json
 import os
 import threading
@@ -70,9 +86,12 @@ import jax.numpy as jnp
 
 __all__ = [
     "PackedX",
+    "BucketedX",
+    "is_packed",
     "is_sparse_2d",
     "max_nnz_per_row",
     "pack_csr_rows",
+    "pack_csr_buckets",
     "pack_decision",
     "would_pack",
     "pack_for_fit",
@@ -81,6 +100,9 @@ __all__ = [
     "packed_rmatvec",
     "packed_to_dense",
     "packed_weighted_gram",
+    "bucketed_matvec",
+    "bucketed_rmatvec",
+    "bucketed_to_dense",
     "matvec_any",
     "LinearOperator",
     "resolve_matvec_mode",
@@ -102,11 +124,31 @@ SPARSE_MATVEC_ENV = "SKDIST_SPARSE_MATVEC"
 PACK_MIN_SAVINGS = 4.0
 PACK_SAVINGS_ENV = "SKDIST_SPARSE_PACK_SAVINGS"
 
-#: nnz-outlier guard: when the max row nnz exceeds this multiple of the
-#: 95th percentile AND padding inflates the packed pair past the same
-#: multiple of the true nnz, the matrix is skew-pathological — max-row
-#: padding would bill every row for a handful of heavy ones
+#: nnz skew: when the max row nnz exceeds this multiple of the 95th
+#: percentile AND padding to it would inflate the packed pair past the
+#: same multiple of the true nnz, max-row padding would bill every row
+#: for a handful of heavy ones — such a matrix packs BUCKETED
+#: (:class:`BucketedX`: a dense head, and buckets whose cost follows nnz)
 OUTLIER_FACTOR = 4.0
+
+#: slots (row x packed column) one step of a bucketed contraction
+#: gathers: the gathered block is ``TILE_SLOTS x columns`` floats, so
+#: this bounds a contraction's temporaries whatever the matrix
+TILE_SLOTS = 16384
+
+#: merging neighbouring buckets may add this share of nnz in padding
+BUCKET_MERGE_SHARE = 0.03
+
+#: a column of a bucketed matrix at least this dense is held DENSE (the
+#: head: a few thousand frequent terms hold most of a corpus's stored
+#: elements): a dense column costs the MXU n multiply-adds a product
+#: column, a packed one a gathered row per stored element — on a v5e
+#: 2 to 10 ns each whatever the row's width (PERF.md) — which crosses
+#: near one element in a few hundred
+HEAD_MIN_DENSITY = 1.0 / 512
+#: ... while the head stays under this many bytes and an eighth of the
+#: columns
+HEAD_MAX_BYTES = 1 << 30
 
 _VALID_MATVEC_MODES = ("gather", "dense", "pallas")
 
@@ -173,6 +215,122 @@ jax.tree_util.register_pytree_node(
 )
 
 
+class BucketedX:
+    """A dense head of the densest columns plus packed buckets whose
+    cost follows nnz, for matrices with skewed row lengths (a few
+    documents of thousands of terms among thousands of a hundred):
+    rows sorted by nnz and cut into a few buckets, each a
+    padded block ``idx (tiles, rows, m_b) int32`` / ``val f32`` of its
+    own width ``m_b`` — a stack of tiles of at most :data:`TILE_SLOTS`
+    slots, the unit a contraction gathers at a time — so a row pays
+    padding only up to its bucket's width.
+
+    Both orientations are placed: ``rows`` holds X bucketed by row
+    length with ``inv (n,)`` — where each original row sits in the
+    concatenation of the buckets — and ``cols`` holds ``X.T`` bucketed
+    by column length with ``tinv (d,)``. ``X.T @ r`` is then the same
+    gather-and-row-sum over ``cols`` that ``X @ W`` is over ``rows``:
+    no scatter runs in either direction, and every product comes back
+    in the matrix's own row (column) order, so nothing outside this
+    module (fold masks, labels, weights) sees the permutation. Padding
+    entries are ``(0, 0.0)`` and padding rows all padding: exact.
+
+    The densest columns (:data:`HEAD_MIN_DENSITY`) are not in the
+    buckets: ``head (n, h) f32`` holds them dense, ``head_cols (h,)``
+    says which they are, and both products add a matmul over them to
+    the gathers over the rest (``head`` is None where no column is
+    that dense). The head is placed whole, zeros and all — ``n x h x
+    4`` bytes, capped by :data:`HEAD_MAX_BYTES` — so it, not nnz, is
+    most of what such a matrix weighs on the device. The density at
+    which a column joins it and that cap are one v5e's crossing, read
+    at one shape (PERF.md, PR 28).
+
+    A registered pytree: the leaves are the blocks, the permutations
+    and the head; the static treedef carries ``n_cols``, ``nnz`` and
+    the head's share of it.
+    """
+
+    __slots__ = ("rows", "inv", "cols", "tinv", "head", "head_cols",
+                 "n_cols", "nnz", "head_nnz")
+
+    def __init__(self, rows, inv, cols, tinv, head, head_cols, n_cols,
+                 nnz, head_nnz=0):
+        self.rows = tuple(tuple(b) for b in rows)
+        self.inv = inv
+        self.cols = tuple(tuple(b) for b in cols)
+        self.tinv = tinv
+        self.head, self.head_cols = head, head_cols
+        self.n_cols = int(n_cols)
+        self.nnz, self.head_nnz = int(nnz), int(head_nnz)
+
+    @property
+    def shape(self):
+        return (self.inv.shape[0], self.n_cols)
+
+    def __len__(self):
+        return int(self.inv.shape[0])
+
+    @property
+    def slots(self):
+        """Slots placed: the buckets' of both orientations, padding and
+        all, and the head's every entry."""
+        return sum(int(np.prod(i.shape)) for i, _ in self.rows + self.cols
+                   ) + (0 if self.head is None
+                        else int(np.prod(self.head.shape)))
+
+    @property
+    def placed(self):
+        """Stored elements placed: the buckets hold each twice."""
+        return 2 * (self.nnz - self.head_nnz) + self.head_nnz
+
+    @property
+    def nbytes(self):
+        return sum(int(leaf.nbytes)
+                   for leaf in jax.tree_util.tree_leaves(self))
+
+    @property
+    def dense_nbytes(self):
+        return int(self.shape[0]) * int(self.n_cols) * 4
+
+    def __repr__(self):  # pragma: no cover - debugging nicety
+        n, d = self.shape
+        return (f"BucketedX(n={n}, d={d}, nnz={self.nnz}, "
+                f"row widths={[i.shape[2] for i, _ in self.rows]}, "
+                f"head={None if self.head is None else self.head.shape}, "
+                f"{self.nbytes >> 10} KiB)")
+
+
+jax.tree_util.register_pytree_node(
+    BucketedX,
+    lambda x: ((x.rows, x.inv, x.cols, x.tinv, x.head, x.head_cols),
+               (x.n_cols, x.nnz, x.head_nnz)),
+    lambda aux, leaves: BucketedX(*leaves, *aux),
+)
+
+
+def _register_for_export():
+    """The export tier of the compile cache serialises a program's
+    argument treedefs; the static parts are small integers."""
+    from jax import export
+
+    for cls, name in ((PackedX, "skdist_tpu.PackedX"),
+                      (BucketedX, "skdist_tpu.BucketedX")):
+        export.register_pytree_node_serialization(
+            cls, serialized_name=name,
+            serialize_auxdata=lambda aux: json.dumps(aux).encode(),
+            deserialize_auxdata=lambda b: (
+                tuple(v) if isinstance(v := json.loads(b), list) else v),
+        )
+
+
+_register_for_export()
+
+
+def is_packed(X):
+    """Either packed representation of the sparse plane."""
+    return isinstance(X, (PackedX, BucketedX))
+
+
 # ---------------------------------------------------------------------------
 # host-side packing + routing
 # ---------------------------------------------------------------------------
@@ -221,12 +379,123 @@ def _pack_savings():
     return PACK_MIN_SAVINGS
 
 
+def _tiling(count, m):
+    """``(tiles, rows a tile)`` of a bucket of ``count`` rows of width
+    ``m``: as few tiles as hold it at :data:`TILE_SLOTS` slots a tile,
+    evenly filled (eight rows or more a tile a multiple of 8, the
+    sublane tile)."""
+    tiles = -(-count * int(m) // TILE_SLOTS)
+    rows = -(-count // tiles)
+    return tiles, rows if rows < 8 else -(-rows // 8) * 8
+
+
+def bucket_widths(nnz):
+    """``(widths, counts)`` of the buckets a vector of row lengths is
+    cut into, from the lengths alone: a ladder of two widths an octave
+    (1, 2, 4, 8, 12, 16, 24, 32, ...), every row in the narrowest bucket
+    that holds it, then the cheapest neighbours merged upward while all
+    merges together add under :data:`BUCKET_MERGE_SHARE` of nnz in
+    padding — so a handful of very long rows share one block instead
+    of compiling a loop each."""
+    nnz = np.asarray(nnz)
+    ladder = [1, 2, 4, 8]
+    while ladder[-1] < (int(nnz.max()) if nnz.size else 0):
+        ladder.append(ladder[-1] * 3 // 2 if ladder[-1] % 3 else
+                      ladder[-1] * 4 // 3)
+    which = np.searchsorted(ladder, nnz, side="left")
+    counts = np.bincount(which, minlength=len(ladder))
+    buckets = [[ladder[i], int(c)] for i, c in enumerate(counts) if c]
+    room = BUCKET_MERGE_SHARE * max(1, int(nnz.sum()))
+    while len(buckets) > 1:
+        costs = [c * (buckets[i + 1][0] - w)
+                 for i, (w, c) in enumerate(buckets[:-1])]
+        i = int(np.argmin(costs))
+        if costs[i] > room:
+            break
+        room -= costs[i]
+        buckets[i + 1][1] += buckets[i][1]
+        del buckets[i]
+    return [w for w, _ in buckets], [c for _, c in buckets]
+
+
+def _pack_buckets(X):
+    """One orientation of :func:`pack_csr_buckets`: ``(blocks, inv)``
+    of a CSR matrix — its rows stably sorted by length, cut at
+    :func:`bucket_widths`, each bucket a stack of tiles
+    ``(tiles, rows, m)`` (:func:`_tiling`) ending in all-padding rows."""
+    indptr = np.asarray(X.indptr)
+    indices, data = np.asarray(X.indices), np.asarray(X.data)
+    nnz = np.diff(indptr)
+    order = np.argsort(nnz, kind="stable")
+    widths, counts = bucket_widths(nnz)
+    inv = np.empty(len(nnz), np.int32)
+    blocks, lo, off = [], 0, 0
+    for m, count in zip(widths, counts):
+        rows = order[lo:lo + count]
+        tiles, tile = _tiling(count, m)
+        n_pad = tiles * tile
+        idx = np.zeros((n_pad, m), np.int32)
+        val = np.zeros((n_pad, m), np.float32)
+        mask = np.arange(m)[None, :] < nnz[rows][:, None]
+        pos = (indptr[rows][:, None] + np.arange(m)[None, :])[mask]
+        idx[:count][mask] = indices[pos]
+        val[:count][mask] = data[pos]
+        inv[rows] = off + np.arange(count, dtype=np.int32)
+        blocks.append((idx.reshape(tiles, tile, m),
+                       val.reshape(tiles, tile, m)))
+        lo, off = lo + count, off + n_pad
+    return tuple(blocks), inv
+
+
+def head_columns(X):
+    """The columns of a CSR matrix dense enough to hold dense
+    (:data:`HEAD_MIN_DENSITY`), densest first, as many as
+    :data:`HEAD_MAX_BYTES` and an eighth of the columns allow; sorted."""
+    n, d = X.shape
+    counts = np.bincount(np.asarray(X.indices), minlength=d)
+    dense = np.flatnonzero(counts >= max(2.0, n * HEAD_MIN_DENSITY))
+    room = int(min(HEAD_MAX_BYTES // max(1, 4 * n), d // 8))
+    dense = dense[np.argsort(-counts[dense], kind="stable")[:room]]
+    return np.sort(dense).astype(np.int32)
+
+
+def pack_csr_buckets(X):
+    """CSR → :class:`BucketedX`: the densest columns dense, the rest in
+    both orientations bucketed by length (``indptr`` and the column
+    counts alone decide)."""
+    import scipy.sparse as sp
+
+    X = X.tocsr()
+    head_cols = head_columns(X)
+    head, head_nnz, tail = None, 0, X
+    if head_cols.size:
+        in_head = np.zeros(X.shape[1], bool)
+        in_head[head_cols] = True
+        keep = ~in_head[np.asarray(X.indices)]
+        head_nnz = int(X.nnz - keep.sum())
+        head = np.asarray(X[:, head_cols].toarray(), np.float32)
+        kept = np.concatenate([[0], np.cumsum(keep)])[np.asarray(X.indptr)]
+        tail = sp.csr_matrix(
+            (np.asarray(X.data)[keep], np.asarray(X.indices)[keep], kept),
+            shape=X.shape)
+    else:
+        head_cols = None
+    rows, inv = _pack_buckets(tail)
+    cols, tinv = _pack_buckets(tail.T.tocsr())
+    return BucketedX(rows, inv, cols, tinv, head, head_cols, X.shape[1],
+                     X.nnz, head_nnz)
+
+
 def pack_decision(X):
     """Routing decision for a 2-D CSR input: ``(pack, reason, m)``.
 
     ``pack`` is True when the packed pair beats the dense matrix by at
     least :data:`PACK_MIN_SAVINGS` in device bytes (``n·m·8`` vs
-    ``n·d·4``) AND the nnz distribution is not outlier-skewed. All
+    ``n·d·4``, with ``m`` the width rows are padded to). Rows of skewed
+    length (:data:`OUTLIER_FACTOR`) pack bucketed, so their ``m`` is
+    the mean slots a row takes over its buckets, not the longest row —
+    reason ``"bucketed"``; there is no densify fallback for skew (at
+    the widths where it arises the dense matrix does not fit). All
     statistics come from ``indptr`` alone — no data is touched before
     the decision, so declining costs nothing.
     """
@@ -235,9 +504,18 @@ def pack_decision(X):
         return False, "disabled via " + SPARSE_FIT_ENV, None
     nnz = np.diff(np.asarray(X.indptr))
     m = max(1, int(nnz.max()) if nnz.size else 1)
+    n, d = X.shape
+    reason = "packed"
+    if n:
+        p95 = float(np.percentile(nnz, 95))
+        total = max(1, int(nnz.sum()))
+        if (m > OUTLIER_FACTOR * max(p95, 1.0)
+                and n * m > OUTLIER_FACTOR * total):
+            widths, counts = bucket_widths(nnz)
+            m = max(1, -(-int(np.dot(widths, counts)) // n))
+            reason = "bucketed"
     if env in ("1", "true", "force", "on"):
         return True, "forced via " + SPARSE_FIT_ENV, m
-    n, d = X.shape
     if n == 0:
         return False, "empty input", m
     if m * 8 * _pack_savings() > d * 4:
@@ -245,23 +523,13 @@ def pack_decision(X):
             f"dense-competitive density (m={m} of d={d}: the packed "
             f"pair saves < {_pack_savings()}x device bytes)"
         ), m
-    # nnz-outlier guard: m is the MAX row nnz, and every row pays
-    # padding to it — a handful of heavy rows must not bill the rest
-    p95 = float(np.percentile(nnz, 95)) if nnz.size else 0.0
-    total = max(1, int(nnz.sum()))
-    if (m > OUTLIER_FACTOR * max(p95, 1.0)
-            and n * m > OUTLIER_FACTOR * total):
-        return False, (
-            f"nnz outlier (max row nnz {m} vs p95 {p95:.0f}: padding "
-            f"would inflate {total} nnz to {n * m} slots)"
-        ), m
-    return True, "packed", m
+    return True, reason, m
 
 
 def would_pack(X):
-    """Whether :func:`pack_for_fit` would return a ``PackedX`` for
-    ``X`` — the same routing decision (sparsity, byte heuristic,
-    outlier guard, pack-budget check), decided from shape and
+    """Whether :func:`pack_for_fit` would return a packed
+    representation for ``X`` — the same routing decision (sparsity,
+    byte heuristic, pack-budget check), decided from shape and
     ``indptr`` alone without building anything. Callers that only need
     the routing outcome (e.g. to order a host-path bail before paying
     a dense conversion) use this instead of packing and discarding."""
@@ -285,14 +553,30 @@ def would_pack(X):
 
 
 def pack_for_fit(X):
-    """``PackedX`` when the fit plane should consume ``X`` packed, else
+    """:class:`PackedX` — or :class:`BucketedX` where the row lengths
+    are skewed — when the fit plane should consume ``X`` packed, else
     None (callers densify). Non-sparse and 1-D sparse inputs always
     return None; the routing decision lives in :func:`would_pack`."""
     if not would_pack(X):
         return None
-    X = X.tocsr()
-    idx, val = pack_csr_rows(X)
-    return PackedX(idx, val, X.shape[1])
+    from .obs import trace as obs_trace
+
+    # host seconds to bucket and pack; the counts are filled in at the
+    # span's end
+    args = {} if obs_trace.enabled() else None
+    with obs_trace.span("pack_x", args):
+        X = X.tocsr()
+        if pack_decision(X)[1] == "bucketed":
+            packed = pack_csr_buckets(X)
+            slots, buckets = packed.slots, len(packed.rows + packed.cols)
+            if args is not None and packed.head is not None:
+                args.update(head_cols=int(packed.head.shape[1]))
+        else:
+            packed = PackedX(*pack_csr_rows(X), X.shape[1])
+            slots, buckets = int(np.prod(packed.idx.shape)), 1
+        if args is not None:
+            args.update(nnz=int(X.nnz), slots=slots, buckets=buckets)
+    return packed
 
 
 def sparse_to_dense_f32(X):
@@ -477,12 +761,171 @@ def packed_weighted_gram(idx, val, sw, n_cols, row_chunk=None):
     return jax.lax.fori_loop(0, n_pad // chunk, body, out0)
 
 
+# ---------------------------------------------------------------------------
+# the bucketed contractions
+# ---------------------------------------------------------------------------
+
+def _gather_rowsum(idx, val, W, bf16):
+    """One bucket's ``X_b @ W`` for ``W (p, c)``, a tile a step: gather
+    the tile's ``W`` rows, scale, sum each row's slots. The gathered
+    block is at most ``TILE_SLOTS x c`` floats whatever the bucket, and
+    ``c`` is the gather's contiguous axis — which is why a batch of
+    weight matrices rides on it (:func:`_spmm`) instead of on an axis
+    of its own."""
+    if bf16:
+        W, val = W.astype(jnp.bfloat16), val.astype(jnp.bfloat16)
+
+    def step(_, iv):
+        i, v = iv
+        g = v[:, :, None] * W[i]
+        return None, jnp.sum(g.astype(jnp.float32), axis=1)
+
+    return jax.lax.scan(step, None, (idx, val))[1].reshape(
+        -1, W.shape[1])
+
+
+def _head_product(head, operand, bf16, transposed):
+    """The dense head's share of a product: ``head @ operand`` or
+    ``head.T @ operand``, float32 at ``highest`` — or the bf16
+    contract's one pass with float32 accumulation."""
+    if bf16:
+        head, operand = (a.astype(jnp.bfloat16) for a in (head, operand))
+    return jax.lax.dot_general(
+        head, operand, (((0 if transposed else 1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+        precision=(jax.lax.Precision.DEFAULT if bf16
+                   else jax.lax.Precision.HIGHEST))
+
+
+@functools.lru_cache(maxsize=None)
+def _spmm(bf16, transposed, ones):
+    """``(side, W (p, c)) -> (n, c)``: the product of one orientation
+    of a :class:`BucketedX` with a matrix — ``side`` is its ``(blocks,
+    inv, head, head_cols)`` — bucket by bucket, back in the matrix's
+    own row order, plus the dense head's matmul. ``ones`` says whether
+    the design matrix has its column of ones (the intercept): forward,
+    ``W`` then has one more row, the bias, added to every output row;
+    ``transposed``, one more output row holds ``W``'s column sums.
+
+    Under ``vmap`` over ``W`` the batch moves onto the column axis —
+    ``(L, p, c) -> (p, L·c)`` — and the same contraction runs once for
+    all lanes: every gathered row is then ``L·c`` contiguous floats,
+    where the batching rule of a plain gather would leave rows of ``c``
+    (20 classes pad to the 128 lanes of a TPU tile, six times the
+    bytes, in the operand and in every gathered block)."""
+    def product(side, W):
+        blocks, inv, head, head_cols = side
+        with jax.named_scope("sparse/spmm"):
+            out = jnp.concatenate(
+                [_gather_rowsum(idx, val, W, bf16) for idx, val in blocks])[inv]
+            if head is not None and not transposed:
+                out = out + _head_product(head, W[head_cols], bf16, False)
+            if head is not None and transposed:
+                out = out.at[head_cols].add(
+                    _head_product(head, W, bf16, True))
+            if ones and not transposed:
+                return out + W[-1]
+            if ones:
+                return jnp.concatenate([out, jnp.sum(W, axis=0)[None]])
+            return out
+
+    spmm = jax.custom_batching.custom_vmap(product)
+
+    @spmm.def_vmap
+    def _(axis_size, in_batched, side, W):
+        if any(jax.tree_util.tree_leaves(in_batched[0])):
+            # a batch of matrices: no caller makes one; plain batching
+            axes = jax.tree_util.tree_map(
+                lambda b: 0 if b else None, tuple(in_batched))
+            return jax.vmap(product, axes)(side, W), True
+        p, c = W.shape[1:]
+        wide = spmm(side, jnp.moveaxis(W, 0, 1).reshape(p, axis_size * c))
+        return jnp.moveaxis(wide.reshape(-1, axis_size, c), 1, 0), True
+
+    return spmm
+
+
+def _as_columns(fn, W):
+    """``fn`` on a matrix, for ``W`` a vector or a matrix."""
+    W = jnp.asarray(W)
+    return fn(W[:, None])[:, 0] if W.ndim == 1 else fn(W)
+
+
+def bucketed_matvec(X, W, bf16=False, intercept=False):
+    """``X @ W`` on a :class:`BucketedX`; ``W`` is ``(d,)`` or
+    ``(d, k)`` — with ``intercept``, ``[X | 1] @ W`` for ``W`` of
+    ``d + 1`` rows. No autodiff rule of its own beyond the gather's:
+    the fit problems take :func:`bucketed_matvec_with_vjp`."""
+    spmm = _spmm(bf16, False, bool(intercept))
+    side = (X.rows, X.inv, X.head, X.head_cols)
+    with jax.named_scope("sparse/matvec"):
+        return _as_columns(lambda w: spmm(side, w), W)
+
+
+def bucketed_rmatvec(X, r, bf16=False, intercept=False):
+    """``X.T @ r`` (``[X | 1].T @ r`` with ``intercept``) on a
+    :class:`BucketedX` — the same gather-and-row-sum over the
+    transposed orientation; ``r`` is ``(n,)`` or ``(n, k)``."""
+    spmm = _spmm(bf16, True, bool(intercept))
+    side = (X.cols, X.tinv, X.head, X.head_cols)
+    with jax.named_scope("sparse/rmatvec"):
+        return _as_columns(lambda g: spmm(side, g), r)
+
+
+def bucketed_matvec_with_vjp(X, bf16=False, intercept=False):
+    """``W -> X @ W`` for a fixed :class:`BucketedX`, whose backward
+    pass IS :func:`bucketed_rmatvec` (the true transpose) — the solvers
+    differentiate the loss through the forward product, and the
+    gather's own transpose would be a scatter-add."""
+
+    @jax.custom_vjp
+    def mv(W):
+        return bucketed_matvec(X, W, bf16, intercept)
+
+    mv.defvjp(
+        lambda W: (mv(W), None),
+        lambda _, g: (bucketed_rmatvec(X, g, bf16, intercept),))
+    return mv
+
+
+def _bucket_rows(X, i):
+    """The packed rows ``i`` (original row numbers) of a
+    :class:`BucketedX`'s buckets, one ``(idx, val)`` pair a bucket, a
+    row all padding in every bucket but its own — the mini-batch forms'
+    view: their cost follows the sum of the bucket widths, not nnz."""
+    pos, off, out = X.inv[i], 0, []
+    for idx, val in X.rows:
+        idx, val = (a.reshape(-1, a.shape[2]) for a in (idx, val))
+        n_b = idx.shape[0]
+        here = (pos >= off) & (pos < off + n_b)
+        at = jnp.clip(pos - off, 0, n_b - 1)
+        out.append((idx[at], jnp.where(here[:, None], val[at], 0.0)))
+        off += n_b
+    return out
+
+
+def bucketed_to_dense(X, fit_intercept=False):
+    """Scatter-rebuild the dense ``(n, d[+1])`` matrix of a
+    :class:`BucketedX` on device (the closed-form ridge family's gram
+    and ``mode='dense'``: feasible only where d is small)."""
+    p = X.n_cols + int(bool(fit_intercept))
+    dense = jnp.concatenate([
+        packed_to_dense(idx.reshape(-1, idx.shape[2]),
+                        val.reshape(-1, idx.shape[2]), p)
+        for idx, val in X.rows])[X.inv]
+    if X.head is not None:
+        dense = dense.at[:, X.head_cols].add(X.head)
+    return dense.at[:, X.n_cols].set(1.0) if fit_intercept else dense
+
+
 def matvec_any(X, W):
-    """``X @ W`` for either representation — the decision/proba
+    """``X @ W`` for any representation — the decision/proba
     kernels' one entry point, so a model fit packed scores packed
     shared data AND dense predict blocks through one closure."""
     if isinstance(X, PackedX):
         return packed_matvec(X.idx, X.val, W)
+    if isinstance(X, BucketedX):
+        return bucketed_matvec(X, W)
     return X @ W
 
 
@@ -519,7 +962,7 @@ class LinearOperator:
     """
 
     __slots__ = ("d", "p", "n", "Xa", "pidx", "pval", "bf16", "_Xmm",
-                 "dtype", "pallas", "_pmv")
+                 "dtype", "pallas", "_pmv", "bx", "_icpt")
 
     def __init__(self, X, fit_intercept, matmul_dtype=None, mode="gather"):
         if mode not in _VALID_MATVEC_MODES:
@@ -530,6 +973,23 @@ class LinearOperator:
         self._Xmm = None
         self.pallas = False
         self._pmv = None
+        self.bx = None
+        if isinstance(X, BucketedX):
+            # the intercept is NOT one more packed column here: a
+            # bucket's padding rows would carry it. It is the same
+            # column of ones applied beside the gathers (a broadcast
+            # add forward, a column sum backward: :func:`_spmm`).
+            self.dtype = X.rows[0][1].dtype
+            self.d, self.n = X.n_cols, X.shape[0]
+            self.p = self.d + int(bool(fit_intercept))
+            self._icpt = bool(fit_intercept)
+            self.pidx = self.pval = self.Xa = None
+            if mode == "dense":
+                self.Xa = bucketed_to_dense(X, fit_intercept)
+                return
+            self.bx = X
+            self._pmv = bucketed_matvec_with_vjp(X, self.bf16, self._icpt)
+            return
         self.dtype = X.val.dtype if isinstance(X, PackedX) else X.dtype
         if isinstance(X, PackedX):
             d = X.n_cols
@@ -570,6 +1030,8 @@ class LinearOperator:
 
     # -- X̃ @ W ---------------------------------------------------------
     def matvec(self, W):
+        if self.bx is not None:
+            return self._pmv(W)
         if self.Xa is not None:
             if self.bf16:
                 if self._Xmm is None:
@@ -597,6 +1059,8 @@ class LinearOperator:
 
     # -- X̃ᵀ @ r --------------------------------------------------------
     def rmatvec(self, r):
+        if self.bx is not None:
+            return bucketed_rmatvec(self.bx, r, self.bf16, self._icpt)
         if self.Xa is not None:
             return self.Xa.T @ r
         if self.pallas:
@@ -607,6 +1071,12 @@ class LinearOperator:
 
     # -- row-batch forms (the SGD mini-batch contractions) --------------
     def row_matvec(self, i, W):
+        if self.bx is not None:
+            out = sum(packed_matvec(idx, val, W[:self.d])
+                      for idx, val in _bucket_rows(self.bx, i))
+            if self.bx.head is not None:
+                out = out + self.bx.head[i] @ W[self.bx.head_cols]
+            return out + W[self.d] if self._icpt else out
         if self.Xa is not None:
             return self.Xa[i] @ W
         if self.pallas:
@@ -618,6 +1088,14 @@ class LinearOperator:
         return packed_matvec(self.pidx[i], self.pval[i], W)
 
     def row_rmatvec(self, i, g):
+        if self.bx is not None:
+            out = sum(packed_rmatvec(idx, val, g, self.d)
+                      for idx, val in _bucket_rows(self.bx, i))
+            if self.bx.head is not None:
+                out = out.at[self.bx.head_cols].add(self.bx.head[i].T @ g)
+            if not self._icpt:
+                return out
+            return jnp.concatenate([out, jnp.sum(g, axis=0)[None]])
         if self.Xa is not None:
             return self.Xa[i].T @ g
         if self.pallas:
@@ -635,6 +1113,11 @@ class LinearOperator:
         (``ops/pallas_sparse.packed_weighted_gram`` — the last packed
         contraction with a Pallas kernel, interpret mode off-TPU),
         while the rhs rides the mode's rmatvec."""
+        if self.bx is not None:
+            # a (p, p) gram exists only where p is small: rebuild
+            Xa = bucketed_to_dense(self.bx, self._icpt)
+            Xw = Xa * sw[:, None]
+            return Xa.T @ Xw, Xw.T @ T
         if self.Xa is not None:
             Xw = self.Xa * sw[:, None]
             return self.Xa.T @ Xw, Xw.T @ T

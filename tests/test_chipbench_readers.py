@@ -285,4 +285,69 @@ def test_every_metric_file_has_its_entry_and_reader():
             REPO, "chipbench", "readers", spec["reader"] + ".py"))
     # the new entries were appended: the accepted ones keep their places
     assert [m["name"] for m in bench["per_layer"]][
-        -len(NEW_METRICS):] == list(NEW_METRICS)
+        -len(NEW_METRICS + TEXT_METRICS):] == list(
+            NEW_METRICS + TEXT_METRICS)
+
+
+# ---------------------------------------------------------------------------
+# the text cell's four (PR 28)
+# ---------------------------------------------------------------------------
+
+#: name -> (reader module, source) of the metrics ``search-20news130k``
+#: brought
+TEXT_METRICS = (
+    "lbfgs_sparse_mfu_pct.search", "packed_fill_pct.search",
+    "pack_s_per_fit.search", "round_mem_estimate_pct.search",
+)
+
+
+@pytest.mark.parametrize("name, reader, source", zip(
+    TEXT_METRICS,
+    ("work_share", "round_counts", "span_seconds", "round_mem_estimate"),
+    ("host_clock", "program_counter", "program_span", "program_counter")))
+def test_text_cell_metric_resolves_to_a_reader_and_an_entry(
+        name, reader, source):
+    with open(os.path.join(REPO, "chipbench", "metrics",
+                           name + ".json")) as f:
+        spec = json.load(f)
+    assert spec["reader"] == reader
+    entry = {m["name"]: m for m in _bench()["per_layer"]}[name]
+    assert entry["workloads"] == ["search-20news130k"]
+    assert (entry["moves"], entry["source"]) == ("search_fits_per_s", source)
+    if reader != "work_share":
+        # nothing to read in a window of a program without the counter
+        # or the span (the parent commit): the metric is left out
+        empty = {"fits": [{"stats": {"rounds": 1}, "units": 1,
+                           "failed": 0}],
+                 "units_done": 1, "memory_peak_bytes": 1 << 30}
+        module = importlib.import_module("chipbench.readers." + reader)
+        assert module.read(empty, **spec.get("args", {})) is None
+
+
+def test_dense_share_of_the_peak_does_not_read_the_text_cell():
+    entry = {m["name"]: m for m in _bench()["per_layer"]}[
+        "lbfgs_mfu_pct.search"]
+    assert entry["workloads"] == ["search-epsilon"]
+
+
+@pytest.mark.parametrize("stats, peak, want", [
+    # 0.7 GB shared and ten lanes of 0.67 GB against 9.1 GB held
+    ({"chunk": 10, "lane_bytes": 670_000_000,
+      "shared_bytes": 700_000_000}, 9_100_000_000, 100 * 7.4 / 9.1),
+    ({"chunk": 10, "lane_bytes": None, "shared_bytes": 7}, 1 << 30, None),
+    ({"chunk": 10, "lane_bytes": 5, "shared_bytes": 7}, 0, None),
+    ({"rounds": 3}, 1 << 30, None),
+])
+def test_round_mem_estimate(stats, peak, want):
+    from chipbench.readers import round_mem_estimate
+
+    got = round_mem_estimate.read(
+        {"fits": [_fit(stats)], "memory_peak_bytes": peak})
+    assert got == (want if want is None else pytest.approx(want))
+
+
+def test_packed_fill_reads_the_booked_counts():
+    stats = {"x_nnz": 2_175_334, "x_slots": 173_281_098, "rounds": 5}
+    got = round_counts.read(_ctx([_fit(stats), _fit(stats)]),
+                            num="x_nnz", den="x_slots")
+    assert got == pytest.approx(1.2553787)

@@ -1,0 +1,199 @@
+"""
+The bucketed packed representation (``sparse.BucketedX``): a CSR whose
+row lengths are heavy-tailed packs at a cost that follows nnz, its
+products equal the dense ones in every mode, a matrix of even rows
+still packs to the one padded pair it always did, and a grid search
+over a skewed 20-class CSR runs packed end to end and agrees with the
+benchmark's plain reference for sparse inputs.
+"""
+
+import numpy as np
+import pytest
+import scipy.sparse as sp
+
+import jax
+import jax.numpy as jnp
+
+from skdist_tpu import sparse as sx
+
+
+def skewed_csr(seed=0, n=600, d=5000, heavy=(2000, 1500, 900)):
+    """Log-normal row lengths with a few rows in the thousands."""
+    rng = np.random.RandomState(seed)
+    lens = np.clip(rng.lognormal(3.0, 1.0, n).astype(int), 1, d // 2)
+    lens[:len(heavy)] = heavy
+    rows = np.repeat(np.arange(n), lens)
+    cols = np.concatenate([rng.choice(d, l, replace=False) for l in lens])
+    vals = rng.rand(len(rows)).astype(np.float32)
+    return sp.csr_matrix((vals, (rows, cols)), shape=(n, d))
+
+
+@pytest.mark.parametrize("mode", ["gather", "pallas"])
+def test_bucketed_products_equal_dense(mode):
+    """matvec, rmatvec, the row forms and the vmapped value-and-gradient
+    through the operator, against the dense matrix — in both nnz-bound
+    modes of the calibration table (a ``BucketedX`` has one contraction
+    for both: the Pallas rebuild kernels' work follows n x d)."""
+    X = skewed_csr()
+    n, d = X.shape
+    k = 5
+    assert sx.pack_decision(X)[:2] == (True, "bucketed")
+    B = jax.tree_util.tree_map(jnp.asarray, sx.pack_for_fit(X))
+    assert isinstance(B, sx.BucketedX) and B.shape == (n, d)
+    assert len(B.rows) > 3 and B.nnz == X.nnz
+    # the densest columns are held dense, the rest packed at a cost
+    # that follows nnz: far under max-row padding
+    assert B.head.shape == (n, B.head_cols.shape[0]) and 0 < B.head_nnz
+    assert B.placed == 2 * (B.nnz - B.head_nnz) + B.head_nnz
+    assert B.slots - B.head.size < 0.25 * n * np.diff(X.indptr).max()
+    Xd = X.toarray()
+    rng = np.random.RandomState(1)
+    W = rng.randn(d + 1, k).astype(np.float32)
+    r = rng.randn(n, k).astype(np.float32)
+    Xa = np.hstack([Xd, np.ones((n, 1), np.float32)])
+    op = sx.LinearOperator(B, True, mode=mode)
+    np.testing.assert_allclose(op.matvec(W), Xa @ W, atol=5e-5)
+    np.testing.assert_allclose(op.matvec(W[:, 0]), Xa @ W[:, 0], atol=5e-5)
+    np.testing.assert_allclose(op.rmatvec(r), Xa.T @ r, atol=5e-5)
+    rows = rng.randint(0, n, 32)
+    g = rng.randn(32, k).astype(np.float32)
+    np.testing.assert_allclose(op.row_matvec(rows, W), Xa[rows] @ W,
+                               atol=5e-5)
+    np.testing.assert_allclose(op.row_rmatvec(rows, g), Xa[rows].T @ g,
+                               atol=5e-5)
+
+    def loss(Wl, X):
+        return jnp.sum(jnp.tanh(
+            sx.LinearOperator(X, True, mode=mode).matvec(Wl)) * r)
+
+    lanes = rng.randn(4, d + 1, k).astype(np.float32)
+    batched = jax.jit(jax.vmap(jax.value_and_grad(loss), (0, None)))
+    v, gr = batched(lanes, B)
+    v_ref, gr_ref = batched(lanes, jnp.asarray(Xd))
+    np.testing.assert_allclose(v, v_ref, rtol=2e-5)
+    np.testing.assert_allclose(gr, gr_ref, atol=5e-5)
+
+
+def test_even_rows_pack_to_the_one_padded_pair():
+    """No skew: ``pack_for_fit`` answers the ``PackedX`` it always did,
+    bit for bit ``pack_csr_rows``."""
+    X = sp.random(300, 4096, density=0.01, format="csr",
+                  dtype=np.float32, random_state=np.random.RandomState(3))
+    assert sx.pack_decision(X)[:2] == (True, "packed")
+    packed = sx.pack_for_fit(X)
+    assert type(packed) is sx.PackedX
+    idx, val = sx.pack_csr_rows(X)
+    assert np.array_equal(packed.idx, idx) and np.array_equal(packed.val, val)
+    assert packed.m == np.diff(X.indptr).max()
+    widths, counts = sx.bucket_widths(np.diff(X.indptr))
+    assert sum(counts) == 300 and widths == sorted(widths)
+
+
+def test_search_on_a_skewed_csr_stays_packed_and_matches_the_reference(
+        monkeypatch):
+    """``DistGridSearchCV`` over a skewed 20-class CSR through the
+    batched path: the matrix is never densified, the round stats book
+    the packing, and every answer is within the stated gaps of the
+    benchmark's plain reference (float32 vectors on its side too: the
+    gaps are the rounding of two float32 solvers on 400 rows, where a
+    fit left at its start would read 1e-1)."""
+    from chipbench import datagen_text
+    from chipbench.reference.softmax_lr import stratified_folds
+    from chipbench.reference.softmax_lr_sparse import SparseSoftmaxLR
+    from skdist_tpu.distribute.search import DistGridSearchCV
+    from skdist_tpu.models import LogisticRegression, linear
+    from skdist_tpu.parallel import TPUBackend
+
+    X, y = datagen_text.bag_of_words(
+        7, 400, 30000, 20, 24000, len_cap=3000, topic_terms=100,
+        len_sigma=1.6)
+    assert sx.pack_decision(X)[1] == "bucketed"
+
+    def no_dense(*a, **k):
+        raise AssertionError("the matrix was densified")
+
+    monkeypatch.setattr(sx, "sparse_to_dense_f32", no_dense)
+    monkeypatch.setattr(linear, "sparse_to_dense_f32", no_dense)
+    Cs, cv = [0.1, 10.0], 3
+    backend = TPUBackend(devices=jax.devices()[:1])
+    gs = DistGridSearchCV(
+        LogisticRegression(max_iter=25, tol=1e-4), {"C": Cs},
+        backend=backend, cv=cv, scoring="neg_log_loss", refit=False,
+        error_score="raise").fit(X, y)
+    stats = backend.last_round_stats
+    assert stats["kernel_mode"] == "packed_gather"
+    assert stats["x_matvec"] == "gather"
+    assert X.nnz < stats["x_nnz"] <= 2 * X.nnz < stats["x_slots"]
+    got = np.array([[gs.cv_results_[f"split{f}_test_score"][c]
+                     for f in range(cv)] for c in range(len(Cs))])
+    ref = SparseSoftmaxLR(X, y, 20)
+    want = np.array(ref.fold_scores(
+        stratified_folds(y, cv), [(f, C) for C in Cs for f in range(cv)],
+        25, 1e-4)).reshape(len(Cs), cv)
+    gap = np.abs(got - want)
+    assert np.median(gap) < 2e-4 and gap.max() < 2e-3, gap
+
+
+def test_search_refit_takes_the_packed_matrix(monkeypatch):
+    """The refit fits the matrix the search packed: one pack a search,
+    and the same model as a fit of the CSR itself."""
+    from skdist_tpu.distribute.search import DistGridSearchCV
+    from skdist_tpu.models import LogisticRegression
+    from skdist_tpu.parallel import TPUBackend
+
+    X = skewed_csr(seed=3)
+    y = np.arange(X.shape[0]) % 3
+    packs = []
+    pack = sx.pack_csr_buckets
+    monkeypatch.setattr(
+        sx, "pack_csr_buckets", lambda M: packs.append(1) or pack(M))
+    est = LogisticRegression(max_iter=15, tol=1e-4)
+    gs = DistGridSearchCV(
+        est, {"C": [0.1, 1.0]}, cv=3, scoring="neg_log_loss",
+        backend=TPUBackend(devices=jax.devices()[:1]),
+        error_score="raise").fit(X, y)
+    assert len(packs) == 1 and not hasattr(gs, "_packed_X_")
+    alone = LogisticRegression(max_iter=15, tol=1e-4,
+                               **gs.best_params_).fit(X, y)
+    assert len(packs) == 2
+    np.testing.assert_array_equal(gs.best_estimator_.coef_, alone.coef_)
+    assert gs.best_estimator_.predict(X).shape == y.shape
+
+
+def test_line_search_along_a_ray_takes_the_same_path():
+    """A loss that offers ``ray(w, d) -> phi`` with ``phi(t) ==
+    loss(w + t * d)`` is searched through it: the same iterates as the
+    plain search up to rounding, three evaluations an iteration."""
+    from skdist_tpu.models.solvers import lbfgs_carry_init, lbfgs_resume
+
+    rng = np.random.RandomState(0)
+    A = jnp.asarray(rng.randn(200, 12).astype(np.float32))
+    y = jnp.asarray((rng.rand(200) < 0.5).astype(np.float32))
+
+    def from_logits(z, w):
+        return jnp.sum(jax.nn.softplus(z) - y * z) + 0.05 * jnp.dot(w, w)
+
+    def plain(w):
+        return from_logits(A @ w, w)
+
+    def with_ray(w):
+        return from_logits(A @ w, w)
+
+    calls = []
+
+    def ray(w, d):
+        calls.append(1)
+        z0, dz = A @ w, A @ d
+        return lambda t: from_logits(z0 + t * dz, w + t * d)
+
+    with_ray.ray = ray
+    w0 = jnp.zeros(12, jnp.float32)
+    out = []
+    for loss in (plain, with_ray):
+        carry = lbfgs_carry_init(loss, w0, max_iter=25, tol=1e-5)
+        out.append(lbfgs_resume(loss, carry, 25, max_iter=25, tol=1e-5))
+    a, b = out
+    assert calls and int(a["it"]) == int(b["it"]) > 3
+    np.testing.assert_allclose(a["w"], b["w"], atol=2e-5)
+    assert int(b["nfev"]) == 3 * int(b["it"]) + 1
+    assert int(a["nfev"]) >= 2 * int(a["it"]) + 1

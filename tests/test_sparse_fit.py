@@ -3,8 +3,8 @@ arrays, nnz-proportional solver kernels, routing, and the end-to-end
 batched paths.
 
 Covers the ISSUE-4 contract: dense-vs-packed parity fuzz for all four
-linear families (weighted + fold-masked), the nnz-outlier guard and
-fallback-to-densify routing, pickle round-trip of a sparse-fit model,
+linear families (weighted + fold-masked), the density routing (skewed
+row lengths pack bucketed: ``test_sparse_bucketed.py``), pickle round-trip of a sparse-fit model,
 OvR/OvO batched sparse grids, and the no-recompile counters across
 mixed sparse/dense rounds.
 """
@@ -16,7 +16,6 @@ import pytest
 import scipy.sparse as sp
 
 from skdist_tpu.sparse import (
-    OUTLIER_FACTOR,
     PackedX,
     SPARSE_FIT_ENV,
     LinearOperator,
@@ -104,7 +103,7 @@ def test_linear_operator_dense_matches_legacy_expressions():
 
 
 # ---------------------------------------------------------------------------
-# routing: pack decision, outlier guard, env switches
+# routing: pack decision, env switches
 # ---------------------------------------------------------------------------
 
 def test_pack_decision_density_and_overrides(monkeypatch):
@@ -129,30 +128,6 @@ def test_pack_decision_density_and_overrides(monkeypatch):
         v = None
     if v is not None and len(v.shape) == 1:
         assert pack_for_fit(v) is None
-
-
-def test_nnz_outlier_guard_falls_back_to_densify():
-    """A handful of heavy rows must not bill every row for max-row
-    padding: the guard routes the matrix to the densify path."""
-    rng = np.random.RandomState(1)
-    n, d = 400, 2048
-    X = sp.random(n, d, density=0.002, format="csr",
-                  dtype=np.float32, random_state=rng).tolil()
-    # one pathological row with ~d/10 nonzeros: small enough that the
-    # byte-ratio check alone would still pack (m <= d/8), so the
-    # OUTLIER guard is what must catch it (p95 stays ~4)
-    heavy = rng.choice(d, size=d // 10, replace=False)
-    for j in heavy:
-        X[0, j] = 1.0
-    X = X.tocsr()
-    ok, reason, m = pack_decision(X)
-    assert not ok and "outlier" in reason
-    assert m > OUTLIER_FACTOR  # the max row really is the outlier
-    # the fit path consequently densifies (dense ndarray, not PackedX)
-    from skdist_tpu.models.linear import prepare_fit_X
-
-    X_prep = prepare_fit_X(X)
-    assert isinstance(X_prep, np.ndarray)
 
 
 def test_explicit_host_pin_beats_packing():

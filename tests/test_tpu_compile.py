@@ -264,7 +264,102 @@ def test_round_size_rule_on_real_programs(n, d, k, n_tasks, free, want):
         rounds = -(-n_tasks // lanes)
         assert chunk == -(-n_tasks // rounds)
     if basis == "memory":
-        assert 8 <= chunk <= lanes_fit < 60
+        # not every carry fits beside two running rounds, so one round
+        # runs at a time and the cap is what that round may hold: the
+        # eight rounds of the rule fit under it
+        assert chunk == 60 <= lanes_fit < n_tasks
+
+
+def _skewed_text_csr(n, d, nnz, seed=0):
+    """A CSR in the shape of a vectorised corpus: log-normal row
+    lengths (mean ``nnz / n``, the longest some twenty times that),
+    Zipf term popularity, rows of unit norm."""
+    import scipy.sparse as sp
+
+    rng = np.random.RandomState(seed)
+    lens = rng.lognormal(0.0, 0.861, n)
+    lens = np.maximum(1, np.round(lens * (nnz / lens.sum()))).astype(int)
+    cdf = np.cumsum(1.0 / (np.arange(d) + 10.0))
+    doc = np.repeat(np.arange(n), 2 * lens)
+    term = np.searchsorted(cdf, rng.random_sample(doc.size) * cdf[-1])
+    X = sp.csr_matrix((np.ones(doc.size, np.float32),
+                       (doc, np.minimum(term, d - 1))), shape=(n, d))
+    X.sum_duplicates()
+    return sp.diags(1.0 / np.sqrt(X.multiply(X).sum(axis=1)).A1).dot(
+        X).astype(np.float32).tocsr()
+
+
+def test_round_size_rule_at_the_text_cell():
+    """``search-20news130k``'s shapes (a corpus of its size and skew,
+    packed bucketed; nothing compiles): 50 lanes of 229 MB outweigh the
+    matrix and the chip, so the rule is ``memory``: one round on the
+    device at a time, the eight rounds of 7 that ``target_rounds``
+    asks for, under the cap of what 15.75 GiB hold of one round."""
+    from skdist_tpu import sparse as sx
+    from skdist_tpu.distribute.search import (
+        _cached_cv_kernel, _cv_iterative_spec, _cv_kernel_key,
+        _resolve_device_scoring,
+    )
+    from skdist_tpu.models import LogisticRegression
+    from skdist_tpu.models.linear import _freeze, extract_aux
+    from skdist_tpu.parallel.backend import (
+        IterativePlan, _iterative_jit_entries, _lane_footprint,
+        _size_iterative_round, resolve_slice_iters, tree_nbytes,
+    )
+
+    n, d, k, n_tasks = 11_314, 130_107, 20, 50
+    X = _skewed_text_csr(n, d, 1_787_565)
+    y = np.arange(n) % k
+    rows = np.diff(X.indptr)
+    assert rows.max() > 4 * np.percentile(rows, 95)
+    B = sx.pack_for_fit(X)
+    assert isinstance(B, sx.BucketedX)
+    # most of the stored elements sit in a dense head of under 1 GiB;
+    # the rest pay a quarter in padding, not the 15 x of max-row padding
+    assert B.head.nbytes < 1 << 30 and B.head_nnz > 0.6 * X.nnz
+    assert (B.placed - B.head_nnz) / (B.slots - B.head.size) > 0.7
+    est = LogisticRegression(max_iter=100, tol=1e-4)
+    data, meta = est._prep_fit_data(B, y, None)
+    static = _freeze(est._static_config(meta))
+    specs = _resolve_device_scoring(est, "neg_log_loss")
+    key = _cv_kernel_key(type(est), meta, static, specs, False)
+    classic = _cached_cv_kernel(type(est), meta, static, specs, False,
+                                key=key)
+    spec, _ = _cv_iterative_spec(
+        type(est), meta, static, specs, False, resolve_slice_iters(100),
+        fallback=classic, fallback_key=key)
+    init_fn, _, _, _ = _iterative_jit_entries(spec, None, None, None, None)
+    f32 = jax.ShapeDtypeStruct
+    shared = {
+        "X": B, "y": f32((n,), data["y"].dtype),
+        "sw": f32((n,), jnp.float32), "aux": extract_aux(data),
+        "train_masks": f32((5, n), jnp.float32),
+        "test_masks": f32((5, n), jnp.float32),
+    }
+    task = {
+        "hyper": {name: f32((n_tasks,), jnp.float32)
+                  for name in type(est)._hyper_names},
+        "split": f32((n_tasks,), jnp.int32),
+    }
+    # no step program: the rule alone, not the check against a compile
+    plan = IterativePlan(init_fn, None, None, None, shared, None)
+
+    class Chip:
+        last_shared_bytes = tree_nbytes(shared)
+
+        def _free_device_bytes(self):
+            return int(15.75 * 1024 ** 3) - self.last_shared_bytes
+
+    chunk, basis, lanes_fit = _size_iterative_round(
+        Chip(), plan, task, n_tasks, None)
+    assert basis == "memory"
+    assert chunk == 7 <= lanes_fit < 50
+    resident, transient, fixed = _lane_footprint(plan, task)
+    width = (d + 1) * k
+    # W, gradient and two histories of ten: 22 vectors, resident
+    assert 4 * 22 * width <= resident <= 4 * 23 * width
+    assert transient > resident
+    assert Chip.last_shared_bytes < 4 * resident
 
 
 def test_matmul_tree_level_step_compiles(sds):
