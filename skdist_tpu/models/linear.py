@@ -348,6 +348,58 @@ def _linear_op(X, fit_intercept, meta, matmul_dtype=None):
     )
 
 
+def _ray_loss(matvec, row_loss, reg_loss, scope, linear=True):
+    """``loss(w) = row_loss(matvec(w)) + reg_loss(w)`` as ``(loss,
+    data_loss)`` — the one statement every L-BFGS fit problem is built
+    from, whatever the representation behind ``matvec``.
+
+    Where ``matvec`` is LINEAR in the flat weights the loss offers the
+    ray below. The one that is not is the bfloat16 contract's
+    (``matmul_dtype='bfloat16'`` rounds its operand, and the rounding
+    of ``w + t·d`` is not the sum of two roundings): that path keeps
+    the plain search, whose trial steps are its own products — along a
+    ray its logits would be float32 sums and its answers a precision
+    class of their own, nearer the float32 path's than the contract's
+    (``LinearOperator`` has the contract).
+
+    ``loss.ray(w, d)`` is what the solver's line search takes
+    (``solvers._lbfgs_body``): ``X̃ @ (w + t·d) = X̃ @ w + t · X̃ @ d``,
+    so a direction costs two forward products, every trial step a pass
+    over the logits, and the accepted point's value and gradient the
+    one transposed product ``X̃ᵀ r`` of ``r = ∇row_loss(z0 + t·dz)`` —
+    three products an iteration however often the search halves.
+    ``jax.vjp`` hands out ``z0`` and that transpose together, through
+    the ``custom_vjp`` of the bucketed and Pallas products as well.
+    ``z0`` is taken from ``w`` anew each direction, so rounding does
+    not build up along a solve."""
+
+    def data_loss(w):
+        with jax.named_scope(f"{scope}/forward_loss"):
+            return row_loss(matvec(w))
+
+    def loss(w):
+        return data_loss(w) + reg_loss(w)
+
+    def ray(w, d):
+        with jax.named_scope(f"{scope}/ray"):
+            z0, rmatvec = jax.vjp(matvec, w)
+            dz = matvec(d)
+
+        def along(t):
+            return row_loss(z0 + t * dz) + reg_loss(w + t * d)
+
+        def value_and_grad_at(t):
+            f_rows, r = jax.value_and_grad(row_loss)(z0 + t * dz)
+            f_reg, g_reg = jax.value_and_grad(reg_loss)(w + t * d)
+            return f_rows + f_reg, rmatvec(r)[0] + g_reg
+
+        return along, value_and_grad_at
+
+    if linear:
+        loss.ray = ray
+    return loss, data_loss
+
+
 def get_kernel(cls, which, meta, static):
     """Fetch a (possibly jitted) kernel from the process-wide cache.
 
@@ -1000,33 +1052,24 @@ class LogisticRegression(_LbfgsFitMixin, _LinearClassifierBase):
             p = op.p
             sw = _apply_class_weight(sw, y_idx, k, class_weight, cw_arr)
             d = meta["n_features"]
-            matvec = op.matvec
             # the data term and regulariser are separable closures: the
-            # resident loss composes them in the historical expression
-            # order (numerics pinned), and the STREAMED fit evaluates
-            # data_loss per block (the term is row-additive) plus
-            # reg_loss once — `parts=True` is that second consumer
+            # resident loss composes them (``_ray_loss``), and the
+            # STREAMED fit evaluates data_loss per block (the term is
+            # row-additive) plus reg_loss once — `parts=True` is that
+            # second consumer
             if binary:
                 ypm = (y_idx == (k - 1)).astype(op.dtype)  # {0,1}
 
-                def data_loss(w):
-                    with jax.named_scope("lr/forward_loss"):
-                        z = matvec(w)
-                        return jnp.sum(
-                            sw * (jax.nn.softplus(z) - ypm * z)
-                        )
+                def row_loss(z):
+                    return jnp.sum(sw * (jax.nn.softplus(z) - ypm * z))
 
                 def reg_loss(w):
                     if unpenalized:  # penalty=None: sklearn's C=inf
                         return jnp.float32(0.0)
                     return 0.5 / C * jnp.dot(w[:d], w[:d])
 
-                def loss(w):
-                    ce = data_loss(w)
-                    if unpenalized:
-                        return ce
-                    return ce + reg_loss(w)
-
+                loss, data_loss = _ray_loss(
+                    op.matvec, row_loss, reg_loss, "lr", linear=not bf16)
                 w0 = jnp.zeros(p, op.dtype)
 
                 def unpack(w, n_iter):
@@ -1038,14 +1081,10 @@ class LogisticRegression(_LbfgsFitMixin, _LinearClassifierBase):
 
             onehot = jax.nn.one_hot(y_idx, k, dtype=op.dtype)
 
-            def data_loss(wflat):
-                W = wflat.reshape(p, k)
-                with jax.named_scope("lr/forward_loss"):
-                    logits = matvec(W)
-                    lse = jax.nn.logsumexp(logits, axis=1)
-                    return jnp.sum(
-                        sw * (lse - jnp.sum(onehot * logits, axis=1))
-                    )
+            def row_loss(logits):
+                lse = jax.nn.logsumexp(logits, axis=1)
+                return jnp.sum(
+                    sw * (lse - jnp.sum(onehot * logits, axis=1)))
 
             def reg_loss(wflat):
                 if unpenalized:  # penalty=None: sklearn's C=inf
@@ -1053,37 +1092,9 @@ class LogisticRegression(_LbfgsFitMixin, _LinearClassifierBase):
                 W = wflat.reshape(p, k)
                 return 0.5 / C * jnp.sum(W[:d] * W[:d])
 
-            def loss(wflat):
-                ce = data_loss(wflat)
-                if unpenalized:
-                    return ce
-                return ce + reg_loss(wflat)
-
-            if op.bx is not None:
-                def ray(wflat, dflat):
-                    """``t -> loss(wflat + t * dflat)`` from two packed
-                    products: the logits are linear in the weights, so
-                    a trial step costs a pass over (n, k) logits, not
-                    over the matrix (a round of lanes runs the halvings
-                    of its slowest lane: at 130,107 columns each was a
-                    gather of every stored element)."""
-                    with jax.named_scope("lr/ray"):
-                        z0 = matvec(wflat.reshape(p, k))
-                        dz = matvec(dflat.reshape(p, k))
-
-                    def along(t):
-                        logits = z0 + t * dz
-                        lse = jax.nn.logsumexp(logits, axis=1)
-                        ce = jnp.sum(
-                            sw * (lse - jnp.sum(onehot * logits, axis=1)))
-                        if unpenalized:
-                            return ce
-                        return ce + reg_loss(wflat + t * dflat)
-
-                    return along
-
-                loss.ray = ray
-
+            loss, data_loss = _ray_loss(
+                lambda wflat: op.matvec(wflat.reshape(p, k)),
+                row_loss, reg_loss, "lr", linear=not bf16)
             w0 = jnp.zeros(p * k, op.dtype)
 
             def unpack(w, n_iter):
@@ -1245,16 +1256,15 @@ class LinearSVC(_LbfgsFitMixin, _LinearClassifierBase):
             if binary:
                 ypm = jnp.where(y_idx == (k - 1), 1.0, -1.0).astype(op.dtype)
 
-                def data_loss(w):
-                    margin = jnp.maximum(0.0, 1.0 - ypm * op.matvec(w))
+                def row_loss(z):
+                    margin = jnp.maximum(0.0, 1.0 - ypm * z)
                     return C * jnp.sum(sw * margin**2)
 
                 def reg_loss(w):
                     return 0.5 * jnp.dot(w[:d], w[:d])
 
-                def loss(w):
-                    return reg_loss(w) + data_loss(w)
-
+                loss, data_loss = _ray_loss(
+                    op.matvec, row_loss, reg_loss, "svc")
                 w0 = jnp.zeros(p, op.dtype)
 
                 def unpack(w, n_iter):
@@ -1266,18 +1276,17 @@ class LinearSVC(_LbfgsFitMixin, _LinearClassifierBase):
 
             Ypm = jnp.where(jax.nn.one_hot(y_idx, k) > 0, 1.0, -1.0).astype(op.dtype)
 
-            def data_loss(wflat):
-                W = wflat.reshape(p, k)
-                margins = jnp.maximum(0.0, 1.0 - Ypm * op.matvec(W))
+            def row_loss(scores):
+                margins = jnp.maximum(0.0, 1.0 - Ypm * scores)
                 return C * jnp.sum(sw[:, None] * margins**2)
 
             def reg_loss(wflat):
                 W = wflat.reshape(p, k)
                 return 0.5 * jnp.sum(W[:d] * W[:d])
 
-            def loss(wflat):
-                return reg_loss(wflat) + data_loss(wflat)
-
+            loss, data_loss = _ray_loss(
+                lambda wflat: op.matvec(wflat.reshape(p, k)),
+                row_loss, reg_loss, "svc")
             w0 = jnp.zeros(p * k, op.dtype)
 
             def unpack(w, n_iter):

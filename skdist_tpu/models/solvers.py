@@ -50,15 +50,17 @@ from jax import lax
 _EPS = 1e-12
 
 #: order of the L-BFGS carry leaves (the ISSUE-pinned pytree contract)
-#: ``nfev`` counts the forward passes over the data the lane ASKED for
-#: (1 for the initial value-and-gradient; per iteration the trial at
-#: t0, one per line-search halving, and the value-and-gradient at the
-#: accepted point) — the work count that tells a change of speed from a
-#: change of work. Under ``vmap`` a round EXECUTES the halvings of its
-#: slowest lane; each lane still counts its own. Where the loss offers
-#: ``ray(w, d)`` a halving is no pass over the data and an iteration
-#: counts 3 (the ray's two products, the value-and-gradient): passes
-#: still, but no longer the evaluations the line search asked for.
+#: ``nfev`` counts the evaluations of the loss the lane ASKED for (1 for
+#: the initial value-and-gradient; per iteration the trial at t0, one
+#: per line-search halving, and the value-and-gradient at the accepted
+#: point: ``n_halved + 2``) — the work count that tells a change of
+#: speed from a change of work, with ONE meaning whether or not the
+#: loss offers a ray. Under ``vmap`` a round EXECUTES the halvings of
+#: its slowest lane; each lane still counts its own. It is not the
+#: count of products over the data: where the loss offers
+#: ``ray(w, d)`` an iteration takes three whatever its halvings, so a
+#: solve takes ``3 * it + 2`` (the initial value-and-gradient is a
+#: forward and a transposed product).
 LBFGS_CARRY_KEYS = ("w", "f", "g", "S", "Y", "rho", "k", "it", "nfev",
                     "done")
 
@@ -113,17 +115,22 @@ def _lbfgs_body(fun, value_and_grad, max_iter, tol, history, max_ls):
 
         return -lax.fori_loop(0, m, fwd, r)
 
-    # a problem whose loss is a function of LINEAR products of w
-    # (``fun.ray(w, d) -> phi`` with ``phi(t) == fun(w + t * d)``) pays
-    # its products once a direction, not once a trial step
+    # a problem whose loss is a function of LINEAR products of w offers
+    # ``fun.ray(w, d) -> (along, value_and_grad_at)`` with ``along(t) ==
+    # fun(w + t * d)`` and ``value_and_grad_at(t) ==
+    # value_and_grad(fun)(w + t * d)``, both from products taken ONCE a
+    # direction: the trial steps and the accepted point's value and
+    # gradient read the ray's logits, never the data. A plain function
+    # pays a product a trial step and two more at the accepted point.
     ray = getattr(fun, "ray", None)
 
-    def line_search(w, f, g, d):
-        """Armijo backtracking; returns (step, f_new, accepted,
-        halvings)."""
-        gd = jnp.dot(g, d)
-        along = ray(w, d) if ray is not None else (
-            lambda t: fun(w + t * d))
+    def plain_ray(w, d):
+        return (lambda t: fun(w + t * d),
+                lambda t: value_and_grad(w + t * d))
+
+    def line_search(along, f, gd):
+        """Armijo backtracking along ``along``; returns (step, f_new,
+        accepted, halvings)."""
 
         def cond(carry):
             t, f_new, it = carry
@@ -160,10 +167,19 @@ def _lbfgs_body(fun, value_and_grad, max_iter, tol, history, max_ls):
             raw_scale, d / (jnp.linalg.norm(d) + _EPS), d
         )
         with jax.named_scope("lbfgs/line_search"):
-            t, f_new, ok, n_halved = line_search(w, f, g, d)
+            along, value_and_grad_at = (ray or plain_ray)(w, d)
+            # a ray's trial values are sums of two products, and the
+            # carried f is a value of the PREVIOUS ray: the two round
+            # apart by an ulp or two, which near a solve's float32
+            # floor is more than the decrease — so the Armijo test
+            # holds phi(t) against phi(0) of the same ray (a pass over
+            # the logits, no product), as the plain search holds
+            # fun(w + t d) against fun(w)
+            f0 = f if ray is None else along(0.0)
+            t, f_new, ok, n_halved = line_search(along, f0, jnp.dot(g, d))
         w_new = w + t * d
         with jax.named_scope("lbfgs/value_and_grad"):
-            f_new2, g_new = value_and_grad(w_new)
+            f_new2, g_new = value_and_grad_at(t)
         with jax.named_scope("lbfgs/history_update"):
             s = w_new - w
             yv = g_new - g
@@ -183,9 +199,8 @@ def _lbfgs_body(fun, value_and_grad, max_iter, tol, history, max_ls):
         # answers "will more steps change this lane?" — what the
         # backend's flags-only compaction gather reads
         done_new = converged | stalled | (it + 1 >= max_iter)
-        # trial at t0 + the halvings + the value-and-gradient above —
-        # or, along a ray, the two products of the ray and that one
-        nfev_new = nfev + (n_halved + 2 if ray is None else 3)
+        # trial at t0 + the halvings + the value-and-gradient above
+        nfev_new = nfev + n_halved + 2
         return (w_new, f_new2, g_new, S, Y, rho, k_new, it + 1, nfev_new,
                 done_new)
 

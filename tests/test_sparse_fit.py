@@ -84,7 +84,11 @@ def test_packed_empty_rows_and_empty_matrix():
 
 def test_linear_operator_dense_matches_legacy_expressions():
     """The dense branch must reproduce the historical ops verbatim —
-    the dense paths' pinned numerics depend on it."""
+    the dense paths' pinned numerics depend on it. The one that moved
+    (PR 29): against a weight VECTOR the product is written lanes-first,
+    ``w @ Xa.T`` — the same contraction, whose logits a ``vmap`` over
+    lanes lays out ``(lanes, n)`` instead of ``(n, lanes)``."""
+    import jax
     import jax.numpy as jnp
 
     rng = np.random.RandomState(5)
@@ -93,7 +97,16 @@ def test_linear_operator_dense_matches_legacy_expressions():
     Xa = jnp.concatenate([X, jnp.ones((30, 1), X.dtype)], axis=1)
     w = jnp.asarray(rng.normal(size=8).astype(np.float32))
     np.testing.assert_array_equal(np.asarray(op.matvec(w)),
-                                  np.asarray(Xa @ w))
+                                  np.asarray(w @ Xa.T))
+    np.testing.assert_allclose(np.asarray(op.matvec(w)),
+                               np.asarray(Xa @ w), rtol=1e-6, atol=1e-6)
+    lanes = jnp.asarray(rng.normal(size=(4, 8)).astype(np.float32))
+    assert jax.vmap(op.matvec)(lanes).shape == (4, 30)
+    assert jax.make_jaxpr(jax.vmap(op.matvec))(lanes).jaxpr.eqns[-1] \
+        .primitive.name == "dot_general"  # no transpose after the product
+    W = jnp.asarray(rng.normal(size=(8, 3)).astype(np.float32))
+    np.testing.assert_array_equal(np.asarray(op.matvec(W)),
+                                  np.asarray(Xa @ W))
     sw = jnp.asarray(rng.rand(30).astype(np.float32))
     T = jnp.asarray(rng.normal(size=(30, 2)).astype(np.float32))
     G, b = op.weighted_gram_rhs(sw, T)
