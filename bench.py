@@ -413,22 +413,19 @@ def packed_lbfgs_fit_flops(nnz, k, n_iter):
 
 
 def kernels_aux(quick=False):
-    """Measured readout of the on-chip kernel push (ISSUE 10): Pallas
-    packed-CSR kernel parity + per-mode fit walls on the BASELINE
-    config-3 shape, kernel_mode round attribution, the chunked-gram
-    satellite, and the quantized serving tier (per-dtype parity,
-    latency split, compile invariant). On CPU the pallas legs run the
-    interpreter at reduced shapes (parity evidence only — the walls
-    that matter are the chip leg's); MFU fields appear only for clean
-    on-chip runs, per ``mfu_fields``. Best-effort: a dict with "error"
-    on any failure."""
+    """Measured readout of the kernel push (ISSUE 10): the packed
+    fit's warm wall on the BASELINE config-3 shape, kernel_mode round
+    attribution, the chunked-gram satellite, and the quantized serving
+    tier (per-dtype parity, latency split, compile invariant). On CPU
+    the shapes are reduced (the walls that matter are the chip leg's);
+    MFU fields appear only for clean on-chip runs, per ``mfu_fields``.
+    Best-effort: a dict with "error" on any failure."""
     import jax
     import jax.numpy as jnp
 
     from skdist_tpu import sparse as sx
     from skdist_tpu.distribute.search import DistGridSearchCV
     from skdist_tpu.models import LogisticRegression
-    from skdist_tpu.ops import pallas_sparse as ps
     from skdist_tpu.parallel import TPUBackend, compile_cache
     from skdist_tpu.serve import ServingEngine
 
@@ -437,38 +434,8 @@ def kernels_aux(quick=False):
         on_tpu = device["platform"] == "tpu"
         out = {"platform": device["platform"], "device": device}
 
-        # ---- raw kernel parity (interpret off-chip, compiled on-chip)
-        rng = np.random.RandomState(0)
-        parity = 0.0
-        for (n, d, m, k) in ((64, 256, 6, 3), (40, 96, 4, 1),
-                             (128, 512, 9, 8)):
-            idx = rng.randint(0, d, size=(n, m)).astype(np.int32)
-            val = rng.randn(n, m).astype(np.float32)
-            pad = rng.rand(n, m) < 0.3
-            idx[pad] = 0
-            val[pad] = 0.0
-            # intercept column, exactly as LinearOperator appends it
-            idx = np.concatenate(
-                [idx, np.full((n, 1), d, np.int32)], axis=1)
-            val = np.concatenate(
-                [val, np.ones((n, 1), np.float32)], axis=1)
-            W = rng.randn(d + 1, k).astype(np.float32)
-            r = rng.randn(n, k).astype(np.float32)
-            a = (jnp.asarray(idx), jnp.asarray(val))
-            parity = max(parity, float(np.max(np.abs(
-                np.asarray(ps.packed_matvec(*a, jnp.asarray(W),
-                                            S=8, DB=128))
-                - np.asarray(sx.packed_matvec(*a, jnp.asarray(W)))
-            ))))
-            parity = max(parity, float(np.max(np.abs(
-                np.asarray(ps.packed_rmatvec(*a, jnp.asarray(r), d + 1,
-                                             S=8, DB=128))
-                - np.asarray(sx.packed_rmatvec(*a, jnp.asarray(r),
-                                               d + 1))
-            ))))
-        out["pallas_kernel_parity_max_diff"] = parity
-
         # ---- chunked-gram satellite: chunked == unchunked
+        rng = np.random.RandomState(0)
         n, d, m = 96, 64, 5
         gi = rng.randint(0, d, size=(n, m)).astype(np.int32)
         gv = rng.randn(n, m).astype(np.float32)
@@ -482,10 +449,8 @@ def kernels_aux(quick=False):
         out["gram_chunked_max_diff"] = float(
             np.max(np.abs(g_full - g_chunk)))
 
-        # ---- per-mode fit walls through the ONE matvec interface.
-        # CPU legs shrink the shape (interpret-mode pallas is the
-        # correctness vehicle, not a wall worth reporting); the chip
-        # leg runs the BASELINE config-3 shape per mode.
+        # ---- the packed fit's warm wall. The CPU leg shrinks the
+        # shape; the chip leg runs the BASELINE config-3 shape.
         if on_tpu and not quick:
             ns, ds, nnz_row = 2000, 4096, 40
         else:
@@ -493,52 +458,25 @@ def kernels_aux(quick=False):
         Xs, ys = make_20news_sparse(n=ns, d=ds, nnz_row=nnz_row,
                                     k=3 if quick or not on_tpu else 20)
         grid = {"C": [0.1, 1.0]}
-        # converged settings: the cross-mode parity readout must
-        # measure the KERNELS, not two different unconverged
-        # trajectories quantised through the accuracy scorer
         est = LogisticRegression(max_iter=80, tol=1e-6, engine="xla")
-        modes = ["gather", "dense", "pallas"] if on_tpu else (
-            ["gather", "pallas"])
-        walls, kernel_modes = {}, {}
         n_fits = len(grid["C"]) * 3
-        for mode in modes:
-            old = os.environ.get(sx.SPARSE_MATVEC_ENV)
-            os.environ[sx.SPARSE_MATVEC_ENV] = mode
-            try:
-                bk = TPUBackend(reuse_broadcast=True)
+        bk = TPUBackend(reuse_broadcast=True)
 
-                def run():
-                    return DistGridSearchCV(
-                        est, grid, backend=bk, cv=3,
-                        scoring="accuracy", refit=False,
-                    ).fit(Xs, ys)
+        def run():
+            return DistGridSearchCV(
+                est, grid, backend=bk, cv=3,
+                scoring="accuracy", refit=False,
+            ).fit(Xs, ys)
 
-                run()  # cold (compiles)
-                t0 = time.perf_counter()
-                gs2 = run()
-                walls[mode] = round(time.perf_counter() - t0, 3)
-                kernel_modes[mode] = (bk.last_round_stats or {}).get(
-                    "kernel_mode")
-                if mode == "gather":
-                    scores_ref = np.asarray(
-                        gs2.cv_results_["mean_test_score"])
-                else:
-                    out[f"{mode}_cv_parity_vs_gather"] = float(np.max(
-                        np.abs(np.asarray(
-                            gs2.cv_results_["mean_test_score"])
-                            - scores_ref)))
-            finally:
-                if old is None:
-                    os.environ.pop(sx.SPARSE_MATVEC_ENV, None)
-                else:
-                    os.environ[sx.SPARSE_MATVEC_ENV] = old
-        out["mode_warm_wall_s"] = walls
-        out["kernel_mode_attribution"] = kernel_modes
-        out["resolved_auto_mode"] = sx.resolve_matvec_mode()
-        # fits/sec + MFU for the winning packed mode (model FLOPs are
-        # the O(nnz) packed contraction bill; off-chip the MFU pair is
-        # omitted by mfu_fields' platform gate)
-        best_mode = min(walls, key=walls.get)
+        run()  # cold (compiles)
+        t0 = time.perf_counter()
+        run()
+        wall = round(time.perf_counter() - t0, 3)
+        out["packed_warm_wall_s"] = wall
+        out["kernel_mode"] = (bk.last_round_stats or {}).get("kernel_mode")
+        # fits/sec + MFU for the packed fit (model FLOPs are the O(nnz)
+        # packed contraction bill; off-chip the MFU pair is omitted by
+        # mfu_fields' platform gate)
         nnz = int(Xs.nnz)
         k_cls = int(len(np.unique(ys)))
         probe = LogisticRegression(
@@ -546,11 +484,10 @@ def kernels_aux(quick=False):
         ).fit(Xs, ys)
         n_iter = float(np.max(np.asarray(probe.n_iter_)))
         flops_fit = packed_lbfgs_fit_flops(nnz, k_cls, n_iter)
-        out["packed_fits_per_s"] = round(n_fits / walls[best_mode], 2)
-        out["best_mode"] = best_mode
+        out["packed_fits_per_s"] = round(n_fits / wall, 2)
         out["model_gflops_per_fit"] = round(flops_fit / 1e9, 3)
         out["mfu_packed"] = mfu_fields(
-            flops_fit * n_fits / walls[best_mode] / 1e12,
+            flops_fit * n_fits / wall / 1e12,
             passes=_F32_HIGHEST_PASSES,
             basis=f"packed O(nnz) basis, n_iter={n_iter:.0f}",
             device=device,
@@ -1994,11 +1931,11 @@ def _streaming_main(quick=False):
 
 def _kernels_main(quick=False):
     """Standalone capture of the on-chip kernel-push readout →
-    ``BENCH_kernels_r11.json`` (Pallas sparse parity, per-matvec-mode
-    warm walls + fits/sec with the packed-FLOPs MFU basis, kernel_mode
-    attribution, quantized-serving per-dtype parity/latency split,
-    compile invariant). Off-chip this is the correctness capture; the
-    chip leg re-runs it for the BENCH_r11 headline."""
+    ``BENCH_kernels_r11.json`` (the packed fit's warm wall + fits/sec
+    with the packed-FLOPs MFU basis, kernel_mode attribution,
+    quantized-serving per-dtype parity/latency split, compile
+    invariant). Off-chip this is the correctness capture; the chip leg
+    re-runs it for the BENCH_r11 headline."""
     import jax
 
     payload = {

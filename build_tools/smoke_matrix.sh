@@ -49,16 +49,13 @@
 #                             reused, <=1e-5 vs uninterrupted); lane
 #                             guard adds <=2% warm wall and 0 compiles
 #                             (fault-tolerance PR).
-#   kernels_smoke.py        — on-chip kernel push: interpret-mode
-#                             Pallas packed-CSR kernel parity <= 1e-5
-#                             vs the XLA kernels (+ identical batched
-#                             CV scores through mode='pallas'),
-#                             chunked-gram parity, int8/bf16
-#                             registration parity inside the
-#                             documented bound with smaller staged
-#                             params, 0 post-warmup compiles across
-#                             all three serve_dtype variants
-#                             (Pallas kernels + quantized serving PR).
+#   kernels_smoke.py        — kernel push: the packed CV grid's
+#                             kernel_mode attribution, chunked-gram
+#                             parity, int8/bf16 registration parity
+#                             inside the documented bound with smaller
+#                             staged params, 0 post-warmup compiles
+#                             across all three serve_dtype variants
+#                             (quantized serving PR).
 #   elastic_smoke.py        — elastic execution: a specific mesh
 #                             participant preempted at round 2 of a
 #                             checkpointed search -> mesh shrinks once,

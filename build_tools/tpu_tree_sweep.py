@@ -1,8 +1,7 @@
-"""On-platform sweep for the forest histogram kernel (VERDICT item 3)
-AND the packed-CSR matvec kernels (ROADMAP item 4).
+"""On-platform sweep for the forest histogram kernel (VERDICT item 3).
 
-Forest leg — two passes on the NOTES benchmark shape (20k x 54, 7
-classes, depth 8, 32 bins):
+Two passes on the NOTES benchmark shape (20k x 54, 7 classes, depth 8,
+32 bins):
 
 1. RANKING: 20-tree forests across hist_mode x hist_block configs
    (cold + warm walls each);
@@ -16,17 +15,6 @@ calibrating ``auto`` for the current platform. Block-size variants are
 timed through that same mechanism (write candidate entry, fit under
 ``auto``) so the sweep exercises the code path users run.
 
-Sparse leg (``--sparse``, or riding along after the forest leg):
-micro-benchmarks the packed matvec/rmatvec contraction pair per mode —
-``gather`` (XLA gather + scatter-add), ``dense``
-(rebuild-once + MXU matmuls), ``pallas`` (the VMEM-rebuild kernels of
-``ops/pallas_sparse.py``; only where compiled Pallas targets the
-platform — the interpreter is never a candidate) — on the BASELINE
-config-3 packed shape, and persists the winner to
-``skdist_tpu/models/sparse_calib.json`` via
-:func:`sparse.record_matvec_calibration`, which is exactly what
-``resolve_matvec_mode()`` (the packed fits' ``'auto'``) consults.
-
 Run ON the chip, as the one process that holds it (no JAX_PLATFORMS
 override).
 """
@@ -35,8 +23,6 @@ import json
 import os
 import sys
 import time
-
-import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
@@ -60,103 +46,6 @@ def time_forest(X, y, n_estimators, repeats=2, **kw):
         f.fit(X, y)
         walls.append(time.perf_counter() - t0)
     return walls
-
-
-#: a non-gather mode must beat gather by this factor on the BINARY
-#: round trip before the sweep records it as the platform default:
-#: gather is today's pinned path (numerics bit-for-bit reproduced by
-#: every historical artifact), and flipping the fleet's default
-#: contraction for a <2x win trades numeric churn for noise
-SPARSE_MIN_WIN = 2.0
-
-
-def sparse_matvec_sweep(repeats=3):
-    """Rank the packed matvec modes on this platform and persist the
-    winner to ``sparse_calib.json``. Returns the recorded entry.
-
-    The measured pair is the solver round trip: one ``X @ W`` plus the
-    grad through it (``X.T @ r`` — on the pallas path that exercises
-    the custom-VJP rmatvec kernel) at the BASELINE config-3 packed
-    shape. The CALIBRATING shape is the binary lane (``k=1`` — OvR
-    columns and CV fold tasks, the dominant packed workload); the
-    joint-multinomial ``k=20`` round trip is recorded alongside as
-    evidence (on XLA CPU the k=20 scatter-add is 100-200x slower than
-    the rebuilt matmul — the very pathology the pallas kernels exist
-    to fix on chip). Each mode runs through the SAME ``LinearOperator``
-    interface the fits use."""
-    import jax
-    import jax.numpy as jnp
-
-    from bench import make_20news_sparse
-    from skdist_tpu import sparse as sx
-    from skdist_tpu.ops import pallas_interpret
-
-    platform = jax.devices()[0].platform
-    X, y = make_20news_sparse(n=4000, d=4096, nnz_row=40, k=20)
-    packed = sx.pack_for_fit(X)
-    assert packed is not None, "sweep shape must route packed"
-    rng = np.random.RandomState(0)
-
-    modes = ["gather", "dense"]
-    if not pallas_interpret():
-        # off-TPU 'pallas' is the interpreter — never a candidate a
-        # CPU calibration should record
-        modes.append("pallas")
-
-    ranking = {}  # mode -> {k: wall}
-    for mode in modes:
-        try:
-            op = sx.LinearOperator(packed, fit_intercept=True, mode=mode)
-            walls = {}
-            for k in (1, 20):
-                shape = ((packed.n_cols + 1,) if k == 1
-                         else (packed.n_cols + 1, k))
-                W = jnp.asarray(rng.randn(*shape).astype(np.float32))
-
-                @jax.jit
-                def round_trip(W):
-                    def f(w):
-                        return jnp.sum(op.matvec(w) ** 2)
-
-                    return jax.value_and_grad(f)(W)
-
-                jax.block_until_ready(round_trip(W))  # compile
-                times = []
-                for _ in range(repeats):
-                    t0 = time.perf_counter()
-                    jax.block_until_ready(round_trip(W))
-                    times.append(time.perf_counter() - t0)
-                walls[f"k{k}"] = round(min(times), 5)
-            ranking[mode] = walls
-            print(json.dumps({"sparse_matvec": mode, **walls,
-                              "platform": platform}), flush=True)
-        except Exception as exc:  # one broken mode must not eat the rest
-            print(json.dumps({"sparse_matvec": mode,
-                              "error": repr(exc)[:300]}), flush=True)
-    if not ranking:
-        print(json.dumps({"error": "every sparse matvec mode failed"}),
-              flush=True)
-        return None
-    best = min(ranking, key=lambda m: ranking[m]["k1"])
-    if (best != "gather" and "gather" in ranking
-            and ranking["gather"]["k1"]
-            < SPARSE_MIN_WIN * ranking[best]["k1"]):
-        best = "gather"  # not a decisive win: keep the pinned default
-    entry = sx.record_matvec_calibration(
-        platform, best,
-        measured={
-            "round_trip_s": ranking,
-            "min_win": SPARSE_MIN_WIN,
-            "shape": [int(X.shape[0]), int(X.shape[1])],
-            "m": packed.m,
-            "captured_at": time.strftime(
-                "%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
-        },
-        source="build_tools/tpu_tree_sweep.py sparse_matvec_sweep",
-    )
-    print(f"# sparse matvec calibration written: {json.dumps(entry)}",
-          flush=True)
-    return entry
 
 
 def main():
@@ -292,13 +181,6 @@ def main():
         "platform": platform,
     }), flush=True)
 
-    # the sparse matvec leg rides along: one full sweep run calibrates
-    # BOTH 'auto' tables (hist_calib.json + sparse_calib.json)
-    sparse_matvec_sweep()
-
 
 if __name__ == "__main__":
-    if "--sparse" in sys.argv:
-        sparse_matvec_sweep()
-    else:
-        main()
+    main()

@@ -13,10 +13,9 @@ each phase against a reference that does not share its code:
   forest         DistRandomForestClassifier, 256 trees of depth 8 on
                  200,000 x 28, binary
   boosting       DistHistGradientBoostingClassifier on the same rows
-  sparse         LogisticRegression on packed CSR, in the matvec mode
-                 the chip resolves
-  kernels        ops.pallas_hist / ops.pallas_sparse called directly,
-                 compiled, against the XLA form of each contraction
+  sparse         LogisticRegression on packed CSR
+  kernels        ops.pallas_hist called directly, compiled, against
+                 the XLA form of its contraction
   batch_predict  1,000,000 x 64 rows, 10 classes
   serving        an in-process ServingEngine with f32, bf16 and int8
                  registrations
@@ -157,19 +156,6 @@ def make_tabular(seed, n, d, k, noise=0.7):
     X = rng.normal(size=(n, d)).astype(np.float32)
     y = np.argmax(X @ W + noise * rng.normal(size=(n, k)), axis=1)
     return X, y
-
-
-def make_packed(seed, n, m, p):
-    """A packed-CSR pair (idx, val) of width ``m`` over ``p`` columns
-    with ~30% padding entries (idx 0, val 0), as ``pack_csr_rows``
-    leaves them."""
-    rng = np.random.RandomState(seed)
-    idx = rng.randint(0, p, size=(n, m)).astype(np.int32)
-    val = rng.randn(n, m).astype(np.float32)
-    pad = rng.rand(n, m) < 0.3
-    idx[pad] = 0
-    val[pad] = 0.0
-    return idx, val
 
 
 # ---------------------------------------------------------------------------
@@ -492,7 +478,7 @@ def phase_boosting(seed=2, n=200_000, d=28, max_iter=30, max_depth=5,
 
 
 # ---------------------------------------------------------------------------
-# sparse — packed CSR in the matvec mode the chip resolves
+# sparse — packed CSR against the dense fit
 # ---------------------------------------------------------------------------
 
 def phase_sparse(X, y):
@@ -505,7 +491,6 @@ def phase_sparse(X, y):
     Xs = sp.csr_matrix(X)
     check(sx.would_pack(Xs), "the CSR input would not route packed: "
           f"{sx.pack_decision(Xs)}")
-    mode = sx.resolve_matvec_mode()
     # a fit that CONVERGES inside its budget: two f32 trajectories cut
     # off early (C=1 at 100 iterations) sit 2.8e-2 apart in probability
     # on the CPU as well — summation order, not the packing
@@ -530,7 +515,6 @@ def phase_sparse(X, y):
     return {
         "shape": [int(X.shape[0]), int(X.shape[1]), int(len(np.unique(y)))],
         "nnz": int(Xs.nnz), "max_row_nnz": int(np.diff(Xs.indptr).max()),
-        "matvec_mode_resolved": mode,
         "packed_cold_s": round(packed_cold_s, 2),
         "packed_warm_s": round(packed_warm_s, 2),
         "n_iter_packed": int(np.max(np.asarray(packed.n_iter_))),
@@ -540,21 +524,18 @@ def phase_sparse(X, y):
 
 
 # ---------------------------------------------------------------------------
-# kernels — the Pallas programs, compiled, against their XLA forms
+# kernels — the Pallas program, compiled, against its XLA form
 # ---------------------------------------------------------------------------
 
 def phase_kernels(seed=5, hist_shape=(200_000, 28, 64, 32, 2),
-                  sparse_shape=(11_314, 128, 4096, 20), interpret=False,
-                  pallas_forest=(20_000, 16, 6)):
-    """``interpret`` is False on the chip — the kernels are compiled and
+                  interpret=False, pallas_forest=(20_000, 16, 6)):
+    """``interpret`` is False on the chip — the kernel is compiled and
     shown compiled; the CPU test passes True, the only way a CPU runs
-    them."""
+    it."""
     import jax
     import jax.numpy as jnp
 
-    from skdist_tpu import sparse as sx
     from skdist_tpu.distribute.ensemble import DistRandomForestClassifier
-    from skdist_tpu.ops import pallas_sparse as ps
     from skdist_tpu.ops.pallas_hist import level_histogram
     from skdist_tpu.parallel import TPUBackend
 
@@ -615,40 +596,6 @@ def phase_kernels(seed=5, hist_shape=(200_000, 28, 64, 32, 2),
     # averages it down
     compare("level_histogram", hist_pallas, jax.jit(hist_scatter),
             (Xb, key, Ych), rtol=5e-3)
-
-    # --- packed contractions vs the densified matmul
-    n, m, p, k = sparse_shape
-    idx_np, val_np = make_packed(seed, n, m, p)
-    idx, val = jnp.asarray(idx_np), jnp.asarray(val_np)
-    W = jnp.asarray(rng.randn(p, k).astype(np.float32))
-    r = jnp.asarray(rng.randn(n, k).astype(np.float32))
-    sw = jnp.asarray(rng.rand(n).astype(np.float32))
-    hi = jax.lax.Precision.HIGHEST
-
-    # densified ONCE (an XLA scatter, half a minute to compile for the
-    # chip): the three references are then plain matmuls on it
-    D = jax.jit(lambda idx, val: sx.packed_to_dense(idx, val, p))(idx, val)
-
-    out["sparse_shape"] = list(sparse_shape)
-    compare(
-        "packed_matvec",
-        lambda idx, val, W: ps.packed_matvec(idx, val, W,
-                                             interpret=interpret),
-        lambda idx, val, W: jnp.matmul(D, W, precision=hi),
-        (idx, val, W), rtol=5e-3)
-    compare(
-        "packed_rmatvec",
-        lambda idx, val, r: ps.packed_rmatvec(idx, val, r, p,
-                                              interpret=interpret),
-        lambda idx, val, r: jnp.matmul(D.T, r, precision=hi),
-        (idx, val, r), rtol=5e-3)
-    compare(
-        "packed_weighted_gram",
-        lambda idx, val, sw: ps.packed_weighted_gram(
-            idx, val, sw, p, interpret=interpret),
-        lambda idx, val, sw: jnp.matmul(D.T, D * sw[:, None],
-                                        precision=hi),
-        (idx, val, sw), rtol=5e-3)
 
     # --- one small forest through the public path with the Pallas engine
     forest_rows, n_trees, depth = pallas_forest

@@ -32,7 +32,6 @@ from ..sparse import (
     is_packed,
     matvec_any,
     pack_for_fit,
-    resolve_matvec_mode,
     sparse_to_dense_f32,
     would_pack,
 )
@@ -264,9 +263,8 @@ def _meta_signature(meta):
         meta.get("y_ndim"),
         # the sparse plane is compile-shaping: a packed-X kernel and a
         # dense-X kernel of the same family must never share a cache
-        # entry, and neither must two packed matvec modes
+        # entry
         meta.get("x_format"),
-        meta.get("x_matvec"),
     )
 
 
@@ -279,7 +277,6 @@ def _annotate_x_meta(meta, X):
         # a cache entry with a padded pair's; the counts are for the
         # round stats (:func:`annotate_round_kernel_mode`)
         meta["x_format"] = "packed"
-        meta["x_matvec"] = resolve_matvec_mode()
         if isinstance(X, PackedX):
             meta["x_nnz"] = int(np.count_nonzero(np.asarray(X.val)))
             meta["x_slots"] = int(np.prod(X.idx.shape))
@@ -296,13 +293,12 @@ def _annotate_stream_meta(meta, dataset):
     as on the resident path."""
     if getattr(dataset, "x_format", "dense") == "packed":
         meta["x_format"] = "packed"
-        meta["x_matvec"] = resolve_matvec_mode()
     return meta
 
 
 def kernel_mode_of(meta):
     """The kernel variant a fit with this ``meta`` runs — ``"dense"``
-    or ``"packed_<matvec mode>"`` for the matvec families, or the
+    or ``"packed_gather"`` for the matvec families, or the
     family tag a non-linear family stamps in ``meta["kernel_family"]``
     (the GBDT histogram trees stamp ``"hist_tree"``). The batched
     dispatch sites stamp it into
@@ -313,7 +309,7 @@ def kernel_mode_of(meta):
     if family is not None:
         return family
     if meta.get("x_format") == "packed":
-        return "packed_" + meta.get("x_matvec", "gather")
+        return "packed_gather"
     return "dense"
 
 
@@ -326,7 +322,7 @@ def annotate_round_kernel_mode(backend, meta):
     stats = getattr(backend, "last_round_stats", None)
     if isinstance(stats, dict):
         mode = stats["kernel_mode"] = kernel_mode_of(meta)
-        for key in ("x_nnz", "x_slots", "x_matvec"):
+        for key in ("x_nnz", "x_slots"):
             if key in meta:
                 stats[key] = meta[key]
         from ..obs import metrics as obs_metrics
@@ -334,18 +330,6 @@ def annotate_round_kernel_mode(backend, meta):
         obs_metrics.counter("rounds.kernel_mode").inc(
             1, kernel_mode=str(mode)
         )
-
-
-def _linear_op(X, fit_intercept, meta, matmul_dtype=None):
-    """The one construction point of the fit problems' matvec
-    interface (``sparse.LinearOperator``): dense X reproduces the
-    historical expressions verbatim; packed X routes through the
-    gather/scatter kernels in the mode ``meta`` resolved at prep
-    time."""
-    return LinearOperator(
-        X, fit_intercept, matmul_dtype=matmul_dtype,
-        mode=meta.get("x_matvec", "gather"),
-    )
 
 
 def _ray_loss(matvec, row_loss, reg_loss, scope, linear=True):
@@ -369,7 +353,7 @@ def _ray_loss(matvec, row_loss, reg_loss, scope, linear=True):
     one transposed product ``X̃ᵀ r`` of ``r = ∇row_loss(z0 + t·dz)`` —
     three products an iteration however often the search halves.
     ``jax.vjp`` hands out ``z0`` and that transpose together, through
-    the ``custom_vjp`` of the bucketed and Pallas products as well.
+    the ``custom_vjp`` of the bucketed product as well.
     ``z0`` is taken from ``w`` anew each direction, so rounding does
     not build up along a solve."""
 
@@ -1047,8 +1031,9 @@ class LogisticRegression(_LbfgsFitMixin, _LinearClassifierBase):
             # — autodiff of the gather matvec IS the scatter-add
             # X.T @ r, so the whole L-BFGS solve runs O(nnz) per
             # iteration with no second code path in the solver
-            op = _linear_op(X, fit_intercept, meta,
-                            matmul_dtype="bfloat16" if bf16 else None)
+            op = LinearOperator(
+                X, fit_intercept,
+                matmul_dtype="bfloat16" if bf16 else None)
             p = op.p
             sw = _apply_class_weight(sw, y_idx, k, class_weight, cw_arr)
             d = meta["n_features"]
@@ -1250,7 +1235,7 @@ class LinearSVC(_LbfgsFitMixin, _LinearClassifierBase):
             # LogisticRegression._build_fit_problem); data/reg split as
             # there — the squared-hinge sum is row-additive (streamed
             # per block), the ridge term is evaluated once
-            op = _linear_op(X, fit_intercept, meta)
+            op = LinearOperator(X, fit_intercept)
             p = op.p
             sw = _apply_class_weight(sw, y_idx, k, class_weight, cw_arr)
             if binary:
@@ -1440,7 +1425,7 @@ class SGDClassifier(_LinearClassifierBase):
             # dense or packed-CSR X behind one matvec interface; the
             # mini-batch forms gather the batch's packed rows, so each
             # SGD step is O(batch nnz) instead of O(batch·d)
-            op = _linear_op(X, fit_intercept, meta)
+            op = LinearOperator(X, fit_intercept)
             n = op.n
             p = op.p
             sw_full = _apply_class_weight(sw, y_idx, k, class_weight, cw_arr)
@@ -1661,7 +1646,7 @@ class _RidgeKernelMixin:
     def _solve(op, T, sw, alpha, d):
         """Weighted ridge: solve (XᵀSX + αI₀)W = XᵀST; intercept column
         unpenalised (I₀ has zero at the bias position). ``op`` is the
-        matvec interface (``_linear_op``): dense X keeps the MXU gram
+        matvec interface (``LinearOperator``): dense X keeps the MXU gram
         matmul verbatim; packed X builds the gram by the m² scatter
         (O(nnz·m) instead of O(n·d²))."""
         G, b = op.weighted_gram_rhs(sw, T)  # (p, p), (p, k)
@@ -1719,7 +1704,7 @@ class Ridge(_LinearModelBase, RegressorMixin, _RidgeKernelMixin):
 
         def kernel(X, y, sw, hyper, aux=None):
             alpha = hyper["alpha"]
-            op = _linear_op(X, fit_intercept, meta)
+            op = LinearOperator(X, fit_intercept)
             T = y.reshape(y.shape[0], -1)
             W = cls._solve(op, T, sw, alpha, d)
             if meta.get("y_ndim", 1) == 1:
@@ -1806,7 +1791,7 @@ class RidgeClassifier(_LinearClassifierBase, _RidgeKernelMixin):
 
         def kernel(X, y_idx, sw, hyper, aux=None):
             alpha = hyper["alpha"]
-            op = _linear_op(X, fit_intercept, meta)
+            op = LinearOperator(X, fit_intercept)
             sw = _apply_class_weight(sw, y_idx, k, class_weight, cw_arr)
             if k <= 2:
                 T = jnp.where(y_idx == (k - 1), 1.0, -1.0).astype(op.dtype)[:, None]

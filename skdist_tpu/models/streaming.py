@@ -722,9 +722,8 @@ def _fit_gram_stream(backend, est_cls, meta, static, dataset, row_arrays,
     iterate to seed; ``rung_hook`` likewise — a one-pass direct solve
     has no pass boundaries for a rung to act between (an adaptive
     search over a gram family stays exhaustive and warns)."""
-    from .linear import (
-        _apply_class_weight, _linear_op, maybe_exact_matmuls,
-    )
+    from ..sparse import LinearOperator
+    from .linear import _apply_class_weight, maybe_exact_matmuls
 
     st = dict(static)
     fit_intercept = st["fit_intercept"]
@@ -735,7 +734,7 @@ def _fit_gram_stream(backend, est_cls, meta, static, dataset, row_arrays,
 
     def gram_kernel(block, tc):
         Xb, yb, swb, hyper = derive(block, tc["task"])
-        op = _linear_op(Xb, fit_intercept, meta)
+        op = LinearOperator(Xb, fit_intercept)
         if k is not None:
             swb = _apply_class_weight(swb, yb, k, class_weight, cw_arr)
             if k <= 2:

@@ -14,10 +14,9 @@ def pallas_interpret():
     interpreter instead of being compiled.
 
     The ONE place that decides it: every ``interpret=None`` of
-    ``ops/pallas_hist.py`` and ``ops/pallas_sparse.py`` resolves here,
-    and nothing else in the package may pass ``interpret=True``. It is
-    ``False`` whenever the default backend is a TPU — there a kernel
-    compiles or raises, it never runs interpreted — and ``True``
-    elsewhere, which is what lets the CPU tests fit through
-    ``hist_mode='pallas'`` and the ``pallas`` matvec mode."""
+    ``ops/pallas_hist.py`` resolves here, and nothing else in the
+    package may pass ``interpret=True``. It is ``False`` whenever the
+    default backend is a TPU — there a kernel compiles or raises, it
+    never runs interpreted — and ``True`` elsewhere, which is what lets
+    the CPU tests fit through ``hist_mode='pallas'``."""
     return jax.default_backend() != "tpu"

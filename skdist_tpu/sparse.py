@@ -24,10 +24,9 @@ batch prediction alike:
 - the two contractions every linear solver needs:
   :func:`packed_matvec` (``X @ W``: gather + row-dot, O(nnz·k)) and
   :func:`packed_rmatvec` (``X.T @ r``: scatter-add over the packed
-  columns, O(nnz·k)) — plus :func:`packed_to_dense` (the
-  dense-matmul-on-packed variant: one device scatter rebuilds the dense
-  block, then the MXU runs ordinary matmuls; H2D still ships only the
-  packed pair) and :func:`packed_weighted_gram` (``XᵀSX`` via the m²
+  columns, O(nnz·k)) — plus :func:`packed_to_dense` (one device scatter
+  rebuilds the dense block: the predict path's fallback and the tests'
+  reference) and :func:`packed_weighted_gram` (``XᵀSX`` via the m²
   scatter, for the closed-form ridge family).
 - :class:`BucketedX` — the representation of a matrix whose row
   lengths are SKEWED (a vectorised text corpus: most documents near a
@@ -41,32 +40,19 @@ batch prediction alike:
   zeros and all (at 11,314 x 130,107 with 1.79 M stored elements:
   15,229 columns, 689 MB, for the 78 % of the elements they hold). A
   batch of weight matrices (``vmap``) rides on the gathers' contiguous
-  axis, not on an axis of its own. One contraction serves it in every
-  matvec mode but ``dense``: the Pallas rebuild kernels' work follows
-  ``n x d``, ten times the gathers' at that shape on a v5e.
+  axis, not on an axis of its own.
+- :class:`LinearOperator` — ``[X | 1]`` behind the five contractions
+  the fit problems take, one implementation of each per representation
+  (ndarray, :class:`PackedX`, :class:`BucketedX`), chosen by the type
+  of X and nothing else.
 - routing (:func:`pack_for_fit`): pack exactly when packing wins.
   The padded pair costs ``n·m·8`` bytes vs ``n·d·4`` dense, so the
-  decision is byte-driven (``d >= 2·m·savings``; savings default 4x,
-  see :data:`PACK_MIN_SAVINGS`); rows of skewed length
+  decision is byte-driven (``d >= 2·m·savings``; savings 4x, see
+  :data:`PACK_MIN_SAVINGS`); rows of skewed length
   (:data:`OUTLIER_FACTOR`) pack bucketed — there is no densify
   fallback for skew, which at the widths where it arises cannot fit.
   ``SKDIST_SPARSE_FIT=0`` disables packing entirely; ``=1``/``force``
   packs any 2-D sparse input.
-- matvec-mode selection (:func:`resolve_matvec_mode`): ``gather`` vs
-  ``dense`` (dense-matmul-on-packed) vs ``pallas`` (the on-chip
-  kernels of ``ops/pallas_sparse.py``: both contractions recast as
-  one-hot matmuls whose dense sub-block is rebuilt in VMEM — no
-  (n, d) tensor in HBM, no serialised gather/scatter) is a measured,
-  persisted decision per platform — the same calibration idiom as the
-  tree kernels' ``hist_mode`` (``models/hist_calib.py``): environment
-  override, then a committed ``sparse_calib.json`` table written by
-  on-platform sweeps (an extended ``build_tools/tpu_tree_sweep.py``
-  records both tables), then the heuristic default (``gather`` —
-  nnz-proportional everywhere; ``dense``/``pallas`` only win where an
-  MXU exists, which is the sweep's call to make). Off-TPU a selected
-  ``pallas`` runs through the Pallas interpreter — correct (the CPU
-  mesh tests it bitwise) but slow, so no CPU calibration ever picks
-  it.
 
 The 1-tuple-shape special case of scipy's 1-D sparse arrays
 (``csr_array`` of a vector) is handled ONCE here, in
@@ -77,7 +63,6 @@ exactly as the dense path treats a 1-D ndarray.
 import functools
 import json
 import os
-import threading
 
 import numpy as np
 
@@ -105,9 +90,6 @@ __all__ = [
     "bucketed_to_dense",
     "matvec_any",
     "LinearOperator",
-    "resolve_matvec_mode",
-    "get_matvec_calibration",
-    "record_matvec_calibration",
 ]
 
 #: kill switch / force switch for the packed fit plane: "0" restores
@@ -115,14 +97,10 @@ __all__ = [
 #: input regardless of the byte heuristic
 SPARSE_FIT_ENV = "SKDIST_SPARSE_FIT"
 
-#: explicit matvec-mode override: "gather" | "dense"
-SPARSE_MATVEC_ENV = "SKDIST_SPARSE_MATVEC"
-
 #: how many times smaller (bytes) the packed pair must be than the
 #: dense f32 matrix before the fit path packs — below this the MXU's
 #: dense matmul beats gather/scatter indexing
 PACK_MIN_SAVINGS = 4.0
-PACK_SAVINGS_ENV = "SKDIST_SPARSE_PACK_SAVINGS"
 
 #: nnz skew: when the max row nnz exceeds this multiple of the 95th
 #: percentile AND padding to it would inflate the packed pair past the
@@ -149,8 +127,6 @@ HEAD_MIN_DENSITY = 1.0 / 512
 #: ... while the head stays under this many bytes and an eighth of the
 #: columns
 HEAD_MAX_BYTES = 1 << 30
-
-_VALID_MATVEC_MODES = ("gather", "dense", "pallas")
 
 #: explicit row-chunk override for the weighted-gram contraction; the
 #: automatic chunking derives from the meminfo budget (see
@@ -367,18 +343,6 @@ def pack_csr_rows(X):
     return idx, val
 
 
-def _pack_savings():
-    env = os.environ.get(PACK_SAVINGS_ENV, "").strip()
-    if env:
-        try:
-            v = float(env)
-            if v > 0:
-                return v
-        except ValueError:
-            pass
-    return PACK_MIN_SAVINGS
-
-
 def _tiling(count, m):
     """``(tiles, rows a tile)`` of a bucket of ``count`` rows of width
     ``m``: as few tiles as hold it at :data:`TILE_SLOTS` slots a tile,
@@ -518,10 +482,10 @@ def pack_decision(X):
         return True, "forced via " + SPARSE_FIT_ENV, m
     if n == 0:
         return False, "empty input", m
-    if m * 8 * _pack_savings() > d * 4:
+    if m * 8 * PACK_MIN_SAVINGS > d * 4:
         return False, (
             f"dense-competitive density (m={m} of d={d}: the packed "
-            f"pair saves < {_pack_savings()}x device bytes)"
+            f"pair saves < {PACK_MIN_SAVINGS}x device bytes)"
         ), m
     return True, reason, m
 
@@ -633,7 +597,7 @@ def _check_densify_budget(n_rows, n_cols):
 
 
 # ---------------------------------------------------------------------------
-# device kernels: the two contractions + the dense-on-packed rebuild
+# device kernels: the two contractions + the dense rebuild
 # ---------------------------------------------------------------------------
 
 def packed_matvec(idx, val, W):
@@ -660,10 +624,9 @@ def packed_rmatvec(idx, val, r, n_cols):
 
 
 def packed_to_dense(idx, val, n_cols):
-    """Scatter-rebuild the dense ``(n, n_cols)`` block on device — the
-    dense-matmul-on-packed variant's one-time cost: H2D still ships
-    only the packed pair, and the MXU then runs ordinary matmuls.
-    Duplicate (row, col) entries accumulate, matching CSR semantics."""
+    """Scatter-rebuild the dense ``(n, n_cols)`` block on device (H2D
+    ships only the packed pair). Duplicate (row, col) entries
+    accumulate, matching CSR semantics."""
     n = idx.shape[0]
     rows = jnp.arange(n)[:, None]
     return jnp.zeros((n, n_cols), val.dtype).at[rows, idx].add(val)
@@ -907,7 +870,7 @@ def _bucket_rows(X, i):
 def bucketed_to_dense(X, fit_intercept=False):
     """Scatter-rebuild the dense ``(n, d[+1])`` matrix of a
     :class:`BucketedX` on device (the closed-form ridge family's gram
-    and ``mode='dense'``: feasible only where d is small)."""
+    and the predict path: feasible only where d is small)."""
     p = X.n_cols + int(bool(fit_intercept))
     dense = jnp.concatenate([
         packed_to_dense(idx.reshape(-1, idx.shape[2]),
@@ -935,130 +898,119 @@ def matvec_any(X, W):
 
 class LinearOperator:
     """The augmented design matrix ``X̃ = [X | 1]`` behind one matvec
-    interface, for dense ndarrays and :class:`PackedX` alike — what
-    lets the LogReg/LinearSVC/SGD/Ridge fit problems (and through them
-    the iteration-sliced solvers and the convergence-compacted
-    scheduler) run unchanged on sparse data.
+    interface — what lets the LogReg/LinearSVC/SGD/Ridge fit problems
+    (and through them the iteration-sliced solvers and the
+    convergence-compacted scheduler) run unchanged on sparse data.
+    ``LinearOperator(X, ...)`` is the implementation of X's
+    representation, and the type of X alone picks it: an ndarray's
+    (:class:`_DenseOperator`), a :class:`PackedX`'s
+    (:class:`_PackedOperator`) or a :class:`BucketedX`'s
+    (:class:`_BucketedOperator`). Each holds the five contractions:
+    ``matvec`` (``X̃ @ W``), ``rmatvec`` (``X̃ᵀ @ r``), the SGD
+    mini-batch forms ``row_matvec`` / ``row_rmatvec`` over rows ``i``,
+    and ``weighted_gram_rhs`` (``(X̃ᵀSX̃, (SX̃)ᵀT)``, the two sides of
+    the ridge normal equations).
 
-    Dense inputs reproduce the pre-sparse-plane expressions VERBATIM
-    (``Xa @ W``, ``Xa[i] @ W``, ``Xa.T @ (Xa * sw)``; for a weight
-    VECTOR the first is written ``W @ Xa.T``, the same contraction
-    lanes-first: :meth:`matvec`). What the L-BFGS family makes of them
-    has moved since its line search runs along a ray
-    (``models/linear._ray_loss``): a trial step's logits are the sum of
-    two such products, ``X̃ @ w + t · X̃ @ d``, and not one product of
-    ``w + t·d``, so trial values round differently than they did.
-    Packed inputs append the
-    intercept as one extra packed column (``idx=d, val=1``) and route
-    through the gather/scatter kernels above — or, in ``mode='dense'``,
-    through one :func:`packed_to_dense` rebuild followed by the exact
-    dense expressions (the MXU variant) — or, in ``mode='pallas'``,
-    through the on-chip Pallas kernels (``ops/pallas_sparse``): the
-    forward matvec carries a custom VJP whose backward IS the Pallas
-    rmatvec, so the solvers autodiff through it exactly as through the
-    gather form.
+    What the L-BFGS family makes of the products has moved since its
+    line search runs along a ray (``models/linear._ray_loss``): a trial
+    step's logits are the sum of two of them, ``X̃ @ w + t · X̃ @ d``,
+    and not one product of ``w + t·d``, so trial values round
+    differently than they did.
 
     ``matmul_dtype='bfloat16'`` applies the LogReg bf16 contract: bf16
-    operands, f32 accumulation, solver state f32. On the gather path
-    the products round to bf16 before the f32 row-sum — same
-    opt-in-screening precision class as the dense bf16 pass. The bf16
-    contract is DEFINED on the gather expressions: ``mode='pallas'``
-    under bf16 keeps the forward/backward on the gather path rather
-    than inventing a third precision class.
+    operands, f32 accumulation, solver state f32. On the packed
+    representations the products round to bf16 before the f32 row-sum
+    (inside the bucketed products' fused scan the compiler may keep
+    the float32 product) — same opt-in-screening precision class as
+    the dense bf16 pass.
     """
 
-    __slots__ = ("d", "p", "n", "Xa", "pidx", "pval", "bf16", "_Xmm",
-                 "dtype", "pallas", "_pmv", "bx", "_icpt")
+    __slots__ = ("d", "p", "n", "dtype", "bf16")
 
-    def __init__(self, X, fit_intercept, matmul_dtype=None, mode="gather"):
-        if mode not in _VALID_MATVEC_MODES:
-            raise ValueError(
-                f"mode must be one of {_VALID_MATVEC_MODES}; got {mode!r}"
-            )
+    def __new__(cls, X, fit_intercept, matmul_dtype=None):
+        if cls is LinearOperator:
+            cls = (_BucketedOperator if isinstance(X, BucketedX)
+                   else _PackedOperator if isinstance(X, PackedX)
+                   else _DenseOperator)
+        return object.__new__(cls)
+
+    def __init__(self, X, fit_intercept, matmul_dtype=None):
         self.bf16 = matmul_dtype == "bfloat16"
-        self._Xmm = None
-        self.pallas = False
-        self._pmv = None
-        self.bx = None
-        if isinstance(X, BucketedX):
-            # the intercept is NOT one more packed column here: a
-            # bucket's padding rows would carry it. It is the same
-            # column of ones applied beside the gathers (a broadcast
-            # add forward, a column sum backward: :func:`_spmm`).
-            self.dtype = X.rows[0][1].dtype
-            self.d, self.n = X.n_cols, X.shape[0]
-            self.p = self.d + int(bool(fit_intercept))
-            self._icpt = bool(fit_intercept)
-            self.pidx = self.pval = self.Xa = None
-            if mode == "dense":
-                self.Xa = bucketed_to_dense(X, fit_intercept)
-                return
-            self.bx = X
-            self._pmv = bucketed_matvec_with_vjp(X, self.bf16, self._icpt)
-            return
-        self.dtype = X.val.dtype if isinstance(X, PackedX) else X.dtype
-        if isinstance(X, PackedX):
-            d = X.n_cols
-            idx, val = X.idx, X.val
-            n = idx.shape[0]
-            if fit_intercept:
-                idx = jnp.concatenate(
-                    [idx, jnp.full((n, 1), d, idx.dtype)], axis=1
-                )
-                val = jnp.concatenate(
-                    [val, jnp.ones((n, 1), val.dtype)], axis=1
-                )
-            self.d, self.p, self.n = d, d + int(bool(fit_intercept)), n
-            if mode == "dense":
-                # rebuild once per trace; XLA keeps the block live for
-                # every matvec of the solve (HBM returns, H2D doesn't)
-                self.Xa = packed_to_dense(idx, val, self.p)
-                self.pidx = self.pval = None
-            else:
-                self.Xa = None
-                self.pidx, self.pval = idx, val
-                if mode == "pallas" and not self.bf16:
-                    from .ops.pallas_sparse import matvec_with_vjp
+        self.n, self.d = X.shape
+        self.p = self.d + int(bool(fit_intercept))
 
-                    self.pallas = True
-                    self._pmv = matvec_with_vjp(idx, val, self.p)
-        else:
-            if fit_intercept:
-                ones = jnp.ones((X.shape[0], 1), X.dtype)
-                Xa = jnp.concatenate([X, ones], axis=1)
-            else:
-                Xa = X
-            self.Xa = Xa
-            self.pidx = self.pval = None
-            self.d = X.shape[1]
-            self.p = Xa.shape[1]
-            self.n = X.shape[0]
 
-    # -- X̃ @ W ---------------------------------------------------------
+class _DenseOperator(LinearOperator):
+    """The pre-sparse-plane expressions VERBATIM (``Xa @ W``,
+    ``Xa[i] @ W``, ``Xa.T @ (Xa * sw)``; for a weight VECTOR the first
+    is written ``W @ Xa.T``, the same contraction lanes-first)."""
+
+    __slots__ = ("Xa", "_Xmm")
+
+    def __init__(self, X, fit_intercept, matmul_dtype=None):
+        super().__init__(X, fit_intercept, matmul_dtype)
+        self.dtype = X.dtype
+        if fit_intercept:
+            ones = jnp.ones((X.shape[0], 1), X.dtype)
+            X = jnp.concatenate([X, ones], axis=1)
+        self.Xa, self._Xmm = X, None
+
     def matvec(self, W):
-        if self.bx is not None:
-            return self._pmv(W)
-        if self.Xa is not None:
-            if self.bf16:
-                if self._Xmm is None:
-                    self._Xmm = self.Xa.astype(jnp.bfloat16)
-                # precision pinned so the library-wide 'highest'
-                # tracing default doesn't promote the bf16 pass
-                return jax.lax.dot_general(
-                    self._Xmm, W.astype(jnp.bfloat16),
-                    (((1,), (0,)), ((), ())),
-                    preferred_element_type=jnp.float32,
-                    precision=jax.lax.Precision.DEFAULT,
-                )
-            if W.ndim == 1:
-                # the same contraction, written lanes-first: under
-                # ``vmap`` over a round's lanes the logits come out
-                # ``(lanes, n)`` — ``Xa @ W`` batches to ``(n, lanes)``,
-                # which XLA holds lanes-minor wherever a ``while``
-                # carries it (the line search's trial steps), every row
-                # of 50 lanes padded to a 128-wide tile
-                return W @ self.Xa.T
-            return self.Xa @ W
+        if self.bf16:
+            if self._Xmm is None:
+                self._Xmm = self.Xa.astype(jnp.bfloat16)
+            # precision pinned so the library-wide 'highest'
+            # tracing default doesn't promote the bf16 pass
+            return jax.lax.dot_general(
+                self._Xmm, W.astype(jnp.bfloat16),
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.DEFAULT,
+            )
+        if W.ndim == 1:
+            # the same contraction, written lanes-first: under
+            # ``vmap`` over a round's lanes the logits come out
+            # ``(lanes, n)`` — ``Xa @ W`` batches to ``(n, lanes)``,
+            # which XLA holds lanes-minor wherever a ``while``
+            # carries it (the line search's trial steps), every row
+            # of 50 lanes padded to a 128-wide tile
+            return W @ self.Xa.T
+        return self.Xa @ W
+
+    def rmatvec(self, r):
+        return self.Xa.T @ r
+
+    def row_matvec(self, i, W):
+        return self.Xa[i] @ W
+
+    def row_rmatvec(self, i, g):
+        return self.Xa[i].T @ g
+
+    def weighted_gram_rhs(self, sw, T):
+        Xw = self.Xa * sw[:, None]
+        return self.Xa.T @ Xw, Xw.T @ T
+
+
+class _PackedOperator(LinearOperator):
+    """The intercept is one extra packed column (``idx=d, val=1``);
+    every product is a gather or a scatter of the kernels above."""
+
+    __slots__ = ("pidx", "pval")
+
+    def __init__(self, X, fit_intercept, matmul_dtype=None):
+        super().__init__(X, fit_intercept, matmul_dtype)
+        self.dtype = X.val.dtype
+        idx, val = X.idx, X.val
+        if fit_intercept:
+            idx = jnp.concatenate(
+                [idx, jnp.full((self.n, 1), self.d, idx.dtype)], axis=1
+            )
+            val = jnp.concatenate(
+                [val, jnp.ones((self.n, 1), val.dtype)], axis=1
+            )
+        self.pidx, self.pval = idx, val
+
+    def matvec(self, W):
         if self.bf16:
             g = W.astype(jnp.bfloat16)[self.pidx]
             v = self.pval.astype(jnp.bfloat16)
@@ -1067,169 +1019,63 @@ class LinearOperator:
             return jnp.sum(
                 (v[:, :, None] * g).astype(jnp.float32), axis=1
             )
-        if self.pallas:
-            return self._pmv(W)
         return packed_matvec(self.pidx, self.pval, W)
 
-    # -- X̃ᵀ @ r --------------------------------------------------------
     def rmatvec(self, r):
-        if self.bx is not None:
-            return bucketed_rmatvec(self.bx, r, self.bf16, self._icpt)
-        if self.Xa is not None:
-            return self.Xa.T @ r
-        if self.pallas:
-            from .ops.pallas_sparse import packed_rmatvec as pl_rmatvec
-
-            return pl_rmatvec(self.pidx, self.pval, r, self.p)
         return packed_rmatvec(self.pidx, self.pval, r, self.p)
 
-    # -- row-batch forms (the SGD mini-batch contractions) --------------
     def row_matvec(self, i, W):
-        if self.bx is not None:
-            out = sum(packed_matvec(idx, val, W[:self.d])
-                      for idx, val in _bucket_rows(self.bx, i))
-            if self.bx.head is not None:
-                out = out + self.bx.head[i] @ W[self.bx.head_cols]
-            return out + W[self.d] if self._icpt else out
-        if self.Xa is not None:
-            return self.Xa[i] @ W
-        if self.pallas:
-            # the SGD family computes its gradients explicitly (no
-            # autodiff through the row forms), so the raw kernels serve
-            from .ops.pallas_sparse import packed_matvec as pl_matvec
-
-            return pl_matvec(self.pidx[i], self.pval[i], W)
         return packed_matvec(self.pidx[i], self.pval[i], W)
 
     def row_rmatvec(self, i, g):
-        if self.bx is not None:
-            out = sum(packed_rmatvec(idx, val, g, self.d)
-                      for idx, val in _bucket_rows(self.bx, i))
-            if self.bx.head is not None:
-                out = out.at[self.bx.head_cols].add(self.bx.head[i].T @ g)
-            if not self._icpt:
-                return out
-            return jnp.concatenate([out, jnp.sum(g, axis=0)[None]])
-        if self.Xa is not None:
-            return self.Xa[i].T @ g
-        if self.pallas:
-            from .ops.pallas_sparse import packed_rmatvec as pl_rmatvec
-
-            return pl_rmatvec(self.pidx[i], self.pval[i], g, self.p)
         return packed_rmatvec(self.pidx[i], self.pval[i], g, self.p)
 
-    # -- closed-form ridge pieces ---------------------------------------
     def weighted_gram_rhs(self, sw, T):
-        """``(X̃ᵀSX̃, (SX̃)ᵀT)`` — the two solves of the ridge normal
-        equations. Dense keeps the historical op order exactly; the
-        packed gram runs the m² scatter in the gather/dense modes and
-        the on-chip Pallas rebuild-and-matmul form in ``mode='pallas'``
-        (``ops/pallas_sparse.packed_weighted_gram`` — the last packed
-        contraction with a Pallas kernel, interpret mode off-TPU),
-        while the rhs rides the mode's rmatvec."""
-        if self.bx is not None:
-            # a (p, p) gram exists only where p is small: rebuild
-            Xa = bucketed_to_dense(self.bx, self._icpt)
-            Xw = Xa * sw[:, None]
-            return Xa.T @ Xw, Xw.T @ T
-        if self.Xa is not None:
-            Xw = self.Xa * sw[:, None]
-            return self.Xa.T @ Xw, Xw.T @ T
-        if self.pallas:
-            from .ops.pallas_sparse import (
-                packed_weighted_gram as pl_gram,
-            )
-
-            G = pl_gram(self.pidx, self.pval, sw, self.p)
-        else:
-            G = packed_weighted_gram(self.pidx, self.pval, sw, self.p)
-        b = self.rmatvec(sw[:, None] * T)
-        return G, b
+        # the m² scatter for the gram; the rhs rides the rmatvec
+        G = packed_weighted_gram(self.pidx, self.pval, sw, self.p)
+        return G, self.rmatvec(sw[:, None] * T)
 
 
-# ---------------------------------------------------------------------------
-# matvec-mode calibration (the hist_mode idiom)
-# ---------------------------------------------------------------------------
+class _BucketedOperator(LinearOperator):
+    """The intercept is NOT one more packed column here: a bucket's
+    padding rows would carry it. It is the same column of ones applied
+    beside the gathers (a broadcast add forward, a column sum backward:
+    :func:`_spmm`). The forward product carries a custom VJP whose
+    backward IS the transposed product, so the solvers differentiate
+    the loss through it."""
 
-_DEFAULT_CALIB_PATH = os.path.join(
-    os.path.dirname(__file__), "models", "sparse_calib.json"
-)
-#: env override so sweeps can stage candidate entries in scratch files
-CALIB_PATH_ENV = "SKDIST_SPARSE_CALIB_PATH"
-_CALIB_LOCK = threading.Lock()
-_CALIB_CACHE = {}  # path -> (mtime, table)
+    __slots__ = ("bx", "_mv", "_icpt")
 
+    def __init__(self, X, fit_intercept, matmul_dtype=None):
+        super().__init__(X, fit_intercept, matmul_dtype)
+        self.dtype = X.rows[0][1].dtype
+        self.bx, self._icpt = X, bool(fit_intercept)
+        self._mv = bucketed_matvec_with_vjp(X, self.bf16, self._icpt)
 
-def _calib_path():
-    return os.environ.get(CALIB_PATH_ENV) or _DEFAULT_CALIB_PATH
+    def matvec(self, W):
+        return self._mv(W)
 
+    def rmatvec(self, r):
+        return bucketed_rmatvec(self.bx, r, self.bf16, self._icpt)
 
-def _load_calib():
-    path = _calib_path()
-    try:
-        mtime = os.stat(path).st_mtime
-    except OSError:
-        return {}
-    with _CALIB_LOCK:
-        ent = _CALIB_CACHE.get(path)
-        if ent is None or ent[0] != mtime:
-            try:
-                with open(path) as f:
-                    ent = (mtime, json.load(f))
-                _CALIB_CACHE[path] = ent
-            except (OSError, ValueError):
-                return ent[1] if ent else {}
-        return ent[1] or {}
+    def row_matvec(self, i, W):
+        out = sum(packed_matvec(idx, val, W[:self.d])
+                  for idx, val in _bucket_rows(self.bx, i))
+        if self.bx.head is not None:
+            out = out + self.bx.head[i] @ W[self.bx.head_cols]
+        return out + W[self.d] if self._icpt else out
 
+    def row_rmatvec(self, i, g):
+        out = sum(packed_rmatvec(idx, val, g, self.d)
+                  for idx, val in _bucket_rows(self.bx, i))
+        if self.bx.head is not None:
+            out = out.at[self.bx.head_cols].add(self.bx.head[i].T @ g)
+        if not self._icpt:
+            return out
+        return jnp.concatenate([out, jnp.sum(g, axis=0)[None]])
 
-def get_matvec_calibration(platform):
-    """Measured matvec-mode entry for ``platform`` or None."""
-    ent = _load_calib().get(platform)
-    if not isinstance(ent, dict) or ent.get("mode") not in _VALID_MATVEC_MODES:
-        return None
-    return ent
-
-
-def record_matvec_calibration(platform, mode, measured=None, source=None):
-    """Persist a sweep result (merging with other platforms' entries),
-    mirroring ``models/hist_calib.record_calibration``."""
-    if mode not in _VALID_MATVEC_MODES:
-        raise ValueError(
-            f"mode must be one of {_VALID_MATVEC_MODES}; got {mode!r}"
-        )
-    path = _calib_path()
-    with _CALIB_LOCK:
-        table = {}
-        try:
-            with open(path) as f:
-                table = json.load(f)
-        except (OSError, ValueError):
-            pass
-        ent = {"mode": mode}
-        if measured is not None:
-            ent["measured"] = measured
-        if source is not None:
-            ent["source"] = source
-        table[platform] = ent
-        with open(path, "w") as f:
-            json.dump(table, f, indent=1, sort_keys=True)
-        _CALIB_CACHE.pop(path, None)
-    return table[platform]
-
-
-def resolve_matvec_mode(platform=None):
-    """The packed matvec mode for this process: environment override →
-    calibration table → heuristic default (``gather`` — the
-    nnz-proportional kernels; ``dense`` is the rebuilt-MXU variant and
-    ``pallas`` the on-chip VMEM-rebuild kernels, either of which a
-    sweep may certify per platform — CPU sweeps never pick ``pallas``,
-    whose off-TPU form is the interpreter)."""
-    env = os.environ.get(SPARSE_MATVEC_ENV, "").strip().lower()
-    if env in _VALID_MATVEC_MODES:
-        return env
-    if platform is None:
-        platform = jax.default_backend()
-    calib = get_matvec_calibration(platform)
-    if calib is not None:
-        return calib["mode"]
-    return "gather"
+    def weighted_gram_rhs(self, sw, T):
+        # a (p, p) gram exists only where p is small: rebuild
+        Xa = bucketed_to_dense(self.bx, self._icpt)
+        Xw = Xa * sw[:, None]
+        return Xa.T @ Xw, Xw.T @ T

@@ -45,8 +45,8 @@ def _sparse():
 
 def _kernels():
     return cs.phase_kernels(
-        seed=5, hist_shape=(700, 5, 8, 4, 2), sparse_shape=(300, 6, 200, 3),
-        interpret=True, pallas_forest=(300, 4, 3))
+        seed=5, hist_shape=(700, 5, 8, 4, 2), interpret=True,
+        pallas_forest=(300, 4, 3))
 
 
 def _predict_and_serve():
@@ -223,7 +223,3 @@ def test_seeded_data_is_reproducible():
     b = cs.make_text_shaped(3, 50, 64, 3)
     np.testing.assert_array_equal(a[0], b[0])
     np.testing.assert_array_equal(a[1], b[1])
-    idx, val = cs.make_packed(1, 20, 5, 30)
-    assert idx.dtype == np.int32 and val.dtype == np.float32
-    assert idx.shape == val.shape == (20, 5) and idx.max() < 30
-    assert 0.1 < float(np.mean(val == 0)) < 0.6  # padding entries

@@ -38,7 +38,6 @@ MODULES = [
     "skdist_tpu.utils.validation",
     "skdist_tpu.utils.childproc",
     "skdist_tpu.ops.pallas_hist",
-    "skdist_tpu.ops.pallas_sparse",
     "skdist_tpu.parallel.compile_cache",
 ]
 

@@ -32,14 +32,13 @@ N, D = 240, 40
 
 
 def _matrix(representation, seed=0):
-    """(what the estimator is handed, the same matrix dense, meta's
-    matvec mode): a dense array; a CSR of even rows (packs to a
-    ``PackedX``) in each of its matvec modes; a skewed CSR (packs to a
-    ``BucketedX``)."""
+    """(what the estimator is handed, the same matrix dense): a dense
+    array; a CSR of even rows (packs to a ``PackedX``); a skewed CSR
+    (packs to a ``BucketedX``)."""
     rng = np.random.RandomState(seed)
     if representation == "dense":
         X = rng.normal(size=(N, D)).astype(np.float32)
-        return X, X, None
+        return X, X
     if representation == "bucketed":
         lens = np.clip(rng.lognormal(2.0, 1.0, N).astype(int), 1, 300)
         lens[:3] = (600, 450, 300)
@@ -51,17 +50,17 @@ def _matrix(representation, seed=0):
             (rng.rand(len(rows)).astype(np.float32), (rows, cols)),
             shape=(N, d))
         assert sx.pack_decision(X)[1] == "bucketed"
-        return X, X.toarray(), "gather"
+        return X, X.toarray()
     X = sp.random(N, 512, density=0.03, format="csr", dtype=np.float32,
                   random_state=rng)
     assert sx.pack_decision(X)[1] == "packed"
-    return X, X.toarray(), representation.split("_")[1]
+    return X, X.toarray()
 
 
 def _problem(est, representation, seed=0):
     """The estimator's own fit problem over ``representation``, and the
     same problem over the dense matrix: ``(loss, dense_loss, p)``."""
-    X, Xd, mode = _matrix(representation, seed)
+    X, Xd = _matrix(representation, seed)
     k = 2 if est.binary else 4
     y = np.random.RandomState(seed + 1).randint(0, k, N)
     sw = np.random.RandomState(seed + 2).rand(N).astype(np.float32) + 0.5
@@ -71,8 +70,6 @@ def _problem(est, representation, seed=0):
         model = est.cls(**est.kwargs)
         Xp = prepare_fit_X(M, est.cls)
         data, meta = model._prep_fit_data(Xp, y, sw)
-        if "x_matvec" in meta:
-            meta["x_matvec"] = mode
         static = _freeze(model._static_config(meta))
         problem = maybe_exact_matmuls(
             est.cls, est.cls._build_fit_problem(meta, static))
@@ -100,8 +97,7 @@ PROBLEMS = {
     "svc-binary": _Est(LinearSVC, True),
     "svc-multiclass": _Est(LinearSVC, False),
 }
-REPRESENTATIONS = ["dense", "packed_gather", "packed_dense",
-                   "packed_pallas", "bucketed"]
+REPRESENTATIONS = ["dense", "padded", "bucketed"]
 
 
 @pytest.mark.parametrize("representation", REPRESENTATIONS)
@@ -175,8 +171,7 @@ def test_an_iteration_takes_three_products_over_x(name):
         False, False, False, True]
 
 
-@pytest.mark.parametrize("representation", ["dense", "packed_gather",
-                                            "bucketed"])
+@pytest.mark.parametrize("representation", REPRESENTATIONS)
 @pytest.mark.parametrize("name", ["lr-binary", "lr-multinomial",
                                   "svc-binary"])
 def test_ray_solve_sliced_equals_unsliced_and_the_plain_search(

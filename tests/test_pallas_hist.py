@@ -61,3 +61,35 @@ def test_level_histogram_total_mass_excludes_padding():
         np.testing.assert_allclose(
             out[f].sum(axis=(0, 1)), want, rtol=1e-5
         )
+
+
+def test_hist_auto_pallas_degrades_below_8_bins(monkeypatch, tmp_path):
+    from skdist_tpu.models.hist_calib import PATH_ENV, record_calibration
+    from skdist_tpu.models.tree import build_tree_kernel, resolve_hist_config
+
+    scratch = tmp_path / "hist_calib.json"
+    monkeypatch.setenv(PATH_ENV, str(scratch))
+    record_calibration("cpu", "pallas", source="test")
+    # auto resolution: degrade to an XLA engine, never 'pallas'
+    mode, _ = resolve_hist_config(10, 4, "auto")
+    assert mode in ("scatter", "matmul")
+    # and the kernel builder accepts it (the explicit-request path at
+    # models/tree.py raises; auto must not reach that raise)
+    kern = build_tree_kernel(
+        n_features=6, n_bins=4, channels=3, max_depth=2,
+        max_features=None, min_samples_split=2, min_samples_leaf=1,
+        min_impurity_decrease=0.0, extra=False, classification=True,
+        hist_mode="auto",
+    )
+    assert callable(kern)
+    # >= 8 bins keeps the calibrated pallas pick
+    mode8, _ = resolve_hist_config(10, 8, "auto")
+    assert mode8 == "pallas"
+    # an EXPLICIT pallas request below 8 bins still raises
+    with pytest.raises(ValueError, match="n_bins >= 8"):
+        build_tree_kernel(
+            n_features=6, n_bins=4, channels=3, max_depth=2,
+            max_features=None, min_samples_split=2, min_samples_leaf=1,
+            min_impurity_decrease=0.0, extra=False, classification=True,
+            hist_mode="pallas",
+        )

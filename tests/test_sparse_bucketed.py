@@ -1,8 +1,9 @@
 """
 The bucketed packed representation (``sparse.BucketedX``): a CSR whose
 row lengths are heavy-tailed packs at a cost that follows nnz, its
-products equal the dense ones in every mode, a matrix of even rows
-still packs to the one padded pair it always did, and a grid search
+products equal the dense ones whatever its structure (no head, a head
+alone, one bucket, empty rows), a matrix of even rows still packs to
+the one padded pair it always did, and a grid search
 over a skewed 20-class CSR runs packed end to end and agrees with the
 benchmark's plain reference for sparse inputs.
 """
@@ -28,43 +29,108 @@ def skewed_csr(seed=0, n=600, d=5000, heavy=(2000, 1500, 900)):
     return sp.csr_matrix((vals, (rows, cols)), shape=(n, d))
 
 
-@pytest.mark.parametrize("mode", ["gather", "pallas"])
-def test_bucketed_products_equal_dense(mode):
+def _one_element_columns(lens, seed=5):
+    """Rows of the given lengths over columns that hold one element
+    each at most: no column is dense enough for the head."""
+    rng = np.random.RandomState(seed)
+    total = int(np.sum(lens))
+    return sp.csr_matrix(
+        (0.5 + rng.rand(total).astype(np.float32),
+         (np.repeat(np.arange(len(lens)), lens),
+          rng.permutation(2 * total)[:total])),
+        shape=(len(lens), 2 * total))
+
+
+def _structured(structure):
+    """``(CSR, what its BucketedX must look like)``."""
+    rng = np.random.RandomState(7)
+    if structure == "skewed":
+        def check(B, X):
+            # the densest columns are held dense, the rest packed at a
+            # cost that follows nnz: far under max-row padding
+            n = X.shape[0]
+            assert len(B.rows) > 3
+            assert B.head.shape == (n, B.head_cols.shape[0])
+            assert 0 < B.head_nnz < B.nnz
+            assert B.slots - B.head.size < 0.25 * n * np.diff(X.indptr).max()
+        X = skewed_csr()
+        assert sx.pack_decision(X)[:2] == (True, "bucketed")
+        return X, check
+    if structure == "no_head":
+        def check(B, X):
+            assert B.head is None and B.head_cols is None
+            assert B.head_nnz == 0 and len(B.rows) > 2
+        return _one_element_columns(
+            np.r_[300, 120, rng.randint(1, 12, 58)]), check
+    if structure == "head_only":
+        # every stored element in an eighth of the columns, each dense
+        # enough: the buckets of both orientations hold padding alone
+        def check(B, X):
+            assert B.head_nnz == B.nnz == X.nnz
+            assert B.head.shape[1] == 64
+            assert not any(np.asarray(v).any() for _, v in B.rows + B.cols)
+        dense = sp.random(48, 64, density=0.5, format="csr",
+                          dtype=np.float32, random_state=rng)
+        return sp.hstack([sp.csr_matrix((48, 200), dtype=np.float32),
+                          dense,
+                          sp.csr_matrix((48, 248), dtype=np.float32)]
+                         ).tocsr(), check
+    if structure == "one_bucket":
+        def check(B, X):
+            assert B.head is None
+            assert len(B.rows) == len(B.cols) == 1
+            assert B.rows[0][0].shape[2] == 8
+        return _one_element_columns(np.full(40, 8)), check
+    assert structure == "empty_rows_and_column"
+
+    def check(B, X):
+        assert B.head is not None and B.nnz == X.nnz
+    X = skewed_csr(seed=2, n=120, d=1500, heavy=(500, 300))
+    keep = sp.diags(np.r_[1, 1, 1, np.zeros(6), np.ones(110), 0.0])
+    X = (keep @ X @ sp.diags(np.r_[0.0, np.ones(1498), 0.0])).tocsr()
+    X = X.astype(np.float32)
+    X.eliminate_zeros()
+    assert (np.diff(X.indptr) == 0).sum() >= 7
+    return X, check
+
+
+@pytest.mark.parametrize("structure", [
+    "skewed", "no_head", "head_only", "one_bucket",
+    "empty_rows_and_column"])
+def test_bucketed_products_equal_dense(structure):
     """matvec, rmatvec, the row forms and the vmapped value-and-gradient
-    through the operator, against the dense matrix — in both nnz-bound
-    modes of the calibration table (a ``BucketedX`` has one contraction
-    for both: the Pallas rebuild kernels' work follows n x d)."""
-    X = skewed_csr()
+    through the operator, against the dense matrix — for a ``BucketedX``
+    of every make-up ``pack_csr_buckets`` can answer."""
+    X, check = _structured(structure)
     n, d = X.shape
     k = 5
-    assert sx.pack_decision(X)[:2] == (True, "bucketed")
-    B = jax.tree_util.tree_map(jnp.asarray, sx.pack_for_fit(X))
+    B = jax.tree_util.tree_map(jnp.asarray, sx.pack_csr_buckets(X))
     assert isinstance(B, sx.BucketedX) and B.shape == (n, d)
-    assert len(B.rows) > 3 and B.nnz == X.nnz
-    # the densest columns are held dense, the rest packed at a cost
-    # that follows nnz: far under max-row padding
-    assert B.head.shape == (n, B.head_cols.shape[0]) and 0 < B.head_nnz
+    assert B.nnz == X.nnz
     assert B.placed == 2 * (B.nnz - B.head_nnz) + B.head_nnz
-    assert B.slots - B.head.size < 0.25 * n * np.diff(X.indptr).max()
+    check(B, X)
     Xd = X.toarray()
+    np.testing.assert_array_equal(jax.jit(sx.bucketed_to_dense)(B), Xd)
     rng = np.random.RandomState(1)
     W = rng.randn(d + 1, k).astype(np.float32)
     r = rng.randn(n, k).astype(np.float32)
     Xa = np.hstack([Xd, np.ones((n, 1), np.float32)])
-    op = sx.LinearOperator(B, True, mode=mode)
+    op = sx.LinearOperator(B, True)
     np.testing.assert_allclose(op.matvec(W), Xa @ W, atol=5e-5)
     np.testing.assert_allclose(op.matvec(W[:, 0]), Xa @ W[:, 0], atol=5e-5)
     np.testing.assert_allclose(op.rmatvec(r), Xa.T @ r, atol=5e-5)
     rows = rng.randint(0, n, 32)
     g = rng.randn(32, k).astype(np.float32)
-    np.testing.assert_allclose(op.row_matvec(rows, W), Xa[rows] @ W,
-                               atol=5e-5)
-    np.testing.assert_allclose(op.row_rmatvec(rows, g), Xa[rows].T @ g,
-                               atol=5e-5)
+    # jitted, as the fits run them: eagerly every bucket's every op
+    # compiles on its own
+    np.testing.assert_allclose(jax.jit(op.row_matvec)(rows, W),
+                               Xa[rows] @ W, atol=5e-5)
+    np.testing.assert_allclose(jax.jit(op.row_rmatvec)(rows, g),
+                               Xa[rows].T @ g, atol=5e-5)
 
     def loss(Wl, X):
         return jnp.sum(jnp.tanh(
-            sx.LinearOperator(X, True, mode=mode).matvec(Wl)) * r)
+            sx.LinearOperator(X, True).matvec(Wl)) * r)
 
     lanes = rng.randn(4, d + 1, k).astype(np.float32)
     batched = jax.jit(jax.vmap(jax.value_and_grad(loss), (0, None)))
@@ -72,6 +138,69 @@ def test_bucketed_products_equal_dense(mode):
     v_ref, gr_ref = batched(lanes, jnp.asarray(Xd))
     np.testing.assert_allclose(v, v_ref, rtol=2e-5)
     np.testing.assert_allclose(gr, gr_ref, atol=5e-5)
+
+
+def test_bf16_contract_on_the_bucketed_products():
+    """``matmul_dtype='bfloat16'`` on a ``BucketedX``: the operands
+    round to bf16, the row sums accumulate in float32, the head is one
+    bf16 pass with float32 accumulation and the intercept's column of
+    ones stays float32 — the padded pair's contract
+    (``test_sparse_fit.py``), in both directions. Whether a bucket's
+    PRODUCTS round to bf16 before the sum, as the pair's do op by op,
+    is the compiler's to say inside the fused scan (XLA may keep the
+    float32 product: excess precision), so either sum is the contract;
+    the references are summed in float64."""
+    X = skewed_csr(seed=4, n=200, d=2000, heavy=(700, 400))
+    B = jax.tree_util.tree_map(jnp.asarray, sx.pack_csr_buckets(X))
+    assert B.head is not None and 0 < B.head_nnz < B.nnz
+    n, d = X.shape
+    k = 3
+    rng = np.random.RandomState(6)
+    W = rng.randn(d + 1, k).astype(np.float32)
+    r = rng.randn(n, k).astype(np.float32)
+    in_head = np.zeros(d, bool)
+    in_head[np.asarray(B.head_cols)] = True
+    coo = X.tocoo()
+
+    def bf16(a):
+        return jnp.asarray(a).astype(jnp.bfloat16)
+
+    def products(operand_rows, rounded):
+        """bf16(val) * bf16(operand row) a stored element, in the
+        buckets ``rounded`` to bf16 or not; exact in the head's
+        accumulation either way."""
+        v, o = bf16(coo.data)[:, None], bf16(operand_rows)
+        exact = np.asarray(v.astype(jnp.float32) * o.astype(jnp.float32),
+                           np.float64)
+        if not rounded:
+            return exact
+        return np.where(in_head[coo.col][:, None], exact,
+                        np.asarray((v * o).astype(jnp.float32), np.float64))
+
+    def forward(rounded):
+        out = np.zeros((n, k))
+        np.add.at(out, coo.row, products(W[coo.col], rounded))
+        return out + W[d]
+
+    def backward(rounded):
+        out = np.zeros((d + 1, k))
+        np.add.at(out, coo.col, products(r[coo.row], rounded))
+        out[d] = r.astype(np.float64).sum(0)
+        return out
+
+    op = sx.LinearOperator(B, True, matmul_dtype="bfloat16")
+    exact = sx.LinearOperator(B, True)
+    grad = jax.grad(lambda w: jnp.sum(op.matvec(w) * r))(jnp.asarray(W))
+    for got, want, f32 in ((op.matvec(W), forward, exact.matvec(W)),
+                           (op.rmatvec(r), backward, exact.rmatvec(r)),
+                           # the solvers' gradient IS the transpose
+                           (grad, backward, exact.rmatvec(r))):
+        got, f32 = np.asarray(got), np.asarray(f32)
+        scale = np.maximum(1.0, np.abs(f32))
+        assert min(np.max(np.abs(got - want(rounded)) / scale)
+                   for rounded in (True, False)) < 2e-6
+        # the contract's own precision class, not float32's
+        assert 1e-4 < np.max(np.abs(got - f32) / scale) < 0.1
 
 
 def test_even_rows_pack_to_the_one_padded_pair():
@@ -122,7 +251,6 @@ def test_search_on_a_skewed_csr_stays_packed_and_matches_the_reference(
         error_score="raise").fit(X, y)
     stats = backend.last_round_stats
     assert stats["kernel_mode"] == "packed_gather"
-    assert stats["x_matvec"] == "gather"
     assert X.nnz < stats["x_nnz"] <= 2 * X.nnz < stats["x_slots"]
     got = np.array([[gs.cv_results_[f"split{f}_test_score"][c]
                      for f in range(cv)] for c in range(len(Cs))])

@@ -79,7 +79,7 @@ def _device_bytes(compiled):
 
 
 # ---------------------------------------------------------------------------
-# Pallas kernels: must be IN the program as Mosaic custom calls
+# the Pallas kernel: must be IN the program as a Mosaic custom call
 # ---------------------------------------------------------------------------
 
 @pytest.mark.parametrize("n,d,B,nl,C", [
@@ -97,26 +97,31 @@ def test_level_histogram_compiles(sds, n, d, B, nl, C):
     assert "tpu_custom_call" in compiled.as_text()
 
 
+# ---------------------------------------------------------------------------
+# the padded pair's gathers and scatters: the forms that run on the chip
+# ---------------------------------------------------------------------------
+
 @pytest.mark.parametrize("kernel", ["matvec", "rmatvec", "gram"])
-def test_pallas_sparse_compiles(sds, kernel):
-    """(11,314 rows, m=128, p=4,096, k=20). The rebuild loop reads the
-    transposed packed pair through the ref: a dynamic slice of a loaded
-    value — what these kernels did until the first rehearsal — has no
-    TPU lowering, and every interpret-mode test had passed."""
-    from skdist_tpu.ops import pallas_sparse as ps
+def test_padded_pair_kernels_compile(sds, kernel):
+    """(11,314 rows, m=128, p=4,096, k=20): the gather, the scatter-add
+    and the m² scatter of the gram, as XLA lowers them for the chip —
+    no Mosaic kernel, and each beside its operands well inside 16 GB."""
+    from skdist_tpu import sparse as sx
 
     n, m, p, k = 11_314, 128, 4096, 20
     idx, val = sds((n, m), jnp.int32), sds((n, m))
     fn, arg = {
-        "matvec": (lambda i, v, W: ps.packed_matvec(
-            i, v, W, interpret=False), sds((p, k))),
-        "rmatvec": (lambda i, v, r: ps.packed_rmatvec(
-            i, v, r, p, interpret=False), sds((n, k))),
-        "gram": (lambda i, v, sw: ps.packed_weighted_gram(
-            i, v, sw, p, interpret=False), sds((n,))),
+        "matvec": (sx.packed_matvec, sds((p, k))),
+        "rmatvec": (lambda i, v, r: sx.packed_rmatvec(i, v, r, p),
+                    sds((n, k))),
+        "gram": (lambda i, v, sw: sx.packed_weighted_gram(i, v, sw, p),
+                 sds((n,))),
     }[kernel]
     compiled = jax.jit(fn).lower(idx, val, arg).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text
+    assert ("gather" if kernel == "matvec" else "scatter") in text
+    assert _device_bytes(compiled) < 0.25 * HBM_BYTES
 
 
 # ---------------------------------------------------------------------------
