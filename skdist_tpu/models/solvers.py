@@ -9,7 +9,8 @@ reference reached through sklearn (e.g. LogisticRegression in
 ``/root/reference/examples/search/basic_usage.py:99``).
 
 Design notes for TPU:
-- fixed-size ring-buffer history (static ``history``), no dynamic shapes
+- fixed-size history (static ``history``) held in age order, no
+  dynamic shapes and no per-lane index (see ``LBFGS_CARRY_KEYS``)
 - convergence handled with a ``done`` flag in the carry so converged
   vmap lanes freeze while others keep iterating (vmap of while_loop
   steps all lanes until every lane's predicate is false)
@@ -50,6 +51,13 @@ from jax import lax
 _EPS = 1e-12
 
 #: order of the L-BFGS carry leaves (the ISSUE-pinned pytree contract)
+#: ``S``, ``Y`` (``(m, p)``) and ``rho`` (``(m,)``) hold the stored
+#: pairs in age order: row ``m-1`` the newest, the last ``min(k, m)``
+#: rows valid, the rest zero. ``k`` counts the pairs stored and no index
+#: is derived from it: ``k`` is a leaf of each lane's carry, and a row
+#: index of a lane's own is a gather or scatter with a batched index
+#: under ``vmap``, where a row named by position is the same slice for
+#: every lane. Nothing outside the solver reads them.
 #: ``nfev`` counts the evaluations of the loss the lane ASKED for (1 for
 #: the initial value-and-gradient; per iteration the trial at t0, one
 #: per line-search halving, and the value-and-gradient at the accepted
@@ -87,31 +95,33 @@ def _lbfgs_body(fun, value_and_grad, max_iter, tol, history, max_ls):
     diverge."""
     m = history
 
-    def two_loop(g, S, Y, rho, k):
-        n_corr = jnp.minimum(k, m)
+    def row(H, j):
+        # ``j`` is a loop counter, which ``vmap`` leaves unbatched
+        return lax.dynamic_index_in_dim(H, j, 0, keepdims=False)
 
-        def bwd(i, carry):
+    def two_loop(g, S, Y, rho, k):
+        first_valid = m - jnp.minimum(k, m)
+
+        def bwd(i, carry):  # newest first
             q, alphas = carry
-            idx = (k - 1 - i) % m
-            valid = i < n_corr
-            alpha = rho[idx] * jnp.dot(S[idx], q)
-            alpha = jnp.where(valid, alpha, 0.0)
-            q = q - alpha * Y[idx]
-            return q, alphas.at[idx].set(alpha)
+            j = m - 1 - i
+            alpha = row(rho, j) * jnp.dot(row(S, j), q)
+            alpha = jnp.where(j >= first_valid, alpha, 0.0)
+            q = q - alpha * row(Y, j)
+            return q, jnp.where(jnp.arange(m) == j, alpha, alphas)
 
         q, alphas = lax.fori_loop(0, m, bwd, (g, jnp.zeros(m, g.dtype)))
-        last = (k - 1) % m
-        sy = jnp.dot(S[last], Y[last])
-        yy = jnp.dot(Y[last], Y[last])
+        sy = jnp.dot(S[m - 1], Y[m - 1])
+        yy = jnp.dot(Y[m - 1], Y[m - 1])
         gamma = jnp.where(k > 0, sy / (yy + _EPS), 1.0)
         r = gamma * q
 
-        def fwd(i, r):
-            idx = (k - n_corr + i) % m
-            valid = i < n_corr
-            beta = rho[idx] * jnp.dot(Y[idx], r)
-            upd = S[idx] * (alphas[idx] - beta)
-            return r + jnp.where(valid, upd, 0.0)
+        def fwd(j, r):  # oldest first
+            beta = row(rho, j) * jnp.dot(row(Y, j), r)
+            # the mask is on the coefficient, as in ``bwd``, so that the
+            # axpy is one multiply-add however XLA compiles the loop
+            coef = jnp.where(j >= first_valid, row(alphas, j) - beta, 0.0)
+            return r + coef * row(S, j)
 
         return -lax.fori_loop(0, m, fwd, r)
 
@@ -186,12 +196,15 @@ def _lbfgs_body(fun, value_and_grad, max_iter, tol, history, max_ls):
             sy = jnp.dot(s, yv)
             # curvature check: only store pairs with s·y > 0
             store = sy > 1e-10
-            idx = k % m
-            S = jnp.where(store, S.at[idx].set(s), S)
-            Y = jnp.where(store, Y.at[idx].set(yv), Y)
-            rho = jnp.where(
-                store, rho.at[idx].set(1.0 / (sy + _EPS)), rho
-            )
+            # a stored pair becomes row m-1 and every row ages by one
+
+            def push(H, newest):
+                return jnp.where(
+                    store, jnp.concatenate([H[1:], newest[None]]), H)
+
+            S = push(S, s)
+            Y = push(Y, yv)
+            rho = push(rho, 1.0 / (sy + _EPS))
             k_new = k + jnp.where(store, 1, 0)
         converged = jnp.max(jnp.abs(g_new)) <= tol
         stalled = ~ok  # line search failed to find decrease

@@ -14,10 +14,12 @@ Three family forms, selected by the estimator's ``_stream_fit_kind``:
   packed-CSR; on a mesh with a 'data' axis the block row-shards and
   GSPMD psums the partials), the regulariser is evaluated once, and the
   L-BFGS state machine (two-loop recursion, Armijo backtracking —
-  mirroring ``models/solvers._lbfgs_body`` lane for lane) runs
-  host-side over the task batch. Each line-search probe is a value-only
-  streamed pass. Block accumulation reorders f32 sums, so results agree
-  with the resident solve to tolerance, not bitwise.
+  mirroring ``models/solvers._lbfgs_body`` lane for lane: its ring of
+  slots and the device's history in age order hold the same pairs in
+  the same order) runs host-side over the task batch. Each line-search
+  probe is a value-only streamed pass. Block accumulation reorders f32
+  sums, so results agree with the resident solve to tolerance, not
+  bitwise.
 - **"sgd"** (SGDClassifier): epochs become block streams. An epoch
   visits blocks in order; within a block, mini-batches advance the
   ``(w, pstate, step, acc)`` carry through the SAME traced update as

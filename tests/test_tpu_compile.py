@@ -21,6 +21,7 @@ chip would resolve.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -365,6 +366,71 @@ def test_round_size_rule_at_the_text_cell():
     assert 4 * 22 * width <= resident <= 4 * 23 * width
     assert transient > resident
     assert Chip.last_shared_bytes < 4 * resident
+
+
+def _buffer_opcodes(hlo, shape):
+    """The opcode of every instruction outside a fused computation
+    whose result holds ``shape`` — the values the program keeps in
+    memory; what a fusion computes on the way is not one."""
+    fused = True
+    for line in hlo.splitlines():
+        head = re.match(r"^(?:ENTRY )?(%[\w.\-]+) \(.*\{$", line)
+        if head:
+            fused = head.group(1).startswith("%fused_computation")
+        elif not fused and " = " in line:
+            # "<name> = <result type> <opcode>(<operands>)...": a type
+            # has no blank before a "(", an opcode always has
+            rhs = line.split(" = ", 1)[1]
+            op = re.search(r"\s([a-z][\w\-]*)\(", rhs)
+            if op and shape in rhs[:op.start()]:
+                yield op.group(1)
+
+
+def test_lbfgs_history_moves_no_row_lane_by_lane(sds):
+    """The vmapped L-BFGS slice alone (a quadratic loss, so that only
+    the solver is in the program) at the text cell's lane shape: 7
+    lanes, ``p = 20 * 130,108``, ``m = 10``. The history's rows are read
+    by position, so the optimised program has no ``gather``, no
+    ``scatter``, no ``dynamic-update-slice`` and no loop that builds a
+    ``f32[lanes,1,p]`` row lane by lane; the loops are the slice's, the
+    line search's and the two-loop's own two. The newest pair
+    ``s[None]`` reaches the store's fusion as a ``(lanes, 1, p)`` view
+    of a ``(lanes, p)`` buffer; nothing writes one in place or carries
+    one through a loop."""
+    from skdist_tpu.models.solvers import lbfgs_carry_init, lbfgs_resume
+
+    lanes, p, m = 7, 20 * 130_108, 10
+
+    def quadratic(a, b):
+        return lambda w: 0.5 * jnp.dot(a * w, w) - jnp.dot(b, w)
+
+    def init(a, b):
+        return lbfgs_carry_init(quadratic(a, b), jnp.zeros_like(b), 30,
+                                1e-4, m)
+
+    def step(a, carry, b):
+        return lbfgs_resume(quadratic(a, b), carry, 4, 30, 1e-4, m)
+
+    a, b = sds((p,)), sds((lanes, p))
+    carry = _on_chip(
+        jax.eval_shape(jax.vmap(init, in_axes=(None, 0)), a, b), sds)
+    assert carry["S"].shape == carry["Y"].shape == (lanes, m, p)
+    compiled = jax.jit(jax.vmap(step, in_axes=(None, 0, 0)),
+                       donate_argnums=1).lower(a, carry, b).compile()
+    hlo = compiled.as_text()
+    assert not re.search(r"\b(gather|scatter)\(", hlo)
+    assert "dynamic-update-slice(" not in hlo
+    assert len(re.findall(r"\bwhile\(", hlo)) <= 4
+    # the parser reads XLA's text: it has to find the history, which is
+    # there, before what it does not find of a row means anything
+    assert "while" in set(_buffer_opcodes(hlo, f"f32[{lanes},{m},{p}]"))
+    row = f"f32[{lanes},1,{p}]"
+    # no loop carries one (parameter, tuple, while), nothing writes one
+    # in place
+    opcodes = set(_buffer_opcodes(hlo, row))
+    assert opcodes <= {"fusion", "get-tuple-element", "copy-start",
+                       "copy-done", "bitcast"}, opcodes
+    assert _device_bytes(compiled) < HBM_BYTES
 
 
 def test_matmul_tree_level_step_compiles(sds):
