@@ -499,6 +499,9 @@ class _LinearModelBase(BaseEstimator):
         hyper = {k: jnp.asarray(hyper_float(getattr(self, k)))
                  for k in self._hyper_names}
         kernel = get_kernel(type(self), "fit", meta, _freeze(static))
+        # placed here and not by the call: a host matrix of several GiB
+        # goes in row blocks (``backend.put_host_array``)
+        data["X"] = _to_jnp(data["X"])
         if warm:
             k = meta.get("n_classes", 2)
             w0 = self._warm_w0_flat(
@@ -704,7 +707,13 @@ def _freeze(d):
 
 
 def _to_jnp(tree):
-    return jax.tree_util.tree_map(jnp.asarray, tree)
+    """The tree's leaves on the default device (a host array of
+    several GiB in row blocks: ``backend.put_host_array``)."""
+    from ..parallel.backend import put_host_array
+
+    return jax.tree_util.tree_map(
+        lambda a: put_host_array(a) if isinstance(a, np.ndarray)
+        else jnp.asarray(a), tree)
 
 
 def _split_Wb(W, d, fit_intercept, n_out):
@@ -1064,12 +1073,18 @@ class LogisticRegression(_LbfgsFitMixin, _LinearClassifierBase):
                     return loss, w0, unpack, data_loss, reg_loss
                 return loss, w0, unpack
 
-            onehot = jax.nn.one_hot(y_idx, k, dtype=op.dtype)
+            # the logits in the layout the representation's product
+            # comes out in (a dense X's: classes first, rows minor —
+            # ``LinearOperator.logits``); every reduction over the
+            # classes runs along that axis
+            ax = op.class_axis
+            onehot = jax.nn.one_hot(y_idx, k, dtype=op.dtype, axis=ax)
 
             def row_loss(logits):
-                lse = jax.nn.logsumexp(logits, axis=1)
-                return jnp.sum(
-                    sw * (lse - jnp.sum(onehot * logits, axis=1)))
+                with jax.named_scope("lr/softmax"):
+                    lse = jax.nn.logsumexp(logits, axis=ax)
+                    return jnp.sum(
+                        sw * (lse - jnp.sum(onehot * logits, axis=ax)))
 
             def reg_loss(wflat):
                 if unpenalized:  # penalty=None: sklearn's C=inf
@@ -1078,7 +1093,7 @@ class LogisticRegression(_LbfgsFitMixin, _LinearClassifierBase):
                 return 0.5 / C * jnp.sum(W[:d] * W[:d])
 
             loss, data_loss = _ray_loss(
-                lambda wflat: op.matvec(wflat.reshape(p, k)),
+                lambda wflat: op.logits(wflat.reshape(p, k)),
                 row_loss, reg_loss, "lr", linear=not bf16)
             w0 = jnp.zeros(p * k, op.dtype)
 

@@ -555,6 +555,7 @@ ROUND_STATS_REQUIRED = {
     "tasks": 0,              # tasks the dispatch covered
     "kernel_mode": None,     # dense / packed_* / hist_tree / None
     "retries": 0,            # fault re-dispatches
+    "refused": 0,            # rounds / probe compiles refused for memory
     "dispatch_s": 0.0,       # host time slicing/placing/enqueueing
     "gather_wait_s": 0.0,    # host time blocked on device results
     "retired_rung": 0,       # lanes killed by an adaptive rung

@@ -23,6 +23,7 @@ mirroring the reference's ``sc=None`` joblib path (search.py:388-408) so
 unit tests need no accelerator.
 """
 
+import collections
 import logging
 import math
 import os
@@ -1253,6 +1254,7 @@ class TPUBackend(TaskBackend):
             kernel, shared_args, static_args, shared_specs, cache_key
         )
         fn, shared_placed, put = plan.fn, plan.shared, plan.put
+        refused0 = faults.snapshot()["rounds_refused"]
         # Proactive round sizing: where the device reports memory
         # stats, AOT-compile the round program and shrink the first
         # round to fit BEFORE dispatch — a device OOM costs a wasted
@@ -1363,6 +1365,7 @@ class TPUBackend(TaskBackend):
                     _obs_incident("rounds_exhausted")
                     raise oom.cause
                 chunk = int(math.ceil(chunk / 2 / d) * d)
+                faults.record("rounds_refused")
                 warnings.warn(
                     "batched_map round exhausted device memory; resuming "
                     f"at round_size={chunk} (pass partitions="
@@ -1475,6 +1478,7 @@ class TPUBackend(TaskBackend):
                     faults.record("shared_replacements")
         out = _concat_rounds(rounds_out)
         stats["retries"] = retry.total
+        stats["refused"] = faults.snapshot()["rounds_refused"] - refused0
         obs_metrics.publish_round_stats(stats)
         return (out, timings) if return_timings else out
 
@@ -1776,6 +1780,56 @@ _BCAST_MIN_BYTES = 1 << 20  # caching tiny arrays is pure overhead
 _BCAST_HITS = 0  # diagnostics + test observability
 
 
+#: a host array at least this large reaches the device in row blocks:
+#: on the v5e ONE transfer of 6.27 GB took 33 s (17 to 21 s for other
+#: widths over 4 GiB) where 3.2 GB take 0.4 s — a fit's placement and
+#: its refit's were 64 of its 110 seconds (PERF.md, PR 32)
+_BLOCK_PUT_BYTES = 1 << 32
+
+
+_WRITE_ROWS = None
+
+
+def _write_rows():
+    """The jitted in-place write of a row block (made once: jax is
+    imported where it is first needed, as everywhere in this module)."""
+    global _WRITE_ROWS
+    if _WRITE_ROWS is None:
+        import jax
+
+        _WRITE_ROWS = jax.jit(
+            lambda whole, block, at: jax.lax.dynamic_update_slice_in_dim(
+                whole, block, at, axis=0),
+            donate_argnums=0)
+    return _WRITE_ROWS
+
+
+def put_host_array(x, sharding=None):
+    """``jax.device_put(x, sharding)`` (uncommitted on the default
+    device where ``sharding`` is None) — a host array of
+    :data:`_BLOCK_PUT_BYTES` or more in row blocks of a quarter of that,
+    each written into the whole on the device, so that beside the
+    whole only one block is ever held."""
+    import jax
+
+    def put(a):
+        return (jax.device_put(a) if sharding is None
+                else jax.device_put(a, sharding))
+
+    if (not isinstance(x, np.ndarray) or x.nbytes < _BLOCK_PUT_BYTES
+            or x.ndim < 1 or len(x) < 2):
+        return put(x)
+    import jax.numpy as jnp
+
+    rows = max(1, len(x) // -(-x.nbytes // (_BLOCK_PUT_BYTES // 4)))
+    whole = jnp.zeros(x.shape, x.dtype, device=sharding)
+    for at in range(0, len(x), rows):
+        # the last block is written over the end of the one before it
+        at = min(at, len(x) - rows)
+        whole = _write_rows()(whole, put(x[at:at + rows]), at)
+    return whole
+
+
 def _put_mesh_scoped(x, sharding):
     """``device_put`` that never joins a JOB-GLOBAL collective.
 
@@ -1795,6 +1849,8 @@ def _put_mesh_scoped(x, sharding):
     import jax
 
     if getattr(sharding, "is_fully_addressable", True):
+        if getattr(sharding, "is_fully_replicated", False):
+            return put_host_array(x, sharding)
         return jax.device_put(x, sharding)
     if getattr(x, "is_fully_addressable", True) is False:
         # already a global (multi-process) array: jax reshards it on
@@ -2277,17 +2333,20 @@ def _dispatch_iterative(backend, plan, spec, task_args, shared_args,
     budget is spent, the classic fallback kernel (which retries per
     round) is the last resort before failing loud."""
     chunk, chunk_basis, lanes_fit = sizing
-    resident, transient = _lane_footprint(plan, task_args)[:2]
+    resident, transient, fixed, rows = _lane_footprint(plan, task_args)
+    shared_bytes = int(backend.last_shared_bytes or 0)
     stats = backend.last_round_stats = obs_metrics.new_round_stats(
-        tasks=int(n_tasks),
-        shared_bytes=int(backend.last_shared_bytes or 0),
-        lane_bytes=int(resident + transient),
+        tasks=int(n_tasks), shared_bytes=shared_bytes,
+        lane_bytes=int(resident + transient), logits_bytes=int(rows),
+        round_bytes_estimate=int(
+            shared_bytes + fixed + chunk * (resident + transient)),
         chunk_basis=chunk_basis, lanes_fit=lanes_fit,
     )
     # where device memory set the size, one round's carry is resident
     live_rounds = 1 if chunk_basis == "memory" else None
     t0 = time.perf_counter()
     retry = _RetryState()
+    refused0 = faults.snapshot()["rounds_refused"]
     while True:
         try:
             if rung is not None:
@@ -2348,6 +2407,7 @@ def _dispatch_iterative(backend, plan, spec, task_args, shared_args,
             elif kind == faults.OOM:
                 if spec.fallback is None:
                     raise cause
+                faults.record("rounds_refused")
                 warnings.warn(
                     "compacted iterative dispatch exhausted device "
                     "memory; falling back to the classic batched path "
@@ -2371,13 +2431,18 @@ def _dispatch_iterative(backend, plan, spec, task_args, shared_args,
                 stats.get("rounds_per_slice", []) or [0]
             ))
             obs_metrics.publish_round_stats(stats)
-            return backend.batched_map(
+            out = backend.batched_map(
                 spec.fallback, task_args, shared_args,
                 static_args=static_args, round_size=chunk,
                 shared_specs=shared_specs, return_timings=return_timings,
                 cache_key=spec.fallback_cache_key or cache_key,
                 on_round=on_round,
             )
+            # the fallback's stats are the dispatch's: what the
+            # compacted attempt had refused counts with its own
+            backend.last_round_stats["refused"] = (
+                faults.snapshot()["rounds_refused"] - refused0)
+            return out
     if return_timings:
         # one pseudo-round covering the whole call: per-task wall is a
         # uniform smear (slices interleave tasks, so a per-round
@@ -2444,7 +2509,7 @@ def _run_compacted(plan, spec, task_args, n_tasks, chunk, stats,
     shared = plan.shared
     shared_sig = plan._shared_sig
 
-    def make_exec(fn):
+    def make_exec(fn, book=None):
         if not hasattr(fn, "lower"):
             # test doubles / non-AOT callables: run direct
             return lambda sl: fn(shared, sl)
@@ -2453,12 +2518,17 @@ def _run_compacted(plan, spec, task_args, n_tasks, chunk, stats,
             comp = compile_cache.aot_executable(
                 fn, shared, sl, _leading_dim(sl), shared_sig=shared_sig
             )
+            if book is not None and book not in stats:
+                stats[book] = _program_bytes(comp)
             return comp(shared, sl)
 
         return run
 
     init_exec = make_exec(plan.init_fn)
-    step_exec = make_exec(plan.step_fn)
+    # what the compiler says a round's step program holds, beside what
+    # round sizing reckoned for it (``round_bytes_estimate``): the
+    # device's own peak counter does not see a program's temporaries
+    step_exec = make_exec(plan.step_fn, "round_bytes_compiled")
     fin_exec = make_exec(plan.fin_fn)
     score_exec = (
         make_exec(plan.score_fn)
@@ -2485,6 +2555,18 @@ def _run_compacted(plan, spec, task_args, n_tasks, chunk, stats,
         )
     stats["finalize"] = fin_stats
     return out
+
+
+def _program_bytes(compiled):
+    """Arguments, outputs and temporaries of a compiled program by its
+    own ``memory_analysis()``, or None where the backend gives none."""
+    try:
+        ma = compiled.memory_analysis()
+        return int(ma.temp_size_in_bytes + ma.argument_size_in_bytes
+                   + ma.output_size_in_bytes)
+    except Exception as exc:
+        faults.log_suppressed("_program_bytes", exc, level=logging.DEBUG)
+        return None
 
 
 def _compacted_slice_loop(spec, put, init_exec, step_exec, score_exec,
@@ -2763,21 +2845,27 @@ _LANE_FOOTPRINTS = {}
 
 
 def _lane_footprint(plan, task_args):
-    """``(resident, transient, fixed)`` bytes of the compacted path's
-    programs, read from the dispatch's own trees before anything is
-    compiled: what ONE lane keeps on its device between slices (its
-    task slice and its carry), what it adds while its round runs (the
-    carry a step writes beside the one it reads, and the largest value
-    computed from the lane's data, twice: some operation reads one that
-    size and writes another), and the largest value the program derives
-    from the shared operands alone (a copy with a ones column, say),
-    which it holds once whatever the round's width.
+    """``(resident, transient, fixed, rows)`` bytes of the compacted
+    path's programs, read from the dispatch's own trees before anything
+    is compiled: what ONE lane keeps on its device between slices (its
+    task slice and its carry), what it adds while its round runs — the
+    most the traced program holds at once of values computed from the
+    lane's data: the carry a step writes beside the one it reads, and
+    whatever its fullest point keeps beside that (a line search along a
+    ray holds both products' logits across its loop, a trial value and
+    the residual beside them) — the largest value the program derives
+    from the shared operands alone (a copy in another layout, say),
+    which it holds once whatever the round's width, and the most it
+    holds at once of values shaped like the data's rows (logits and
+    their kin: what makes a lane heavy where the weights are small).
 
-    The carry and the two largest values come from one abstract trace
-    of the init program (it runs the same solver slice the step
-    program does) at :data:`_PROBE_LANES` lanes a slot; no data moves
-    and nothing is lowered. An entry that cannot be traced (a test
-    double) or whose trace fails leaves the task slice alone."""
+    All but the task slice come from one abstract trace of the init
+    program (it runs the same solver slice the step program does) at
+    :data:`_PROBE_LANES` lanes a slot; no data moves and nothing is
+    lowered. The count is of the program AS TRACED: a value XLA fuses
+    away is counted, the tiles it pads a small axis to are not. An
+    entry that cannot be traced (a test double) or whose trace fails
+    leaves the task slice alone."""
     import jax
 
     task_sig = tuple((tuple(l.shape[1:]), str(l.dtype))
@@ -2787,30 +2875,92 @@ def _lane_footprint(plan, task_args):
     if found is None:
         task_bytes = tree_nbytes(task_args) // max(
             1, _leading_dim(task_args))
-        found = (task_bytes, 0, 0)
+        found = (task_bytes, 0, 0, 0)
         if hasattr(plan.init_fn, "trace"):
             try:
-                carry, lane_top, shared_top = _traced_lane_bytes(
+                carry, live, shared_top, rows = _traced_lane_bytes(
                     plan.init_fn, plan.shared, task_args,
                     _PROBE_LANES * plan.n_task_slots,
                 )
-                found = (task_bytes + carry, carry + 2 * lane_top,
-                         shared_top)
+                found = (task_bytes + carry, live, shared_top, rows)
             except Exception as exc:
                 faults.log_suppressed("_lane_footprint", exc)
         _LANE_FOOTPRINTS[key] = found
     return found
 
 
+def _sample_rows(shared):
+    """The data's row count: the leading dimension most leaves of the
+    shared operands have (X, y, the sample weights), or None."""
+    import jax
+
+    dims = collections.Counter(
+        int(l.shape[0]) for l in jax.tree_util.tree_leaves(shared)
+        if getattr(l, "ndim", 0) and l.shape[0] > 1)
+    return dims.most_common(1)[0][0] if dims else None
+
+
 def _traced_lane_bytes(init_fn, shared, task_args, width):
     """One abstract trace of ``init_fn`` at ``width`` lanes: the bytes
-    of one lane's carry (the program's output), of the largest value
-    with a lane axis (a dimension that is a multiple of ``width``), per
-    lane, and of the largest value without one."""
+    of one lane's carry (the program's output); the most bytes of
+    values with a lane axis (a dimension that is a multiple of
+    ``width``) that the program computes and holds AT ONCE, per lane —
+    a walk over the jaxpr in order, a value alive from the equation
+    that makes it to the last that reads it, a loop's or a call's body
+    counted at its own fullest point on top of what is alive around
+    it; the bytes of the largest value without a lane axis; and the
+    same walk over the lane values that have an axis of the data's row
+    count."""
     import jax
+
+    rows = _sample_rows(shared)
 
     def nbytes(aval):
         return math.prod(aval.shape) * getattr(aval.dtype, "itemsize", 4)
+
+    def lane_bytes(aval):
+        if any(n and n % width == 0 for n in aval.shape):
+            return nbytes(aval) // width
+        return 0
+
+    def row_bytes(aval):
+        return lane_bytes(aval) if rows in aval.shape else 0
+
+    shared_top = 0
+
+    def peak(jaxpr, size):
+        """The most ``size`` bytes of the values this jaxpr computes
+        that are alive at once (its own inputs are its caller's)."""
+        nonlocal shared_top
+        last = {var: i for i, eqn in enumerate(jaxpr.eqns)
+                for var in eqn.invars if hasattr(var, "count")}
+        last.update((var, len(jaxpr.eqns)) for var in jaxpr.outvars
+                    if hasattr(var, "count"))
+        live = top = 0
+        dying = collections.Counter()
+        for i, eqn in enumerate(jaxpr.eqns):
+            made = 0
+            for var in eqn.outvars:
+                if not hasattr(var.aval, "shape"):
+                    continue
+                made += size(var.aval)
+                dying[last.get(var, i)] += size(var.aval)
+                # (a transposed operand is the contraction's own
+                # reading of it, not a value the program keeps)
+                if (not lane_bytes(var.aval)
+                        and eqn.primitive.name != "transpose"):
+                    shared_top = max(shared_top, nbytes(var.aval))
+            # the bodies of loops, conditionals and calls
+            inner = 0
+            for param in eqn.params.values():
+                for sub in (param if isinstance(param, (tuple, list))
+                            else (param,)):
+                    sub = getattr(sub, "jaxpr", sub)
+                    if hasattr(sub, "eqns"):
+                        inner = max(inner, peak(sub, size))
+            top = max(top, live + max(made, inner))
+            live += made - dying.pop(i, 0)
+        return top
 
     traced = init_fn.trace(shared, jax.tree_util.tree_map(
         lambda a: jax.ShapeDtypeStruct(
@@ -2820,25 +2970,8 @@ def _traced_lane_bytes(init_fn, shared, task_args, width):
     carry = sum(
         nbytes(o) for o in jax.tree_util.tree_leaves(traced.out_info)
     ) // width
-    lane_top = shared_top = 0
-    todo = [traced.jaxpr.jaxpr]
-    while todo:
-        for eqn in todo.pop().eqns:
-            for var in eqn.outvars:
-                if not hasattr(var.aval, "shape"):
-                    continue
-                if any(n and n % width == 0 for n in var.aval.shape):
-                    lane_top = max(lane_top, nbytes(var.aval) // width)
-                else:
-                    shared_top = max(shared_top, nbytes(var.aval))
-            # the bodies of loops, conditionals and calls
-            for param in eqn.params.values():
-                for sub in (param if isinstance(param, (tuple, list))
-                            else (param,)):
-                    sub = getattr(sub, "jaxpr", sub)
-                    if hasattr(sub, "eqns"):
-                        todo.append(sub)
-    return carry, lane_top, shared_top
+    jaxpr = traced.jaxpr.jaxpr
+    return carry, peak(jaxpr, lane_bytes), shared_top, peak(jaxpr, row_bytes)
 
 
 def _size_iterative_round(backend, plan, task_args, n_tasks, round_size,
@@ -2875,7 +3008,7 @@ def _size_iterative_round(backend, plan, task_args, n_tasks, round_size,
     if round_size:
         chunk = int(math.ceil(min(n_tasks, round_size) / d) * d)
         return chunk, "round_size", None
-    resident, transient, fixed = _lane_footprint(plan, task_args)
+    resident, transient, fixed = _lane_footprint(plan, task_args)[:3]
     shared, lane = backend.last_shared_bytes or 0, resident + transient
     free = backend._free_device_bytes()
     if free is None or free <= 0:
@@ -2947,6 +3080,7 @@ def _aot_exec_fn(fn, shared_args, task_args, chunk, d, free_bytes,
         except Exception as exc:
             if faults.classify(exc) != faults.OOM or sized <= d:
                 raise
+            faults.record("rounds_refused")
             sized = max(d, (sized // 8) // d * d)
     try:
         ma = compiled.memory_analysis()

@@ -264,6 +264,9 @@ _LOCK = threading.RLock()
 #: a bug and raises (the old dict's KeyError contract)
 FAULT_COUNTERS = (
     "rounds_retried",       # re-dispatches after a retryable fault
+    "rounds_refused",       # rounds and probe compiles the device
+                            # refused for memory, absorbed by a smaller
+                            # round or the classic fallback
     "retries_exhausted",    # faults that ran out of policy budget
     "shared_replacements",  # shared-arg re-placements (preemption)
     "lanes_quarantined",    # tasks mapped to error_score by the guard

@@ -906,7 +906,9 @@ class LinearOperator:
     (:class:`_DenseOperator`), a :class:`PackedX`'s
     (:class:`_PackedOperator`) or a :class:`BucketedX`'s
     (:class:`_BucketedOperator`). Each holds the five contractions:
-    ``matvec`` (``X̃ @ W``), ``rmatvec`` (``X̃ᵀ @ r``), the SGD
+    ``matvec`` (``X̃ @ W``; ``logits`` is the same product of a weight
+    matrix in the representation's own layout), ``rmatvec``
+    (``X̃ᵀ @ r``), the SGD
     mini-batch forms ``row_matvec`` / ``row_rmatvec`` over rows ``i``,
     and ``weighted_gram_rhs`` (``(X̃ᵀSX̃, (SX̃)ᵀT)``, the two sides of
     the ridge normal equations).
@@ -927,6 +929,9 @@ class LinearOperator:
 
     __slots__ = ("d", "p", "n", "dtype", "bf16")
 
+    #: the axis of :meth:`logits`'s result that carries the classes
+    class_axis = 1
+
     def __new__(cls, X, fit_intercept, matmul_dtype=None):
         if cls is LinearOperator:
             cls = (_BucketedOperator if isinstance(X, BucketedX)
@@ -939,56 +944,114 @@ class LinearOperator:
         self.n, self.d = X.shape
         self.p = self.d + int(bool(fit_intercept))
 
+    def logits(self, W):
+        """``X̃ @ W`` for a weight MATRIX ``(p, k)`` in the layout this
+        representation's product comes out in, the classes on
+        :attr:`class_axis` — what the multinomial loss reduces over."""
+        return self.matvec(W)
+
 
 class _DenseOperator(LinearOperator):
-    """The pre-sparse-plane expressions VERBATIM (``Xa @ W``,
-    ``Xa[i] @ W``, ``Xa.T @ (Xa * sw)``; for a weight VECTOR the first
-    is written ``W @ Xa.T``, the same contraction lanes-first)."""
+    """The plain dense products, the intercept BESIDE them (``X @ W[:d]
+    + W[d]``, ``[Xᵀr ; Σr]``, the gram in blocks): no copy of X with a
+    ones column exists, which at every program call cost a second X.
+    A weight VECTOR's product is written ``w @ X.T`` and a weight
+    MATRIX's logits (:meth:`logits`) ``Wᵀ @ X.T``: the same
+    contractions lanes- and classes-first, rows minor."""
 
-    __slots__ = ("Xa", "_Xmm")
+    __slots__ = ("X", "_Xmm", "_icpt")
+
+    #: :meth:`logits` carries the classes first, ``(k, n)``
+    class_axis = 0
 
     def __init__(self, X, fit_intercept, matmul_dtype=None):
         super().__init__(X, fit_intercept, matmul_dtype)
         self.dtype = X.dtype
-        if fit_intercept:
-            ones = jnp.ones((X.shape[0], 1), X.dtype)
-            X = jnp.concatenate([X, ones], axis=1)
-        self.Xa, self._Xmm = X, None
+        self.X, self._Xmm, self._icpt = X, None, bool(fit_intercept)
+
+    def _split(self, W):
+        """``(W[:d], W[d])`` — the intercept rounded as the bf16
+        contract rounds every operand of its pass."""
+        if not self._icpt:
+            return W, None
+        b = W[self.d]
+        if self.bf16:
+            b = b.astype(jnp.bfloat16).astype(jnp.float32)
+        return W[:self.d], b
+
+    def _bf16_dot(self, lhs, rhs, contract):
+        """The bf16 pass: bf16 operands, f32 accumulation; precision
+        pinned so the library-wide 'highest' tracing default doesn't
+        promote it."""
+        return jax.lax.dot_general(
+            lhs.astype(jnp.bfloat16), rhs.astype(jnp.bfloat16),
+            (contract, ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.DEFAULT,
+        )
+
+    def _X16(self):
+        if self._Xmm is None:
+            self._Xmm = self.X.astype(jnp.bfloat16)
+        return self._Xmm
 
     def matvec(self, W):
-        if self.bf16:
-            if self._Xmm is None:
-                self._Xmm = self.Xa.astype(jnp.bfloat16)
-            # precision pinned so the library-wide 'highest'
-            # tracing default doesn't promote the bf16 pass
-            return jax.lax.dot_general(
-                self._Xmm, W.astype(jnp.bfloat16),
-                (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32,
-                precision=jax.lax.Precision.DEFAULT,
-            )
-        if W.ndim == 1:
-            # the same contraction, written lanes-first: under
-            # ``vmap`` over a round's lanes the logits come out
-            # ``(lanes, n)`` — ``Xa @ W`` batches to ``(n, lanes)``,
-            # which XLA holds lanes-minor wherever a ``while``
-            # carries it (the line search's trial steps), every row
-            # of 50 lanes padded to a 128-wide tile
-            return W @ self.Xa.T
-        return self.Xa @ W
+        with jax.named_scope("dense/matvec"):
+            A, b = self._split(W)
+            if self.bf16:
+                z = self._bf16_dot(self._X16(), A, ((1,), (0,)))
+            elif W.ndim == 1:
+                # the same contraction, written lanes-first: under
+                # ``vmap`` over a round's lanes the logits come out
+                # ``(lanes, n)`` — ``X @ w`` batches to ``(n, lanes)``,
+                # which XLA holds lanes-minor wherever a ``while``
+                # carries it (the line search's trial steps), every row
+                # of 50 lanes padded to a 128-wide tile
+                z = A @ self.X.T
+            else:
+                z = self.X @ A
+            return z if b is None else z + b
+
+    def logits(self, W):
+        # ``(k, n)``, under ``vmap`` ``(lanes, k, n)``: ``X @ W`` batches
+        # to ``(n, lanes, k)``, ten classes padded to a 128-wide tile
+        # wherever a ``while`` carries it
+        with jax.named_scope("dense/matvec"):
+            A, b = self._split(W)
+            if self.bf16:
+                z = self._bf16_dot(A, self._X16(), ((0,), (1,)))
+            else:
+                z = A.T @ self.X.T
+            return z if b is None else z + b[:, None]
+
+    def _with_sum(self, G, r):
+        """``[G ; Σ_rows r]``: the ones column's row of a transposed
+        product."""
+        if not self._icpt:
+            return G
+        return jnp.concatenate([G, jnp.sum(r, axis=0)[None]])
 
     def rmatvec(self, r):
-        return self.Xa.T @ r
+        with jax.named_scope("dense/rmatvec"):
+            return self._with_sum(self.X.T @ r, r)
 
     def row_matvec(self, i, W):
-        return self.Xa[i] @ W
+        A, b = self._split(W)
+        z = self.X[i] @ A
+        return z if b is None else z + b
 
     def row_rmatvec(self, i, g):
-        return self.Xa[i].T @ g
+        return self._with_sum(self.X[i].T @ g, g)
 
     def weighted_gram_rhs(self, sw, T):
-        Xw = self.Xa * sw[:, None]
-        return self.Xa.T @ Xw, Xw.T @ T
+        Xw = self.X * sw[:, None]
+        G, rhs = self.X.T @ Xw, Xw.T @ T
+        if not self._icpt:
+            return G, rhs
+        c = jnp.sum(Xw, axis=0)
+        G = jnp.block([[G, c[:, None]],
+                       [c[None, :], jnp.sum(sw)[None, None]]])
+        return G, jnp.concatenate([rhs, (sw @ T)[None]])
 
 
 class _PackedOperator(LinearOperator):

@@ -262,7 +262,8 @@ def test_new_metric_resolves_to_a_reader_and_an_entry(name):
     entry = {m["name"]: m for m in _bench()["per_layer"]}[name]
     assert set(entry) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
-    assert entry["workloads"] == ["search-epsilon"]
+    # the dense cells: the multiclass one joined them (PR 32)
+    assert entry["workloads"] == ["search-epsilon", "search-mnist8m"]
     assert entry["moves"] == "search_fits_per_s"
     assert entry["source"] == ("program_span" if reader is span_seconds
                                else "program_counter")
@@ -284,9 +285,9 @@ def test_every_metric_file_has_its_entry_and_reader():
         assert os.path.exists(os.path.join(
             REPO, "chipbench", "readers", spec["reader"] + ".py"))
     # the new entries were appended: the accepted ones keep their places
+    appended = NEW_METRICS + TEXT_METRICS + MNIST_METRICS
     assert [m["name"] for m in bench["per_layer"]][
-        -len(NEW_METRICS + TEXT_METRICS):] == list(
-            NEW_METRICS + TEXT_METRICS)
+        -len(appended):] == list(appended)
 
 
 # ---------------------------------------------------------------------------
@@ -301,6 +302,13 @@ TEXT_METRICS = (
 )
 
 
+#: the three ``search-mnist8m`` brought (PR 32)
+MNIST_METRICS = (
+    "logits_share_of_lane_pct.search", "round_retries_per_fit.search",
+    "round_mem_vs_compiled_pct.search",
+)
+
+
 @pytest.mark.parametrize("name, reader, source", zip(
     TEXT_METRICS,
     ("work_share", "round_counts", "span_seconds", "round_mem_estimate"),
@@ -312,7 +320,10 @@ def test_text_cell_metric_resolves_to_a_reader_and_an_entry(
         spec = json.load(f)
     assert spec["reader"] == reader
     entry = {m["name"]: m for m in _bench()["per_layer"]}[name]
-    assert entry["workloads"] == ["search-20news130k"]
+    # round sizing's estimate is read in every cell whose rounds memory
+    # sizes: the dense multiclass one joined it (PR 32)
+    assert entry["workloads"] == ["search-20news130k"] + (
+        ["search-mnist8m"] if reader == "round_mem_estimate" else [])
     assert (entry["moves"], entry["source"]) == ("search_fits_per_s", source)
     if reader != "work_share":
         # nothing to read in a window of a program without the counter
@@ -327,7 +338,9 @@ def test_text_cell_metric_resolves_to_a_reader_and_an_entry(
 def test_dense_share_of_the_peak_does_not_read_the_text_cell():
     entry = {m["name"]: m for m in _bench()["per_layer"]}[
         "lbfgs_mfu_pct.search"]
-    assert entry["workloads"] == ["search-epsilon"]
+    # the dense cells, binary and multinomial (its work function counts
+    # ``k`` columns), and never the packed one
+    assert entry["workloads"] == ["search-epsilon", "search-mnist8m"]
 
 
 @pytest.mark.parametrize("stats, peak, want", [
@@ -351,3 +364,45 @@ def test_packed_fill_reads_the_booked_counts():
     got = round_counts.read(_ctx([_fit(stats), _fit(stats)]),
                             num="x_nnz", den="x_slots")
     assert got == pytest.approx(1.2553787)
+
+
+@pytest.mark.parametrize("stats, units, want", [
+    ({"retries": 0, "refused": 0}, 50, 0.0),
+    ({"retries": 2, "refused": 1, "finalize": {"retries": 1}}, 50, 4 / 50),
+    # a program that books no refusals (the parent): its retries alone
+    ({"retries": 3}, 50, 3 / 50),
+    ({"rounds": 4}, 50, None),
+    ({"retries": 1, "refused": 1}, 0, None),
+])
+def test_round_retries_sums_what_was_dispatched_again(stats, units, want):
+    from chipbench.readers import round_retries
+
+    got = round_retries.read(
+        {"fits": [_fit(stats)], "units_done": units},
+        keys=["retries", "refused"])
+    assert got == (want if want is None else pytest.approx(want))
+
+
+@pytest.mark.parametrize("name, num, den", [
+    ("logits_share_of_lane_pct.search", "logits_bytes", "lane_bytes"),
+    ("round_mem_vs_compiled_pct.search", "round_bytes_estimate",
+     "round_bytes_compiled"),
+])
+def test_mnist_cell_share_metrics_read_the_booked_bytes(name, num, den):
+    """The two shares PR 32 brings ride ``round_counts``: 100 x the
+    booked numerator over the booked denominator, nothing on a program
+    that books neither; each lists the cell and moves the rate."""
+    from chipbench import run
+    from chipbench.readers import round_counts
+
+    spec = run.load_json("chipbench", "metrics", name + ".json")
+    assert spec == {"reader": "round_counts",
+                    "args": {"num": num, "den": den}}
+    entry = {m["name"]: m for m in _bench()["per_layer"]}[name]
+    assert entry["workloads"] == ["search-mnist8m"]
+    assert entry["moves"] == "search_fits_per_s"
+    ctx = {"fits": [_fit({num: 416, den: 417})], "units_done": 50}
+    assert round_counts.read(ctx, **spec["args"]) == pytest.approx(
+        100 * 416 / 417)
+    ctx = {"fits": [_fit({"rounds": 3})], "units_done": 50}
+    assert round_counts.read(ctx, **spec["args"]) is None

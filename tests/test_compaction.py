@@ -170,14 +170,14 @@ def test_sliced_vmapped_bitwise():
 # backend: batched_map_iterative
 # ---------------------------------------------------------------------------
 
-def _toy_spec_and_tasks(n_tasks=37):
+def _toy_spec_and_tasks(n_tasks=37, n_features=6):
     """A self-contained iterative kernel + its classic fallback over a
     tiny logistic problem, for driving the backend loop directly."""
     from skdist_tpu.models import LogisticRegression
     from skdist_tpu.models.linear import _freeze, as_dense_f32
 
     rng = np.random.RandomState(0)
-    X = rng.normal(size=(90, 6)).astype(np.float32)
+    X = rng.normal(size=(90, n_features)).astype(np.float32)
     y = (X[:, 0] + 0.3 * rng.normal(size=90) > 0).astype(np.int64)
     est = LogisticRegression(max_iter=40, tol=1e-5, engine="xla")
     data, meta = est._prep_fit_data(as_dense_f32(X), y, None)
@@ -310,10 +310,10 @@ def test_round_size_rule(n_tasks, n_slots, sizes, want):
 
 
 @pytest.mark.parametrize("how, want", [
-    # 37 lanes over 90 x 7 floats, a lane's carry and row-sized
-    # temporaries about 2 KB: two lanes a slot weigh the shared 3 KB,
-    # which on eight slots passes the eight-round answer (8) and on one
-    # slot does not (5)
+    # 37 lanes over 90 x 48 floats; a lane's carry and what its program
+    # holds at its fullest are about half the shared 18 KB: two lanes a
+    # slot weigh it, which on eight slots passes the eight-round answer
+    # (8) and on one slot does not (5)
     ("rule", {8: (16, "amortised", None), 1: (5, "target_rounds", None)}),
     ("round_size", {8: (24, "round_size", None),
                     1: (20, "round_size", None)}),
@@ -328,7 +328,7 @@ def test_backend_books_round_sizing(how, want, monkeypatch):
     ``_size_iterative_round`` and book ``chunk_basis`` / ``lanes_fit``
     beside ``chunk``; the answers do not depend on the round size
     beyond f32 noise."""
-    spec, _fallback, shared, tasks = _toy_spec_and_tasks()
+    spec, _fallback, shared, tasks = _toy_spec_and_tasks(n_features=47)
     ref = None
     for make_backend in (TPUBackend, LocalBackend):
         bk = make_backend()
@@ -340,7 +340,9 @@ def test_backend_books_round_sizing(how, want, monkeypatch):
         out = bk.batched_map_iterative(
             spec, tasks, shared,
             round_size=20 if how == "round_size" else None,
-            cache_key=("tc", "iter", make_backend.__name__),
+            # a key of this toy's own: the kernels memoised under
+            # ("tc", ...) were built for the six-column toy
+            cache_key=("tc47", "iter", make_backend.__name__),
         )
         stats = bk.last_round_stats
         chunk, basis, fit = want[bk.n_task_slots]
@@ -381,6 +383,10 @@ def test_iterative_oom_falls_back_to_classic(monkeypatch):
             cache_key=("tc", "iter", "TPUBackend"),
         )
     np.testing.assert_array_equal(ref["W"], out["W"])
+    # the refusal is booked on the dispatch that answered, and counted
+    stats = bk.last_round_stats
+    assert stats["refused"] == 1 and stats["retries"] == 0
+    assert stats["mode"] != "compacted"
 
 
 def test_iterative_no_recompile_after_warmup(tpu_backend):
@@ -464,8 +470,10 @@ def test_search_one_round_matches_eight():
     from skdist_tpu.models import LogisticRegression
 
     rng = np.random.RandomState(3)
-    X = rng.normal(size=(4000, 96)).astype(np.float32)
-    y = (X @ rng.normal(size=96) + rng.normal(size=4000) > 0).astype(int)
+    # wide enough that 24 lanes' own bytes (their row-sized values at
+    # the program's fullest point) stay under the matrix's
+    X = rng.normal(size=(4000, 320)).astype(np.float32)
+    y = (X @ rng.normal(size=320) + rng.normal(size=4000) > 0).astype(int)
 
     def search(backend, partitions):
         return DistGridSearchCV(

@@ -275,10 +275,9 @@ def test_compacted_counts_in_caller_order(one_device_backend):
             StratifiedKFold(cv).split(X, y)):
         masks[s, train] = 1.0
 
-    def whole(C, mask):
+    def whole(C, mask, X):
         hyper = {"C": C, "tol": jnp.float32(est.tol)}
-        loss, w0, _ = problem(data["X"], data["y"], data["sw"] * mask,
-                              hyper)
+        loss, w0, _ = problem(X, data["y"], data["sw"] * mask, hyper)
         carry = lbfgs_carry_init(loss, w0, max_iter, hyper["tol"])
         carry = lbfgs_resume(loss, carry, max_iter, max_iter,
                              hyper["tol"])
@@ -288,13 +287,17 @@ def test_compacted_counts_in_caller_order(one_device_backend):
     # since a CPU matmul's rounding may follow the batch width
     task_C = np.repeat(np.asarray(Cs, np.float32), cv)
     task_mask = np.tile(masks, (len(Cs), 1))
+    # X an argument, as the search's programs take it: closed over, the
+    # compiler folds the products' operand and rounds them otherwise
     solve = jax.jit(jax.vmap(
-        maybe_exact_matmuls(LogisticRegression, whole)))
+        maybe_exact_matmuls(LogisticRegression, whole),
+        in_axes=(0, 0, None)))
     chunk = stats["chunk"]
     assert n_tasks % chunk == 0
     want_it, want_nfev = [], []
     for lo in range(0, n_tasks, chunk):
-        it, nfev = solve(task_C[lo:lo + chunk], task_mask[lo:lo + chunk])
+        it, nfev = solve(task_C[lo:lo + chunk], task_mask[lo:lo + chunk],
+                         data["X"])
         want_it += [int(v) for v in it]
         want_nfev += [int(v) for v in nfev]
     assert stats["iters"] == want_it

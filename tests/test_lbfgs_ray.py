@@ -158,11 +158,13 @@ def test_an_iteration_takes_three_products_over_x(name):
 
     along_the_ray = one_iteration(loss)
     assert [in_while for _, in_while in along_the_ray] == [False] * 3
-    # X̃ is (N, D + 1): two products contract its columns with a weight
-    # vector (or matrix), the third its rows with the residual
-    others = [min((v.aval.shape for v in eqn.invars), key=np.prod)
-              for eqn, _ in along_the_ray]
-    assert sorted(shape[0] for shape in others) == [D + 1, D + 1, N]
+    # X is (N, D), the intercept rides beside the products: two of them
+    # contract its columns with a weight vector (or matrix), the third
+    # its rows with the residual
+    contracted = [
+        eqn.invars[0].aval.shape[eqn.params["dimension_numbers"][0][0][0]]
+        for eqn, _ in along_the_ray]
+    assert sorted(contracted) == [D, D, N]
 
     def plain(w):
         return loss(w)

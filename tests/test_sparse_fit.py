@@ -222,37 +222,69 @@ def test_packed_empty_rows_and_empty_matrix():
         np.zeros(5, np.float32))
 
 
-def test_linear_operator_dense_matches_legacy_expressions():
-    """The dense branch must reproduce the historical ops verbatim —
-    the dense paths' pinned numerics depend on it. The one that moved
-    (PR 29): against a weight VECTOR the product is written lanes-first,
-    ``w @ Xa.T`` — the same contraction, whose logits a ``vmap`` over
-    lanes lays out ``(lanes, n)`` instead of ``(n, lanes)``."""
+@pytest.mark.parametrize("fit_intercept", [True, False])
+def test_linear_operator_dense_matches_the_concatenated_copy(fit_intercept):
+    """The dense operator carries the intercept BESIDE its products
+    (no copy of X with a ones column exists in a program): all five
+    contractions, and the classes-first ``logits``, against the plain
+    expressions over the concatenated copy. Against a weight VECTOR the
+    product is written lanes-first (PR 29), against a weight MATRIX
+    ``logits`` is classes-first: a ``vmap`` over lanes lays them out
+    ``(lanes, n)`` and ``(lanes, k, n)``, rows minor."""
     import jax
     import jax.numpy as jnp
 
     rng = np.random.RandomState(5)
-    X = jnp.asarray(rng.normal(size=(30, 7)).astype(np.float32))
-    op = LinearOperator(X, fit_intercept=True)
-    Xa = jnp.concatenate([X, jnp.ones((30, 1), X.dtype)], axis=1)
-    w = jnp.asarray(rng.normal(size=8).astype(np.float32))
-    np.testing.assert_array_equal(np.asarray(op.matvec(w)),
-                                  np.asarray(w @ Xa.T))
-    np.testing.assert_allclose(np.asarray(op.matvec(w)),
-                               np.asarray(Xa @ w), rtol=1e-6, atol=1e-6)
-    lanes = jnp.asarray(rng.normal(size=(4, 8)).astype(np.float32))
-    assert jax.vmap(op.matvec)(lanes).shape == (4, 30)
-    assert jax.make_jaxpr(jax.vmap(op.matvec))(lanes).jaxpr.eqns[-1] \
-        .primitive.name == "dot_general"  # no transpose after the product
-    W = jnp.asarray(rng.normal(size=(8, 3)).astype(np.float32))
-    np.testing.assert_array_equal(np.asarray(op.matvec(W)),
-                                  np.asarray(Xa @ W))
-    sw = jnp.asarray(rng.rand(30).astype(np.float32))
-    T = jnp.asarray(rng.normal(size=(30, 2)).astype(np.float32))
-    G, b = op.weighted_gram_rhs(sw, T)
-    Xw = Xa * sw[:, None]
-    np.testing.assert_array_equal(np.asarray(G), np.asarray(Xa.T @ Xw))
-    np.testing.assert_array_equal(np.asarray(b), np.asarray(Xw.T @ T))
+    n, d, k = 30, 7, 3
+    X = jnp.asarray(rng.normal(size=(n, d)).astype(np.float32))
+    op = LinearOperator(X, fit_intercept=fit_intercept)
+    Xa = (jnp.concatenate([X, jnp.ones((n, 1), X.dtype)], axis=1)
+          if fit_intercept else X)
+    p = d + int(fit_intercept)
+    assert (op.p, op.class_axis) == (p, 0)
+    close = dict(rtol=2e-6, atol=2e-6)
+    w = jnp.asarray(rng.normal(size=p).astype(np.float32))
+    W = jnp.asarray(rng.normal(size=(p, k)).astype(np.float32))
+    np.testing.assert_allclose(op.matvec(w), Xa @ w, **close)
+    np.testing.assert_allclose(op.matvec(W), Xa @ W, **close)
+    np.testing.assert_allclose(op.logits(W), (Xa @ W).T, **close)
+    for r in (rng.normal(size=n), rng.normal(size=(n, k))):
+        r = jnp.asarray(r.astype(np.float32))
+        np.testing.assert_allclose(op.rmatvec(r), Xa.T @ r, **close)
+    i = jnp.asarray([4, 0, 29, 4])
+    np.testing.assert_allclose(op.row_matvec(i, w), Xa[i] @ w, **close)
+    np.testing.assert_allclose(op.row_matvec(i, W), Xa[i] @ W, **close)
+    for g in (rng.normal(size=4), rng.normal(size=(4, k))):
+        g = jnp.asarray(g.astype(np.float32))
+        np.testing.assert_allclose(op.row_rmatvec(i, g), Xa[i].T @ g,
+                                   **close)
+    sw = jnp.asarray(rng.rand(n).astype(np.float32))
+    for T in (rng.normal(size=(n, 2)), rng.normal(size=n)):
+        T = jnp.asarray(T.astype(np.float32))
+        G, b = op.weighted_gram_rhs(sw, T)
+        Xw = Xa * sw[:, None]
+        np.testing.assert_allclose(G, Xa.T @ Xw, **close)
+        np.testing.assert_allclose(b, Xw.T @ T, **close)
+    # the gradient a solver takes through the products is X̃ᵀ r
+    r = jnp.asarray(rng.normal(size=(k, n)).astype(np.float32))
+    np.testing.assert_allclose(
+        jax.grad(lambda W: jnp.sum(op.logits(W) * r))(W), Xa.T @ r.T,
+        **close)
+    # under vmap the products come out rows-minor, no transpose after
+    lanes = jnp.asarray(rng.normal(size=(4, p)).astype(np.float32))
+    Lanes = jnp.asarray(rng.normal(size=(4, p, k)).astype(np.float32))
+    for fn, arg, shape in ((op.matvec, lanes, (4, n)),
+                           (op.logits, Lanes, (4, k, n))):
+        eqns = jax.make_jaxpr(jax.vmap(fn))(arg).jaxpr.eqns
+        dots = [e for e in eqns if e.primitive.name == "dot_general"]
+        assert [e.outvars[0].aval.shape for e in dots] == [shape]
+        after = eqns[eqns.index(dots[0]):]
+        assert not any(e.primitive.name == "transpose" for e in after)
+    # no value of a program is a second X
+    for fn, arg in ((op.matvec, w), (op.logits, W), (op.rmatvec, sw)):
+        assert not any(
+            v.aval.shape in ((n, d + 1), (d + 1, n))
+            for e in jax.make_jaxpr(fn)(arg).jaxpr.eqns for v in e.outvars)
 
 
 # ---------------------------------------------------------------------------
