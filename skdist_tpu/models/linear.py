@@ -384,6 +384,16 @@ def _ray_loss(matvec, row_loss, reg_loss, scope, linear=True):
     return loss, data_loss
 
 
+def _start(seed, size, dtype, flat=jnp.ravel):
+    """Where an L-BFGS solve starts: zeros, or a warm start's ``seed``
+    — ``_warm_w0_flat``'s vector, a ``(p, k)`` weight matrix rows-major
+    — taken through ``flat`` into the problem's own flat layout of
+    ``size`` entries."""
+    if seed is None:
+        return jnp.zeros(size, dtype)
+    return flat(jnp.asarray(seed, dtype)).reshape(size)
+
+
 def get_kernel(cls, which, meta, static):
     """Fetch a (possibly jitted) kernel from the process-wide cache.
 
@@ -529,9 +539,13 @@ class _LinearModelBase(BaseEstimator):
         ``coef_``/``intercept_``) onto the family's flat solver
         layout: ``W`` is ``(p, n_out)`` with rows ``[:d]`` the
         coefficients and row ``d`` the intercept (when fitted),
-        flattened to ``(p,)`` single-output / ``(p*n_out,)``
-        multiclass — exactly the layout ``unpack`` reshapes and the
-        host engines' ``x0`` consumes."""
+        flattened rows-major to ``(p,)`` single-output /
+        ``(p*n_out,)`` multiclass — what the host engines' ``x0`` and
+        the streamed driver consume as it is. A device fit's problem
+        takes it into its own flat layout (``_start``): the
+        multinomial's through the ``LinearOperator`` of X's
+        representation (``LinearOperator.flat``), whose inverse
+        ``unpack`` reads the fitted matrix back with."""
         fit_intercept = self._fit_intercept_flag()
         d = int(d)
         n_out = int(n_out)
@@ -809,7 +823,9 @@ class _LbfgsFitMixin:
     @classmethod
     def _flat_w_width(cls, meta, static):
         """Flat weight-vector width of this family's solve — what the
-        streamed driver allocates per task without tracing a kernel."""
+        streamed driver allocates per task without tracing a kernel
+        (streamed blocks are dense or padded pairs, whose weight
+        matrices lie rows-major: ``LinearOperator.flat_size``)."""
         st = dict(static)
         p = meta["n_features"] + (1 if st["fit_intercept"] else 0)
         k = meta.get("n_classes", 2)
@@ -839,11 +855,12 @@ class _LbfgsFitMixin:
         max_iter, hist = st["max_iter"], st["history"]
 
         def kernel(X, y_idx, sw, hyper, aux=None):
-            loss, w0, unpack = problem(X, y_idx, sw, hyper)
-            if aux is not None and "w0" in aux:
-                # warm start: the solve begins at the caller's seed
-                # (a parent fit's coefficients in the flat layout)
-                w0 = jnp.asarray(aux["w0"], w0.dtype).reshape(w0.shape)
+            # warm start: the solve begins at the caller's seed (a
+            # parent fit's coefficients as ``_warm_w0_flat`` hands
+            # them), laid out by the problem
+            loss, w0, unpack = problem(
+                X, y_idx, sw, hyper,
+                seed=None if aux is None else aux.get("w0"))
             w, n_iter = lbfgs_minimize(loss, w0, max_iter=max_iter,
                                        tol=hyper["tol"], history=hist)
             return unpack(w, n_iter)
@@ -1031,7 +1048,7 @@ class LogisticRegression(_LbfgsFitMixin, _LinearClassifierBase):
         unpenalized = penalty in (None, "none")
         bf16 = md == "bfloat16"
 
-        def problem(X, y_idx, sw, hyper, parts=False):
+        def problem(X, y_idx, sw, hyper, parts=False, seed=None):
             C = hyper["C"]
             # one matvec interface over dense AND packed-CSR X: the
             # operator reproduces the historical dense expressions
@@ -1064,7 +1081,7 @@ class LogisticRegression(_LbfgsFitMixin, _LinearClassifierBase):
 
                 loss, data_loss = _ray_loss(
                     op.matvec, row_loss, reg_loss, "lr", linear=not bf16)
-                w0 = jnp.zeros(p, op.dtype)
+                w0 = _start(seed, p, op.dtype)
 
                 def unpack(w, n_iter):
                     return {"W": w, "n_iter": n_iter}
@@ -1086,19 +1103,23 @@ class LogisticRegression(_LbfgsFitMixin, _LinearClassifierBase):
                     return jnp.sum(
                         sw * (lse - jnp.sum(onehot * logits, axis=ax)))
 
+            # the weight matrix lies in the flat vector as the
+            # operator lays it (``LinearOperator.weights``): no
+            # statement here shapes it by hand
             def reg_loss(wflat):
                 if unpenalized:  # penalty=None: sklearn's C=inf
                     return jnp.float32(0.0)
-                W = wflat.reshape(p, k)
-                return 0.5 / C * jnp.sum(W[:d] * W[:d])
+                W = op.weights(wflat, k)
+                return 0.5 / C * op.coef_sq_sum(W)
 
             loss, data_loss = _ray_loss(
-                lambda wflat: op.logits(wflat.reshape(p, k)),
+                lambda wflat: op.logits(op.weights(wflat, k)),
                 row_loss, reg_loss, "lr", linear=not bf16)
-            w0 = jnp.zeros(p * k, op.dtype)
+            w0 = _start(seed, op.flat_size(k), op.dtype,
+                        lambda W: op.flat(W.reshape(p, k)))
 
             def unpack(w, n_iter):
-                return {"W": w.reshape(p, k), "n_iter": n_iter}
+                return {"W": op.matrix(w, k), "n_iter": n_iter}
 
             if parts:
                 return loss, w0, unpack, data_loss, reg_loss
@@ -1244,7 +1265,7 @@ class LinearSVC(_LbfgsFitMixin, _LinearClassifierBase):
             # not silently fit squared hinge (ADVICE r05 #3)
             raise ValueError("LinearSVC supports loss='squared_hinge'")
 
-        def problem(X, y_idx, sw, hyper, parts=False):
+        def problem(X, y_idx, sw, hyper, parts=False, seed=None):
             C = hyper["C"]
             # dense or packed-CSR X behind one matvec interface (see
             # LogisticRegression._build_fit_problem); data/reg split as
@@ -1265,7 +1286,7 @@ class LinearSVC(_LbfgsFitMixin, _LinearClassifierBase):
 
                 loss, data_loss = _ray_loss(
                     op.matvec, row_loss, reg_loss, "svc")
-                w0 = jnp.zeros(p, op.dtype)
+                w0 = _start(seed, p, op.dtype)
 
                 def unpack(w, n_iter):
                     return {"W": w, "n_iter": n_iter}
@@ -1287,7 +1308,7 @@ class LinearSVC(_LbfgsFitMixin, _LinearClassifierBase):
             loss, data_loss = _ray_loss(
                 lambda wflat: op.matvec(wflat.reshape(p, k)),
                 row_loss, reg_loss, "svc")
-            w0 = jnp.zeros(p * k, op.dtype)
+            w0 = _start(seed, p * k, op.dtype)
 
             def unpack(w, n_iter):
                 return {"W": w.reshape(p, k), "n_iter": n_iter}

@@ -128,6 +128,11 @@ HEAD_MIN_DENSITY = 1.0 / 512
 #: columns
 HEAD_MAX_BYTES = 1 << 30
 
+#: a weight matrix's rows in a bucketed solve's flat vector are padded to
+#: a multiple of this, the lanes of a TPU tile
+#: (:class:`_BucketedOperator`)
+ROW_ALIGN = 128
+
 #: explicit row-chunk override for the weighted-gram contraction; the
 #: automatic chunking derives from the meminfo budget (see
 #: :func:`packed_weighted_gram`)
@@ -761,24 +766,37 @@ def _head_product(head, operand, bf16, transposed):
 
 
 @functools.lru_cache(maxsize=None)
-def _spmm(bf16, transposed, ones):
-    """``(side, W (p, c)) -> (n, c)``: the product of one orientation
-    of a :class:`BucketedX` with a matrix — ``side`` is its ``(blocks,
-    inv, head, head_cols)`` — bucket by bucket, back in the matrix's
-    own row order, plus the dense head's matmul. ``ones`` says whether
-    the design matrix has its column of ones (the intercept): forward,
-    ``W`` then has one more row, the bias, added to every output row;
-    ``transposed``, one more output row holds ``W``'s column sums.
+def _spmm(bf16, transposed, ones, d, width):
+    """``(side, At (c, r)) -> (c, r')``: the product of one orientation
+    of a :class:`BucketedX` of ``d`` columns with a matrix, operand and
+    result both CLASSES-MAJOR (``(M @ At.T).T``) — ``side`` is the
+    orientation's ``(blocks, inv, head, head_cols)`` — bucket by
+    bucket, back in the matrix's own row order, plus the dense head's
+    matmul. The weights' side of either product has rows of ``width``
+    entries: the ``d`` coefficients, the bias where ``ones`` (the
+    design matrix's column of ones), zeros after (the aligned rows of
+    :class:`_BucketedOperator`). Forward, ``At (c, width) -> (c, n)``:
+    the bias at ``d`` is added to every output, the zeros are never
+    gathered. ``transposed``, ``At (c, n) -> (c, width)``: entry ``d``
+    holds ``At``'s row sums and the rest comes back zero.
 
-    Under ``vmap`` over ``W`` the batch moves onto the column axis —
-    ``(L, p, c) -> (p, L·c)`` — and the same contraction runs once for
-    all lanes: every gathered row is then ``L·c`` contiguous floats,
+    The gathers take rows of ``At.T``, the classes on the contiguous
+    axis: ONE two-dimensional transpose on a product's way in and one
+    on its way out, which XLA runs as its native tiled copy. Under
+    ``vmap`` the lanes join the classes — ``(L, c, r) -> (L·c, r)``, a
+    reshape that moves nothing — and the same contraction runs once
+    for all lanes: every gathered row is ``L·c`` contiguous floats,
     where the batching rule of a plain gather would leave rows of ``c``
     (20 classes pad to the 128 lanes of a TPU tile, six times the
-    bytes, in the operand and in every gathered block)."""
-    def product(side, W):
+    bytes, in the operand and in every gathered block). The lanes lay
+    on the OTHER side of the rows once, ``(L, p, c) -> (p, L·c)``: no
+    axis stayed minor, and XLA moved a round's weights lane by lane
+    through ``(L, p, c)`` buffers padded to 128, a third of the text
+    cell's device time (PERF.md, PR 34)."""
+    def product(side, At):
         blocks, inv, head, head_cols = side
         with jax.named_scope("sparse/spmm"):
+            W = At.T
             out = jnp.concatenate(
                 [_gather_rowsum(idx, val, W, bf16) for idx, val in blocks])[inv]
             if head is not None and not transposed:
@@ -786,32 +804,61 @@ def _spmm(bf16, transposed, ones):
             if head is not None and transposed:
                 out = out.at[head_cols].add(
                     _head_product(head, W, bf16, True))
-            if ones and not transposed:
-                return out + W[-1]
-            if ones:
-                return jnp.concatenate([out, jnp.sum(W, axis=0)[None]])
-            return out
+            if not transposed:
+                if ones:
+                    out = out + W[d]
+                # the head's matmul stays as written, ``head`` streaming
+                # against the weights: fused with the transpose below
+                # XLA turns it round (``Wᵀ @ headᵀ``, the 0.7 GB head
+                # the stationary operand for a round's 140 columns) and
+                # it takes 3.1 ms where this takes 1.9 (PERF.md, PR 34)
+                return jax.lax.optimization_barrier(out).T
+            tail = [jnp.sum(W, axis=0)[None]] if ones else []
+            if width > d + len(tail):
+                tail.append(jnp.zeros(
+                    (width - d - len(tail), W.shape[1]), out.dtype))
+            return jnp.concatenate([out] + tail).T
 
     spmm = jax.custom_batching.custom_vmap(product)
 
     @spmm.def_vmap
-    def _(axis_size, in_batched, side, W):
+    def _(axis_size, in_batched, side, At):
         if any(jax.tree_util.tree_leaves(in_batched[0])):
             # a batch of matrices: no caller makes one; plain batching
             axes = jax.tree_util.tree_map(
                 lambda b: 0 if b else None, tuple(in_batched))
-            return jax.vmap(product, axes)(side, W), True
-        p, c = W.shape[1:]
-        wide = spmm(side, jnp.moveaxis(W, 0, 1).reshape(p, axis_size * c))
-        return jnp.moveaxis(wide.reshape(-1, axis_size, c), 1, 0), True
+            return jax.vmap(product, axes)(side, At), True
+        c, r = At.shape[1:]
+        wide = spmm(side, At.reshape(axis_size * c, r))
+        return wide.reshape(axis_size, c, -1), True
 
     return spmm
 
 
-def _as_columns(fn, W):
-    """``fn`` on a matrix, for ``W`` a vector or a matrix."""
+def _bucketed_product(X, transposed, bf16, intercept, width=None):
+    """``[X | 1] @ W`` — or, ``transposed``, ``[X | 1].T @ r`` — for a
+    :class:`BucketedX`, classes-major on both sides (:func:`_spmm`):
+    ``At (c, width) -> (c, n)``, ``(c, n) -> (c, width)``. ``width`` is
+    the weights' own ``d [+ 1]`` unless their rows are padded."""
+    ones = bool(intercept)
+    spmm = _spmm(bf16, transposed, ones, X.n_cols,
+                 X.n_cols + ones if width is None else width)
+    side = ((X.cols, X.tinv) if transposed else (X.rows, X.inv)) + (
+        X.head, X.head_cols)
+    scope = "sparse/rmatvec" if transposed else "sparse/matvec"
+
+    def product(At):
+        with jax.named_scope(scope):
+            return spmm(side, At)
+
+    return product
+
+
+def _classes_major(fn, W):
+    """``fn``, which takes and gives matrices classes-major, on ``W`` a
+    vector or a matrix with the classes on its columns."""
     W = jnp.asarray(W)
-    return fn(W[:, None])[:, 0] if W.ndim == 1 else fn(W)
+    return fn(W[None])[0] if W.ndim == 1 else fn(W.T).T
 
 
 def bucketed_matvec(X, W, bf16=False, intercept=False):
@@ -819,36 +866,36 @@ def bucketed_matvec(X, W, bf16=False, intercept=False):
     ``(d, k)`` — with ``intercept``, ``[X | 1] @ W`` for ``W`` of
     ``d + 1`` rows. No autodiff rule of its own beyond the gather's:
     the fit problems take :func:`bucketed_matvec_with_vjp`."""
-    spmm = _spmm(bf16, False, bool(intercept))
-    side = (X.rows, X.inv, X.head, X.head_cols)
-    with jax.named_scope("sparse/matvec"):
-        return _as_columns(lambda w: spmm(side, w), W)
+    return _classes_major(_bucketed_product(X, False, bf16, intercept), W)
 
 
 def bucketed_rmatvec(X, r, bf16=False, intercept=False):
     """``X.T @ r`` (``[X | 1].T @ r`` with ``intercept``) on a
     :class:`BucketedX` — the same gather-and-row-sum over the
     transposed orientation; ``r`` is ``(n,)`` or ``(n, k)``."""
-    spmm = _spmm(bf16, True, bool(intercept))
-    side = (X.cols, X.tinv, X.head, X.head_cols)
-    with jax.named_scope("sparse/rmatvec"):
-        return _as_columns(lambda g: spmm(side, g), r)
+    return _classes_major(_bucketed_product(X, True, bf16, intercept), r)
+
+
+def _with_transpose_as_vjp(forward, backward):
+    """``forward`` with ``backward``, its true transpose, as its
+    backward pass: the solvers differentiate the loss through the
+    forward product, and the gather's own transpose would be a
+    scatter-add."""
+    @jax.custom_vjp
+    def mv(W):
+        return forward(W)
+
+    mv.defvjp(lambda W: (mv(W), None), lambda _, g: (backward(g),))
+    return mv
 
 
 def bucketed_matvec_with_vjp(X, bf16=False, intercept=False):
-    """``W -> X @ W`` for a fixed :class:`BucketedX`, whose backward
-    pass IS :func:`bucketed_rmatvec` (the true transpose) — the solvers
-    differentiate the loss through the forward product, and the
-    gather's own transpose would be a scatter-add."""
-
-    @jax.custom_vjp
-    def mv(W):
-        return bucketed_matvec(X, W, bf16, intercept)
-
-    mv.defvjp(
-        lambda W: (mv(W), None),
-        lambda _, g: (bucketed_rmatvec(X, g, bf16, intercept),))
-    return mv
+    """``W -> X @ W`` (``W`` a vector or ``(p, k)``) for a fixed
+    :class:`BucketedX`, whose backward pass IS
+    :func:`bucketed_rmatvec`."""
+    return _with_transpose_as_vjp(
+        lambda W: bucketed_matvec(X, W, bf16, intercept),
+        lambda g: bucketed_rmatvec(X, g, bf16, intercept))
 
 
 def _bucket_rows(X, i):
@@ -913,6 +960,16 @@ class LinearOperator:
     and ``weighted_gram_rhs`` (``(X̃ᵀSX̃, (SX̃)ᵀT)``, the two sides of
     the ridge normal equations).
 
+    The representation also owns how a weight MATRIX ``(p, k)`` lies in
+    the flat vector a solve carries: :meth:`flat_size` entries,
+    :meth:`weights` the view of them :meth:`logits` takes (and
+    :meth:`coef_sq_sum` penalises), :meth:`matrix` and :meth:`flat` the
+    way to the true ``(p, k)`` and back — once a fit each, for the
+    fitted parameters and a warm start. Here, and for a dense or
+    padded-pair X, that is ``W.reshape(-1)``; a :class:`BucketedX`'s
+    lies classes-major in aligned rows (:class:`_BucketedOperator` says
+    why). A weight VECTOR has no layout.
+
     What the L-BFGS family makes of the products has moved since its
     line search runs along a ray (``models/linear._ray_loss``): a trial
     step's logits are the sum of two of them, ``X̃ @ w + t · X̃ @ d``,
@@ -945,10 +1002,32 @@ class LinearOperator:
         self.p = self.d + int(bool(fit_intercept))
 
     def logits(self, W):
-        """``X̃ @ W`` for a weight MATRIX ``(p, k)`` in the layout this
-        representation's product comes out in, the classes on
-        :attr:`class_axis` — what the multinomial loss reduces over."""
+        """``X̃ @ W`` for a weight MATRIX as :meth:`weights` views it,
+        in the layout this representation's product comes out in, the
+        classes on :attr:`class_axis` — what the multinomial loss
+        reduces over."""
         return self.matvec(W)
+
+    def flat_size(self, k):
+        """Entries of the flat vector of a ``(p, k)`` weight matrix."""
+        return self.p * k
+
+    def weights(self, wflat, k):
+        """The flat vector as :meth:`logits` takes it: ``(p, k)``."""
+        return wflat.reshape(self.p, k)
+
+    def coef_sq_sum(self, W):
+        """``Σ coef²`` of :meth:`weights`' view, what an L2 penalty
+        weighs: the intercepts are left out."""
+        return jnp.sum(W[:self.d] * W[:self.d])
+
+    def matrix(self, wflat, k):
+        """The flat vector as the true ``(p, k)`` weight matrix."""
+        return wflat.reshape(self.p, k)
+
+    def flat(self, W):
+        """``(p, k) -> (flat_size(k),)``: :meth:`matrix`'s inverse."""
+        return W.reshape(-1)
 
 
 class _DenseOperator(LinearOperator):
@@ -1103,17 +1182,66 @@ class _BucketedOperator(LinearOperator):
     """The intercept is NOT one more packed column here: a bucket's
     padding rows would carry it. It is the same column of ones applied
     beside the gathers (a broadcast add forward, a column sum backward:
-    :func:`_spmm`). The forward product carries a custom VJP whose
+    :func:`_spmm`). The forward products carry a custom VJP whose
     backward IS the transposed product, so the solvers differentiate
-    the loss through it."""
+    the loss through them.
 
-    __slots__ = ("bx", "_mv", "_icpt")
+    A weight matrix lies CLASSES-MAJOR in the flat vector, each class's
+    row padded to ``width = ⌈p / 128⌉ · 128`` entries
+    (:data:`ROW_ALIGN`): class ``c``'s ``p`` weights at ``[c·width,
+    c·width + p)``, its intercept at ``c·width + d``, zeros after. The
+    zeros stay zero through a solve — the transposed product returns
+    zero there, the penalty leaves them out, and every vector of the
+    solver is a sum of gradients — and no gather reads them. A round's
+    ``(lanes, k·width)`` vectors are then ``(lanes·k, width)`` as they
+    lie, the operand of :func:`_spmm` under ``vmap`` with nothing
+    moved, and the one transpose on each side of a product has a
+    multiple of a tile's 128 on its minor axis going in and coming
+    out. :meth:`logits` comes out classes-first, ``(k, n)``, as the
+    dense operator's. ``matvec`` and ``rmatvec`` keep the true
+    ``(p, k)`` on their weights' side, for the callers that shape a
+    flat vector by hand."""
+
+    __slots__ = ("bx", "_mv", "_logits", "_icpt", "width")
+
+    #: :meth:`logits` carries the classes first, ``(k, n)``
+    class_axis = 0
 
     def __init__(self, X, fit_intercept, matmul_dtype=None):
         super().__init__(X, fit_intercept, matmul_dtype)
         self.dtype = X.rows[0][1].dtype
         self.bx, self._icpt = X, bool(fit_intercept)
+        self.width = -(-self.p // ROW_ALIGN) * ROW_ALIGN
         self._mv = bucketed_matvec_with_vjp(X, self.bf16, self._icpt)
+        self._logits = _with_transpose_as_vjp(*(
+            _bucketed_product(X, transposed, self.bf16, self._icpt,
+                              self.width)
+            for transposed in (False, True)))
+
+    def logits(self, W):
+        return self._logits(W)
+
+    def flat_size(self, k):
+        return self.width * k
+
+    def weights(self, wflat, k):
+        return wflat.reshape(k, self.width)
+
+    def coef_sq_sum(self, W):
+        if not self._icpt:  # the padding is zero: it adds nothing
+            return jnp.sum(W * W)
+        # the intercepts masked where they lie, inside the one
+        # reduction: a slice of the coefficients beside it makes every
+        # trial step of a line search write its ``w + t·d`` out
+        at = jax.lax.broadcasted_iota(jnp.int32, W.shape, 1)
+        return jnp.sum(jnp.where(at == self.d, 0.0, W * W))
+
+    def matrix(self, wflat, k):
+        return self.weights(wflat, k)[:, :self.p].T
+
+    def flat(self, W):
+        return jnp.pad(
+            W.T, ((0, 0), (0, self.width - self.p))).reshape(-1)
 
     def matvec(self, W):
         return self._mv(W)

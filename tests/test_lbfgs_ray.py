@@ -59,7 +59,14 @@ def _matrix(representation, seed=0):
 
 def _problem(est, representation, seed=0):
     """The estimator's own fit problem over ``representation``, and the
-    same problem over the dense matrix: ``(loss, dense_loss, p)``."""
+    same problem over the dense matrix: ``(loss, dense_loss, p)``, ``p``
+    the length of the dense problem's flat vector. A weight matrix lies
+    in a problem's flat vector as the operator of its representation
+    lays it (``LinearOperator.weights``), so each loss carries the way
+    across: ``loss.seed`` takes a rows-major vector of ``p`` into the
+    problem's own layout (the warm start's way in), ``loss.rows`` a
+    vector of the problem's back (``unpack``'s way out), and
+    ``loss.width`` is the problem's own length."""
     X, Xd = _matrix(representation, seed)
     k = 2 if est.binary else 4
     y = np.random.RandomState(seed + 1).randint(0, k, N)
@@ -74,12 +81,17 @@ def _problem(est, representation, seed=0):
         problem = maybe_exact_matmuls(
             est.cls, est.cls._build_fit_problem(meta, static))
         Xj = jax.tree_util.tree_map(jnp.asarray, data["X"])
-        loss, w0, _ = problem(Xj, jnp.asarray(data["y"]),
-                              jnp.asarray(data["sw"]), hyper)
+        args = (Xj, jnp.asarray(data["y"]), jnp.asarray(data["sw"]), hyper)
+        loss, w0, unpack = problem(*args)
         out.append(maybe_exact_matmuls(est.cls, loss))
         if hasattr(loss, "ray"):
             out[-1].ray = loss.ray
-    return out[0], out[1], w0.shape[0]
+        out[-1].seed = lambda v, problem=problem, args=args: problem(
+            *args, seed=v)[1]
+        out[-1].rows = lambda v, unpack=unpack: jnp.ravel(
+            unpack(v, 0)["W"])
+        out[-1].width = w0.shape[0]
+    return out[0], out[1], out[1].width
 
 
 class _Est:
@@ -110,9 +122,12 @@ def test_ray_equals_the_loss_and_its_gradient_along_the_direction(
     representation — and the same against the dense problem."""
     loss, dense_loss, p = _problem(PROBLEMS[name], representation)
     rng = np.random.RandomState(5)
-    w = jnp.asarray(0.3 * rng.normal(size=p).astype(np.float32))
-    d = jnp.asarray(rng.normal(size=p).astype(np.float32))
-    d = d / jnp.linalg.norm(d)
+    w_rows = jnp.asarray(0.3 * rng.normal(size=p).astype(np.float32))
+    d_rows = jnp.asarray(rng.normal(size=p).astype(np.float32))
+    d_rows = d_rows / jnp.linalg.norm(d_rows)
+    w, d = loss.seed(w_rows), loss.seed(d_rows)
+    assert w.shape == (loss.width,)
+    np.testing.assert_array_equal(loss.rows(w), w_rows)
     with jax.default_matmul_precision("highest"):
         along, value_and_grad_at = loss.ray(w, d)
         for t in (1.0, 0.5, 0.125, 2.0 ** -12, 0.0):
@@ -122,9 +137,14 @@ def test_ray_equals_the_loss_and_its_gradient_along_the_direction(
             np.testing.assert_allclose(f_ray, f, rtol=2e-6)
             scale = float(jnp.max(jnp.abs(g)))
             np.testing.assert_allclose(g_ray, g, atol=2e-5 * scale)
-            f_d, g_d = jax.value_and_grad(dense_loss)(w + t * d)
+            # what a layout pads has no gradient: a solve leaves it 0
+            np.testing.assert_array_equal(loss.seed(loss.rows(g_ray)),
+                                          g_ray)
+            f_d, g_d = jax.value_and_grad(dense_loss)(
+                w_rows + t * d_rows)
             np.testing.assert_allclose(f_ray, f_d, rtol=2e-5)
-            np.testing.assert_allclose(g_ray, g_d, atol=2e-4 * scale)
+            np.testing.assert_allclose(loss.rows(g_ray), g_d,
+                                       atol=2e-4 * scale)
 
 
 def _products(jaxpr, min_size, in_while=False):
@@ -182,8 +202,8 @@ def test_ray_solve_sliced_equals_unsliced_and_the_plain_search(
     (``w``, ``f``, ``it``, ``nfev``), and it reaches the plain search's
     answer: the same rule, the same grid, trial values that round
     differently."""
-    loss, _dense, p = _problem(PROBLEMS[name], representation)
-    w0 = jnp.zeros(p, jnp.float32)
+    loss, _dense, _p = _problem(PROBLEMS[name], representation)
+    w0 = jnp.zeros(loss.width, jnp.float32)
     max_iter, tol = 12, 1e-4
 
     @jax.jit
@@ -224,3 +244,75 @@ def test_the_bfloat16_path_keeps_the_plain_search(name):
     assert not hasattr(loss, "ray")
     exact, _dense, _p = _problem(est, "dense")
     assert hasattr(exact, "ray")
+
+
+# ---------------------------------------------------------------------------
+# the dense programs are pinned
+# ---------------------------------------------------------------------------
+
+def _dense_sliced_program(k, which, n=64, d=12, lanes=4, n_slice=3):
+    """``(lowered text, equations by primitive)`` of one entry of
+    ``LogisticRegression``'s sliced fit — ``init``, ``step`` or
+    ``finalize`` — over a dense ``(n, d)`` X with ``k`` classes,
+    vmapped over ``lanes`` as a round runs it (X and the labels shared;
+    row weights, hyperparameters and the carry a lane's own)."""
+    import collections
+
+    X = np.random.RandomState(0).normal(size=(n, d)).astype(np.float32)
+    est = LogisticRegression(max_iter=9, engine="xla")
+    data, meta = est._prep_fit_data(X, np.arange(n) % k, None)
+    kernels = LogisticRegression._build_fit_slice_kernels(
+        meta, _freeze(est._static_config(meta)), n_slice)
+    args = (jnp.asarray(X), jnp.asarray(data["y"]), jnp.ones((lanes, n)),
+            {"C": jnp.ones(lanes), "tol": jnp.full(lanes, 1e-4)})
+    entry = {name: jax.vmap(
+        maybe_exact_matmuls(LogisticRegression, kernels[name]),
+        in_axes=(None, None, 0, 0) + (0,) * (name != "init"))
+        for name in ("init", "step", "finalize")}
+    if which != "init":
+        args += (jax.eval_shape(entry["init"], *args),)
+    counts = collections.Counter()
+
+    def count(jaxpr):
+        for eqn in jaxpr.eqns:
+            counts[eqn.primitive.name] += 1
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                count(sub)
+
+    count(jax.make_jaxpr(entry[which])(*args).jaxpr)
+    return jax.jit(entry[which]).lower(*args).as_text(), dict(counts)
+
+
+#: sha256 of the lowered text (what the persistent compile cache keys
+#: on, locations apart) and the number of jaxpr equations, nested ones
+#: included, read on the tree of PR 32 (jax 0.9.0) and unchanged by
+#: PR 34
+DENSE_PROGRAMS = {
+    (2, "init"): ("3e931c3eceedc0a0", 467),
+    (2, "step"): ("e5b16ef0e5808b30", 384),
+    (2, "finalize"): ("8678980d46fbfeb0", 3),
+    (3, "init"): ("5b8401f1e6cb99ba", 540),
+    (3, "step"): ("cf5ba08c10698117", 430),
+    (3, "finalize"): ("d377e0e1e65b812a", 7),
+}
+
+
+@pytest.mark.parametrize("k, which", sorted(DENSE_PROGRAMS))
+def test_dense_step_programs_are_pinned(k, which):
+    """The programs a dense search runs — the binary problem's
+    (``search-epsilon``) and the multinomial's (``search-mnist8m``) —
+    are the ones the benchmark's accepted numbers and the machines'
+    compile caches were read on: an edit to the fit problems, the
+    operator or the solver that reaches them changes a digest HERE and
+    not in a chip check (PR 33 was refused on such a cell). A change
+    that means to move them re-reads the table on its own tree and
+    says so; a newer jax prints other text and re-reads it on the
+    parent."""
+    import hashlib
+
+    text, counts = _dense_sliced_program(k, which)
+    digest = hashlib.sha256(text.encode()).hexdigest()[:16]
+    assert (digest, sum(counts.values())) == DENSE_PROGRAMS[k, which], (
+        f"the dense {'binary' if k == 2 else 'multinomial'} {which} "
+        f"program changed; its equations by primitive: "
+        f"{dict(sorted(counts.items()))}")

@@ -140,6 +140,241 @@ def test_bucketed_products_equal_dense(structure):
     np.testing.assert_allclose(gr, gr_ref, atol=5e-5)
 
 
+def _representation(name, d=600):
+    """``(X as the operator takes it, the same matrix dense)`` for a
+    dense array, a padded pair and a ``BucketedX`` of ``d`` columns."""
+    X = skewed_csr(seed=4, n=96, d=d, heavy=(250, 120))
+    Xd = X.toarray()
+    if name == "dense":
+        return jnp.asarray(Xd), Xd
+    if name == "padded":
+        return sx.PackedX(*map(jnp.asarray, sx.pack_csr_rows(X)), d), Xd
+    return jax.tree_util.tree_map(jnp.asarray, sx.pack_csr_buckets(X)), Xd
+
+
+@pytest.mark.parametrize("fit_intercept", [True, False],
+                         ids=["intercept", "plain"])
+@pytest.mark.parametrize("representation", ["dense", "padded", "bucketed"])
+def test_operator_owns_the_flat_layout_of_a_weight_matrix(
+        representation, fit_intercept):
+    """``flat`` / ``matrix`` round-trip a ``(p, k)`` weight matrix at a
+    ``p`` that no tile divides, for every representation; ``logits``
+    of ``weights``' view, its VJP and ``coef_sq_sum`` equal the dense
+    float32 expressions, one lane and vmapped. A dense or padded-pair
+    X keeps ``W.reshape(-1)``; a ``BucketedX``'s lies classes-major in
+    rows of the next multiple of 128, zeros after each class's ``p``
+    weights, and its VJP leaves those zeros exactly zero."""
+    d, k, lanes = 600, 5, 3
+    X, Xd = _representation(representation, d)
+    n = Xd.shape[0]
+    Xa = np.hstack([Xd, np.ones((n, 1), np.float32)]) if fit_intercept \
+        else Xd
+    op = sx.LinearOperator(X, fit_intercept)
+    p = d + int(fit_intercept)
+    assert op.p == p and p % sx.ROW_ALIGN
+    rng = np.random.RandomState(2)
+    W = rng.randn(lanes, p, k).astype(np.float32)
+    flat = np.asarray(jax.vmap(op.flat)(jnp.asarray(W)))
+    assert flat.shape == (lanes, op.flat_size(k))
+    np.testing.assert_array_equal(
+        jax.vmap(lambda w: op.matrix(w, k))(jnp.asarray(flat)), W)
+    if representation == "bucketed":
+        width = 640
+        assert (op.width, op.flat_size(k), op.class_axis) == (
+            width, k * width, 0)
+        rows = flat.reshape(lanes, k, width)
+        np.testing.assert_array_equal(rows[:, :, :p],
+                                      W.transpose(0, 2, 1))
+        assert not rows[:, :, p:].any()
+        assert op.weights(jnp.asarray(flat[0]), k).shape == (k, width)
+    else:
+        assert op.flat_size(k) == p * k
+        np.testing.assert_array_equal(flat, W.reshape(lanes, -1))
+        np.testing.assert_array_equal(
+            op.weights(jnp.asarray(flat[0]), k), W[0])
+    ax = op.class_axis
+    r = rng.randn(lanes, k, n).astype(np.float32)
+
+    def value(wflat, r):
+        Wv = op.weights(wflat, k)
+        z = op.logits(Wv)
+        z = z if ax == 0 else z.T
+        return jnp.sum(jnp.tanh(z) * r) + op.coef_sq_sum(Wv), z
+
+    with jax.default_matmul_precision("highest"):
+        one = jax.jit(jax.value_and_grad(value, has_aux=True))
+        many = jax.jit(jax.vmap(jax.value_and_grad(value, has_aux=True)))
+        (v, z), g = many(jnp.asarray(flat), jnp.asarray(r))
+        (v0, z0), g0 = one(jnp.asarray(flat[0]), jnp.asarray(r[0]))
+    for got_v, got_z, got_g, Wl, rl in [(v0, z0, g0, W[0], r[0])] + list(
+            zip(v, z, g, W, r)):
+        want_z = (Xa.astype(np.float64) @ Wl).T
+        np.testing.assert_allclose(got_z, want_z, atol=5e-5)
+        want_v = np.sum(np.tanh(want_z) * rl) + np.sum(Wl[:d] ** 2.0)
+        np.testing.assert_allclose(got_v, want_v, rtol=2e-5)
+        want_g = Xa.T.astype(np.float64) @ (
+            (1.0 - np.tanh(want_z) ** 2) * rl).T
+        want_g[:d] += 2.0 * Wl[:d]
+        np.testing.assert_allclose(op.matrix(got_g, k), want_g, atol=2e-4)
+        # what the layout pads has no gradient
+        np.testing.assert_array_equal(op.flat(op.matrix(got_g, k)), got_g)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of everything nested in it, in
+    order."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("transposed", [False, True],
+                         ids=["forward", "transposed"])
+def test_vmapped_product_is_one_transpose_on_each_side_of_the_gathers(
+        transposed):
+    """A round's multinomial product over a ``BucketedX``: the lanes
+    join the classes by a reshape, ONE transpose makes the gathers'
+    operand — rows of ``lanes·k`` contiguous floats — and one takes the
+    result back; nothing is moved lane by lane (no
+    ``dynamic_update_slice``, no transpose of three axes)."""
+    d, k, lanes = 600, 5, 7
+    B, Xd = _representation("bucketed", d)
+    n = Xd.shape[0]
+    op = sx.LinearOperator(B, True)
+
+    def logits(wflat):
+        return op.logits(op.weights(wflat, k))
+
+    w = jnp.zeros((lanes, op.flat_size(k)), jnp.float32)
+    if transposed:
+        def product(r):
+            return jax.vmap(lambda wl, rl: jax.vjp(logits, wl)[1](rl)[0])(
+                w, r)
+        jaxpr = jax.make_jaxpr(product)(jnp.zeros((lanes, k, n)))
+        wide_in, wide_out = (n, lanes * k), (op.width, lanes * k)
+    else:
+        jaxpr = jax.make_jaxpr(jax.vmap(logits))(w)
+        wide_in, wide_out = (op.width, lanes * k), (n, lanes * k)
+    if transposed:
+        # the forward product of ``jax.vjp`` is traced too: the
+        # transposed one is what follows its last equation
+        names = [e.primitive.name for e in _eqns(jaxpr.jaxpr)]
+        assert names.count("transpose") == 4
+    eqns = [e for e in _eqns(jaxpr.jaxpr)]
+    if transposed:
+        second = [i for i, e in enumerate(eqns)
+                  if e.primitive.name == "transpose"][2]
+        eqns = eqns[second:]
+    names = [e.primitive.name for e in eqns]
+    assert "dynamic_update_slice" not in names
+    turns = [e for e in eqns if e.primitive.name == "transpose"]
+    assert [(e.invars[0].aval.shape, e.outvars[0].aval.shape)
+            for e in turns] == [(wide_in[::-1], wide_in),
+                                (wide_out, wide_out[::-1])]
+    gathers = [i for i, n_ in enumerate(names) if n_ == "gather"]
+    assert gathers and names.index("transpose") < gathers[0]
+    assert gathers[-1] < len(names) - 1 - names[::-1].index("transpose")
+    for i in gathers:
+        assert eqns[i].invars[0].aval.shape[-1] == lanes * k
+
+
+def _sliced_multinomial_fit(X, y, Cs, max_iter=8, n_slice=4):
+    """The estimator's own sliced fit (``init``, ``step`` ...,
+    ``finalize``) vmapped over the lanes ``Cs``: the carry after the
+    last slice and the fitted parameters."""
+    from skdist_tpu.models import LogisticRegression
+    from skdist_tpu.models.linear import (
+        _freeze, maybe_exact_matmuls, prepare_fit_X)
+
+    est = LogisticRegression(max_iter=max_iter, engine="xla")
+    data, meta = est._prep_fit_data(
+        prepare_fit_X(X, LogisticRegression), y, None)
+    kernels = LogisticRegression._build_fit_slice_kernels(
+        meta, _freeze(est._static_config(meta)), n_slice)
+    args = (jax.tree_util.tree_map(jnp.asarray, data["X"]),
+            jnp.asarray(data["y"]), jnp.asarray(data["sw"]))
+
+    def run(C):
+        hyper = {"C": C, "tol": jnp.float32(1e-4)}
+        carry = kernels["init"](*args, hyper)
+        for _ in range(-(-max_iter // n_slice) - 1):
+            carry = kernels["step"](*args, hyper, carry)
+        return carry, kernels["finalize"](*args, hyper, carry)
+
+    return jax.jit(jax.vmap(maybe_exact_matmuls(LogisticRegression, run)))(
+        jnp.asarray(Cs, jnp.float32))
+
+
+def test_padding_is_zero_after_a_fit():
+    """A multinomial solve over a ``BucketedX`` runs on the aligned
+    layout from its start to ``unpack``: every padding entry of the
+    fitted carry (iterate, gradient, both histories) is exactly zero,
+    lane by lane, and the fit is the fit over the densified X — the
+    same iterations, coefficients within float32 rounding."""
+    from test_sparse_fit import _skewed_problem
+
+    X, y = _skewed_problem(seed=11, d=3000, k=4)
+    Cs = [0.02, 0.1, 0.5]
+    carry, params = _sliced_multinomial_fit(X, y, Cs)
+    p, k, width = 3001, 4, 3072
+    assert carry["w"].shape == (3, k * width)
+    assert carry["S"].shape == (3, 10, k * width)
+    for key in ("w", "g", "S", "Y"):
+        leaf = np.asarray(carry[key])
+        leaf = leaf.reshape(leaf.shape[:-1] + (k, width))
+        assert leaf[..., :p].any() and not leaf[..., p:].any(), key
+    assert params["W"].shape == (3, p, k)
+    dense_carry, dense = _sliced_multinomial_fit(
+        np.asarray(X.toarray(), np.float32), y, Cs)
+    assert dense_carry["w"].shape == (3, p * k)
+    np.testing.assert_array_equal(carry["it"], dense_carry["it"])
+    np.testing.assert_array_equal(carry["it"], 8)
+    np.testing.assert_allclose(params["W"], dense["W"], atol=2e-4)
+
+
+@pytest.mark.parametrize("warm", [False, True], ids=["cold", "warm"])
+def test_multinomial_fit_on_a_bucketed_x_equals_the_densified_fit(
+        warm, monkeypatch):
+    """``LogisticRegression.fit`` over a skewed CSR (packed
+    ``BucketedX``) gives the densified fit's ``coef_`` and
+    ``intercept_``, shapes and values — started cold, and warm from a
+    seed in sklearn's shapes, which the problem takes into the
+    operator's layout (``LinearOperator.flat``)."""
+    from skdist_tpu.models import LogisticRegression
+    from test_sparse_fit import _skewed_problem
+
+    X, y = _skewed_problem(seed=12, d=3000, k=4)
+    assert sx.pack_decision(X)[1] == "bucketed"
+    seeds = {}
+    if warm:
+        rng = np.random.RandomState(3)
+        seeds = dict(
+            coef_init=0.05 * rng.randn(4, 3000).astype(np.float32),
+            intercept_init=0.1 * rng.randn(4).astype(np.float32))
+
+    def fit(M, **kw):
+        return LogisticRegression(max_iter=300, tol=1e-5, engine="xla",
+                                  **kw).fit(M, y, **seeds)
+
+    packed = fit(X)
+    assert packed._meta.get("x_format") == "packed"
+    dense = fit(np.asarray(X.toarray(), np.float32))
+    assert packed.coef_.shape == (4, 3000)
+    assert packed.intercept_.shape == (4,)
+    assert 10 < packed.n_iter_ < 300 and 10 < dense.n_iter_ < 300
+    np.testing.assert_allclose(packed.coef_, dense.coef_, atol=5e-4)
+    np.testing.assert_allclose(packed.intercept_, dense.intercept_,
+                               atol=5e-4)
+    if warm:
+        # a solve of no iterations hands the seed back as it came
+        still = LogisticRegression(max_iter=0, engine="xla").fit(
+            X, y, **seeds)
+        np.testing.assert_array_equal(still.coef_, seeds["coef_init"])
+        np.testing.assert_array_equal(still.intercept_,
+                                      seeds["intercept_init"])
+
+
 def test_bf16_contract_on_the_bucketed_products():
     """``matmul_dtype='bfloat16'`` on a ``BucketedX``: the operands
     round to bf16, the row sums accumulate in float32, the head is one

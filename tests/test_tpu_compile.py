@@ -564,6 +564,61 @@ def test_lbfgs_history_moves_no_row_lane_by_lane(sds):
     assert _device_bytes(compiled) < HBM_BYTES
 
 
+def test_bucketed_multinomial_ray_moves_no_weights_lane_by_lane(sds):
+    """The lane-batched ray of the multinomial problem (``X̃w``, ``X̃d``,
+    a halving loop over ``along``, the VJP ``X̃ᵀr``) over a ``BucketedX``
+    of the text cell's WIDTH — 7 lanes, ``d`` 130,107, ``k`` 20; a
+    corpus of 2,048 rows, so that it compiles in seconds. A round's
+    weights lie classes-major in 128-aligned rows
+    (``_BucketedOperator``): ``(7, 20·130,176)`` is ``(140, 130,176)``
+    as it lies, and a 2-D copy on each side of a product is all the
+    relayout there is. Rows-major (PR 32) the same program kept
+    ``f32[7,130108,20]`` buffers — 20 classes padded to a tile's 128 —
+    and flattened them lane by lane through a 1-D ``f32[18215120]``:
+    a third of the cell's device time. The loops left are the gathers'
+    own scans and the line search's."""
+    from skdist_tpu import sparse as sx
+    from skdist_tpu.models import LogisticRegression
+    from skdist_tpu.models.linear import _freeze, maybe_exact_matmuls
+
+    n, d, k, lanes = 2048, 130_107, 20, 7
+    B = sx.pack_csr_buckets(_skewed_text_csr(n, d, 320_000))
+    est = LogisticRegression(max_iter=30, tol=1e-4)
+    data, meta = est._prep_fit_data(B, np.arange(n) % k, None)
+    problem = LogisticRegression._build_fit_problem(
+        meta, _freeze(est._static_config(meta)))
+
+    def ray_step(X, y, sw, C, w, direction, bound):
+        loss, w0, _ = problem(X, y, sw, {"C": C})
+        assert w0.shape == w.shape
+        along, value_and_grad_at = loss.ray(w, direction)
+        t = jax.lax.while_loop(
+            lambda t: (along(t) > bound) & (t > 1e-6),
+            lambda t: 0.5 * t, jnp.float32(1.0))
+        return value_and_grad_at(t)
+
+    width = 20 * 130_176
+    assert sx.LinearOperator(B, True).flat_size(k) == width
+    step = jax.jit(jax.vmap(
+        maybe_exact_matmuls(LogisticRegression, ray_step),
+        in_axes=(None, None, 0, 0, 0, 0, 0)))
+    compiled = step.lower(
+        _on_chip(B, sds), sds((n,), jnp.int32), sds((lanes, n)),
+        sds((lanes,)), sds((lanes, width)), sds((lanes, width)),
+        sds((lanes,))).compile()
+    hlo = compiled.as_text()
+    for gone in ("f32[7,130108,20]", "f32[130108,7,20]", "f32[18215120]",
+                 "f32[18224640]"):
+        assert gone not in hlo, gone
+    # the parser has to find what is there before what it does not find
+    # means anything: the transposes, as copies of their own
+    assert "copy" in set(_buffer_opcodes(hlo, "f32[140,130176]"))
+    scans = 2 * sum(idx.shape[0] > 1 for idx, _ in B.rows) + sum(
+        idx.shape[0] > 1 for idx, _ in B.cols)
+    assert 0 < len(re.findall(r"\bwhile\(", hlo)) <= scans + 1
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
 def test_matmul_tree_level_step_compiles(sds):
     """The forest tree kernel in ``hist_mode='matmul'`` (what a TPU
     resolves with no calibration entry) at 200,000 x 28, 32 bins, depth
