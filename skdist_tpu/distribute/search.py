@@ -758,6 +758,10 @@ class DistBaseSearchCV(BaseEstimator):
         # the packed form of X, where the batched path packed one: the
         # refit takes it instead of packing the same matrix again
         self._packed_X_ = None
+        # the dense X as the batched path placed it on a mesh with a
+        # ``data`` axis, a row shard a device: every bucket dispatches
+        # over it and the refit runs over it
+        self._mesh_X_ = None
         check_estimator_backend(self, self.verbose)
         backend = resolve_backend(self.backend, n_jobs=self.n_jobs)
         estimator = self.estimator
@@ -847,12 +851,8 @@ class DistBaseSearchCV(BaseEstimator):
         if self.refit:
             best = clone(estimator).set_params(**self.best_params_)
             refit_start = time.perf_counter()
-            refit_X = X if self._packed_X_ is None else self._packed_X_
             with obs_trace.span("refit"):
-                if y is not None:
-                    best.fit(refit_X, y, **fit_params)
-                else:
-                    best.fit(refit_X, **fit_params)
+                self._refit(backend, best, X, y, fit_params)
             self.refit_time_ = time.perf_counter() - refit_start
             self.best_estimator_ = best
             if self.preds:
@@ -864,9 +864,26 @@ class DistBaseSearchCV(BaseEstimator):
         # estimator.sc`, search.py:568-570 — a footgun we avoid: the
         # user's own estimator object keeps its backend)
         self.estimator = clone(self.estimator)
-        del self._packed_X_
+        del self._packed_X_, self._mesh_X_
         strip_runtime(self)
         return self
+
+    def _refit(self, backend, best, X, y, fit_params):
+        """Fit ``best`` on all of the data. Where the batched path ran
+        on a mesh with a ``data`` axis, over the row shards it placed
+        (``_LinearModelBase._fit_on_mesh``): a standalone ``fit``
+        places X whole on one device, which an operand that NEEDS the
+        mesh does not fit. Everywhere else ``best.fit``, on the packed
+        X where the search packed one."""
+        sw, sw_ok = full_length_sample_weight(fit_params, num_samples(X))
+        if self._mesh_X_ is not None and sw_ok and y is not None:
+            best._fit_on_mesh(backend, self._mesh_X_, y, sw)
+            return
+        refit_X = X if self._packed_X_ is None else self._packed_X_
+        if y is not None:
+            best.fit(refit_X, y, **fit_params)
+        else:
+            best.fit(refit_X, **fit_params)
 
     def _refit_metric(self, scorers, multimetric):
         if multimetric:
@@ -1240,6 +1257,16 @@ class DistBaseSearchCV(BaseEstimator):
                 "split": np.asarray(split_ids, dtype=np.int32),
             }
             specs = row_sharded_specs(backend, shared, _CV_SAMPLE_AXES)
+            if (specs is not None and not is_packed(X_arr)
+                    and hasattr(est_cls, "_fit_on_mesh")):
+                # a mesh with a ``data`` axis: X is placed once, a row
+                # shard a device, and every bucket's dispatch and the
+                # refit take it placed (a dispatch leaves a placed leaf
+                # where it is)
+                if self._mesh_X_ is None:
+                    self._mesh_X_ = backend.place_shared(
+                        {"X": shared["X"]}, {"X": specs["X"]})["X"]
+                shared["X"] = self._mesh_X_
             n_bucket = len(split_ids)
             # convergence-compacted path: iteration-sliced solvers +
             # live-task compaction, for families that support sliced

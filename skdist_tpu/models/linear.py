@@ -504,26 +504,52 @@ class _LinearModelBase(BaseEstimator):
                 finally:
                     del self._warm_w0
             return self._host_fit(X, y, sample_weight)
+        # placed here and not by the call: a host matrix of several GiB
+        # goes in row blocks (``backend.put_host_array``)
+        return self._device_fit(
+            X, y, sample_weight, lambda data: {**data, "X": _to_jnp(data["X"])},
+            coef_init, intercept_init)
+
+    def _device_fit(self, X, y, sample_weight, place, coef_init=None,
+                    intercept_init=None):
+        """The compiled fit over what ``place`` makes of the staged
+        ``{"X", "y", "sw"}``: where the operands lie is where the
+        kernel runs."""
         data, meta = self._prep_fit_data(X, y, sample_weight)
         static = self._static_config(meta)
         hyper = {k: jnp.asarray(hyper_float(getattr(self, k)))
                  for k in self._hyper_names}
         kernel = get_kernel(type(self), "fit", meta, _freeze(static))
-        # placed here and not by the call: a host matrix of several GiB
-        # goes in row blocks (``backend.put_host_array``)
-        data["X"] = _to_jnp(data["X"])
-        if warm:
+        data = place({k: data[k] for k in ("X", "y", "sw")})
+        args = (data["X"], data["y"], data["sw"], hyper)
+        if coef_init is not None or intercept_init is not None:
             k = meta.get("n_classes", 2)
             w0 = self._warm_w0_flat(
                 meta["n_features"], 1 if k <= 2 else k,
                 coef_init, intercept_init,
             )
-            params = kernel(data["X"], data["y"], data["sw"], hyper,
-                            {"w0": jnp.asarray(w0)})
-        else:
-            params = kernel(data["X"], data["y"], data["sw"], hyper)
-        self._set_fitted(params, meta)
+            args += ({"w0": jnp.asarray(w0)},)
+        self._set_fitted(kernel(*args), meta)
         return self
+
+    def _fit_on_mesh(self, backend, X, y, sample_weight=None):
+        """:meth:`fit`'s device fit over operands ROW-SHARDED on
+        ``backend``'s mesh (one with a ``data`` axis): the same jitted
+        fit kernel, so the partitioner cuts it the way it cuts a
+        round's step program — logits stay with their rows, ``X̃ᵀr``
+        and the loss's row sums are reduced between the devices. ``X``
+        is dense and already placed, a row shard a device
+        (``TPUBackend.place_shared``: a search hands over the X its
+        dispatches ran on); labels and weights are placed here the same
+        way. No device ever holds X whole."""
+        from ..parallel.backend import row_sharded_specs
+
+        def place(data):
+            rows = {"y": data["y"], "sw": data["sw"]}
+            return {"X": data["X"], **backend.place_shared(
+                rows, row_sharded_specs(backend, rows, {"y": 0, "sw": 0}))}
+
+        return self._device_fit(X, y, sample_weight, place)
 
     def _warm_n_out(self, y):
         """Solver output columns for warm-seed shaping, before meta

@@ -27,6 +27,7 @@ import collections
 import logging
 import math
 import os
+import re
 import time
 import warnings
 from concurrent.futures import ThreadPoolExecutor
@@ -64,14 +65,21 @@ def prefers_host_engine(backend, estimator):
     return bool(resolve())
 
 
-def tree_nbytes(tree):
+def tree_nbytes(tree, per_device=False):
     """Total leaf bytes of a pytree — the placement layer's shared-data
     byte accounting (registered pytree containers like
-    ``sparse.PackedX`` contribute their actual leaves)."""
+    ``sparse.PackedX`` contribute their actual leaves). ``per_device``:
+    of a PLACED tree, what one device holds — a sharded leaf counts
+    its shard, a replicated one the whole."""
     import jax
 
+    def shape(leaf):
+        if per_device and hasattr(leaf, "sharding"):
+            return leaf.sharding.shard_shape(leaf.shape)
+        return leaf.shape
+
     return int(sum(
-        int(np.prod(l.shape)) * np.dtype(l.dtype).itemsize
+        int(np.prod(shape(l))) * np.dtype(l.dtype).itemsize
         for l in jax.tree_util.tree_leaves(tree)
         if hasattr(l, "shape")
     ))
@@ -134,7 +142,9 @@ class TaskBackend:
     #: (``sparse.PackedX``) contributes its idx+val bytes, NOT its
     #: logical dense size, so this is the number that shows the sparse
     #: plane's device-memory win (and what the sparse fit smoke
-    #: asserts shrank)
+    #: asserts shrank). On a mesh with a ``data`` axis it counts ONE
+    #: device's share (a row-sharded leaf its shard), which is what
+    #: round sizing holds against one device's free memory
     last_shared_bytes = None
 
     def run_tasks(self, fn, tasks, verbose=0):
@@ -297,10 +307,10 @@ class IterativePlan:
     loop (:func:`_run_compacted`)."""
 
     __slots__ = ("init_fn", "step_fn", "fin_fn", "score_fn", "shared",
-                 "put", "n_task_slots", "_shared_sig")
+                 "put", "n_task_slots", "data_shards", "_shared_sig")
 
     def __init__(self, init_fn, step_fn, fin_fn, score_fn, shared, put,
-                 n_task_slots=1):
+                 n_task_slots=1, data_shards=1):
         self.init_fn = init_fn
         self.step_fn = step_fn
         self.fin_fn = fin_fn
@@ -308,6 +318,8 @@ class IterativePlan:
         self.shared = shared
         self.put = put
         self.n_task_slots = n_task_slots
+        # devices that share the rows of each shared operand
+        self.data_shards = data_shards
         self._shared_sig = compile_cache.shape_sig(shared)
 
 
@@ -774,12 +786,23 @@ class TPUBackend(TaskBackend):
                  sync_rounds=None, donate_tasks=True, elastic=None):
         """``data_axis_size`` > 1 builds a 2D ('tasks', 'data') mesh:
         that many devices cooperate on each task with row-sharded shared
-        data (GSPMD inserts the psum of gram/gradient partials over
-        ICI), while tasks fan out over the remaining factor. The default
-        1D mesh replicates shared data and gives every task one device.
-        An explicit ``mesh`` (e.g. from ``parallel.mesh`` helpers) is
-        used as-is; its leading axis is the task axis and a 'data' axis,
-        if present, row-shards.
+        data, while tasks fan out over the remaining factor — the mesh
+        for an operand no single device holds (``data_axis_size`` equal
+        to the device count: every lane on every device, each device a
+        row shard). A row-sharded host array is placed shard by shard,
+        each shard in row blocks, the devices' transfers in flight
+        together (:func:`_put_mesh_scoped`); round sizing, and the
+        ``shared_bytes`` / ``lane_bytes`` / ``logits_bytes`` /
+        ``round_bytes_estimate`` it books, count ONE device's share; the
+        partitioner keeps a value with an axis of the data's rows
+        sharded on it through a solver's loop and reduces partial sums
+        only (``collective_ops_compiled`` / ``collective_bytes_compiled``
+        of the round stats say what it inserted in the step program);
+        a search places its X once (:meth:`place_shared`) and refits
+        over those shards. The default 1D mesh replicates shared
+        data and gives every task one device. An explicit ``mesh``
+        (e.g. from ``parallel.mesh`` helpers) is used as-is; its leading
+        axis is the task axis and a 'data' axis, if present, row-shards.
 
         ``reuse_broadcast=True`` caches device-resident copies of shared
         arrays across fits (keyed by host-array identity + sharding), so
@@ -939,6 +962,15 @@ class TPUBackend(TaskBackend):
     def n_task_slots(self):
         return self.n_devices
 
+    def place_shared(self, shared_args, shared_specs=None):
+        """``shared_args`` on the mesh as a dispatch would place them
+        (``shared_specs``: :func:`row_sharded_specs`), for a caller
+        that holds an operand across dispatches — a placed leaf handed
+        to a later dispatch stays where it is — or runs a program of
+        its own over it (a search on a mesh with a ``data`` axis does
+        both with its X)."""
+        return self._resolve_placement(shared_args, shared_specs)[2]
+
     def _resolve_placement(self, shared_args, shared_specs):
         """Shared sharding/placement logic of the batched plans: resolve
         the task-axis and shared shardings, place the shared args
@@ -947,7 +979,9 @@ class TPUBackend(TaskBackend):
         shared_args_placed, put)``.
 
         The placement runs under a ``place_shared`` span (``args``:
-        ``bytes``). With tracing ENABLED the span ends only after
+        ``bytes``, the tree's; ``shards``, the devices that share each
+        row-sharded leaf; ``bytes_per_device``, what one device holds of
+        it). With tracing ENABLED the span ends only after
         ``jax.block_until_ready`` of the placed tree, so its duration
         is the transfer's and not the enqueue's — the one place tracing
         changes what the host does (untraced, the transfer overlaps the
@@ -969,14 +1003,11 @@ class TPUBackend(TaskBackend):
         else:
             shared_shardings = rep_sharding
         tracing = obs_trace.enabled()
-        with obs_trace.span(
-            "place_shared",
-            {"bytes": tree_nbytes(shared_args)} if tracing else None,
-        ):
+        span_args = {"bytes": tree_nbytes(shared_args)} if tracing else None
+        with obs_trace.span("place_shared", span_args):
             if isinstance(shared_shardings, NamedSharding):
                 # single sharding for the whole tree: leaf-wise put
-                # through the reuse cache (sharding-spec trees skip the
-                # cache — the 2D row-sharded case re-puts every fit)
+                # through the reuse cache
                 shared_args = jax.tree_util.tree_map(
                     lambda a: _cached_device_put(
                         a, shared_shardings, self.reuse_broadcast
@@ -986,7 +1017,10 @@ class TPUBackend(TaskBackend):
             else:
                 # shardings form a PREFIX tree of shared_args (one
                 # sharding per top-level entry; entries may be
-                # sub-trees)
+                # sub-trees). These skip the reuse cache: a caller
+                # that wants a row-sharded leaf to outlive a dispatch
+                # places it itself (``place_shared``) and hands it in
+                # placed, which the put below leaves where it is
                 shared_args = jax.tree_util.tree_map(
                     lambda sh, sub: jax.tree_util.tree_map(
                         lambda a: _put_mesh_scoped(a, sh), sub
@@ -994,14 +1028,20 @@ class TPUBackend(TaskBackend):
                     shared_shardings, shared_args,
                     is_leaf=lambda x: isinstance(x, NamedSharding),
                 )
+            # byte-account what was just placed: packed-CSR leaves count
+            # their idx+val bytes, not their logical dense size, and on
+            # a mesh with a ``data`` axis a leaf counts the shard ONE
+            # device holds of it
+            self.last_shared_bytes = tree_nbytes(
+                shared_args, per_device=self.data_axis_size > 1)
             if tracing:
+                span_args.update(
+                    shards=int(self.data_axis_size),
+                    bytes_per_device=int(self.last_shared_bytes))
                 jax.block_until_ready(shared_args)
         put = lambda t: jax.tree_util.tree_map(
             lambda a: _put_mesh_scoped(a, task_sharding), t
         )
-        # byte-account what was just placed: packed-CSR leaves count
-        # their idx+val bytes, not their logical dense size
-        self.last_shared_bytes = tree_nbytes(shared_args)
         return task_sharding, shared_shardings, shared_args, put
 
     def prepare_batched(self, kernel, shared_args=(), static_args=None,
@@ -1096,7 +1136,8 @@ class TPUBackend(TaskBackend):
             spec, static_args, task_sharding, shared_shardings, cache_key
         )
         return IterativePlan(*fns, shared_args, put,
-                             n_task_slots=self.n_devices)
+                             n_task_slots=self.n_devices,
+                             data_shards=self.data_axis_size)
 
     def batched_map_iterative(self, spec, task_args, shared_args=(),
                               static_args=None, round_size=None,
@@ -1804,30 +1845,102 @@ def _write_rows():
     return _WRITE_ROWS
 
 
+def _row_blocks(x, nbytes=None):
+    """``(at, rows)`` of the row blocks of about ``nbytes`` a host array
+    of :data:`_BLOCK_PUT_BYTES` or more goes to a device in (a quarter
+    of that bound, 1 GiB, where none is given); the last is cut over
+    the end of the one before it."""
+    nbytes = nbytes or _BLOCK_PUT_BYTES // 4
+    rows = max(1, len(x) // -(-x.nbytes // nbytes))
+    return [(min(at, len(x) - rows), rows) for at in range(0, len(x), rows)]
+
+
+def _goes_in_blocks(x):
+    return (isinstance(x, np.ndarray) and x.nbytes >= _BLOCK_PUT_BYTES
+            and x.ndim >= 1 and len(x) >= 2)
+
+
 def put_host_array(x, sharding=None):
     """``jax.device_put(x, sharding)`` (uncommitted on the default
-    device where ``sharding`` is None) — a host array of
-    :data:`_BLOCK_PUT_BYTES` or more in row blocks of a quarter of that,
-    each written into the whole on the device, so that beside the
-    whole only one block is ever held."""
+    device where ``sharding`` is None) for ONE device or a replica on
+    each — a host array of :data:`_BLOCK_PUT_BYTES` or more in row
+    blocks of a quarter of that, each written into the whole on the
+    device, so that beside the whole only one block is ever held. (A
+    row-sharded array goes the same way shard by shard:
+    :func:`_put_row_shards`.)"""
     import jax
 
     def put(a):
         return (jax.device_put(a) if sharding is None
                 else jax.device_put(a, sharding))
 
-    if (not isinstance(x, np.ndarray) or x.nbytes < _BLOCK_PUT_BYTES
-            or x.ndim < 1 or len(x) < 2):
+    if not _goes_in_blocks(x):
         return put(x)
     import jax.numpy as jnp
 
-    rows = max(1, len(x) // -(-x.nbytes // (_BLOCK_PUT_BYTES // 4)))
     whole = jnp.zeros(x.shape, x.dtype, device=sharding)
-    for at in range(0, len(x), rows):
-        # the last block is written over the end of the one before it
-        at = min(at, len(x) - rows)
+    for at, rows in _row_blocks(x):
         whole = _write_rows()(whole, put(x[at:at + rows]), at)
     return whole
+
+
+#: a SHARD's row blocks are a 64th of :data:`_BLOCK_PUT_BYTES`
+#: (64 MiB), sixteen of them (1 GiB) in flight a device. On the
+#: four-chip v5e host the size of ONE transfer sets the rate: the
+#: cell's 25.4 GB over four devices took 50.7 s in blocks of 1 GiB
+#: (half of a fit) and 0.9 to 2.2 s in blocks of 64 MiB; 8.6 GB took
+#: 6.46 s in 1 GiB, 4.23 s in 256 MiB, 0.65 s in 64 MiB (PERF.md,
+#: PR 35). One chip's machine takes 1 GiB blocks at 9 GB/s, so
+#: :func:`put_host_array` keeps them.
+_SHARD_BLOCK_SHARE = 64
+_SHARD_BLOCKS_IN_FLIGHT = 16
+
+
+def _put_row_shards(x, sharding):
+    """A host array onto a sharding that cuts it (fully addressable):
+    ``jax.device_put(x, sharding)``, or, where a device's shard is
+    :data:`_BLOCK_PUT_BYTES` or more, each shard in row blocks (of a
+    :data:`_SHARD_BLOCK_SHARE`-th of that bound), written into that
+    device's shard in place as :func:`put_host_array` writes a whole. A
+    shard of a row-sharded array is a contiguous row slice of ``x`` and
+    so are its blocks: views, no host copy. The blocks go block-major
+    — every device's first, then every device's second — so the
+    devices' transfers are in flight together and a shard never waits
+    for another's; every :data:`_SHARD_BLOCKS_IN_FLIGHT` blocks what
+    was enqueued is waited for, so that beside a shard a device never
+    holds more than that many blocks (nor the host as many staged),
+    and the last are waited for too: what round sizing then reads of a
+    device's free memory is the placed shard and no block in flight."""
+    import jax
+
+    parts = [(d, x[idx]) for d, idx in
+             sharding.addressable_devices_indices_map(x.shape).items()]
+    if not any(_goes_in_blocks(part) for _, part in parts):
+        return jax.device_put(x, sharding)
+    import jax.numpy as jnp
+
+    def zeros_on(d, part):
+        # made ON the device: ``jnp.zeros(..., device=)`` fills the
+        # default device and copies from there, a second shard on
+        # device 0 (its peak 12.77 GB for a shard of 6.35: PERF.md,
+        # PR 35)
+        with jax.default_device(d):
+            return jax.device_put(jnp.zeros(part.shape, part.dtype), d)
+
+    wholes = [zeros_on(d, part) for d, part in parts]
+    blocks = [_row_blocks(part, _BLOCK_PUT_BYTES // _SHARD_BLOCK_SHARE)
+              for _, part in parts]
+    for b in range(max(len(bl) for bl in blocks)):
+        for i, (d, part) in enumerate(parts):
+            if b < len(blocks[i]):
+                at, rows = blocks[i][b]
+                wholes[i] = _write_rows()(
+                    wholes[i], jax.device_put(part[at:at + rows], d), at)
+        if (b + 1) % _SHARD_BLOCKS_IN_FLIGHT == 0:
+            jax.block_until_ready(wholes)
+    jax.block_until_ready(wholes)
+    return jax.make_array_from_single_device_arrays(
+        x.shape, sharding, wholes)
 
 
 def _put_mesh_scoped(x, sharding):
@@ -1844,13 +1957,18 @@ def _put_mesh_scoped(x, sharding):
     global array (collective-free); the SPMD contract that every
     participating process passes the same host value is assumed, as it
     already is for the round loop itself. Fully-addressable shardings
-    (single-process) take the plain fast path.
+    (single-process) take the plain path: a replica on each device
+    through :func:`put_host_array`, a host array cut across devices
+    through :func:`_put_row_shards` — both in row blocks where a
+    device's part is several GiB.
     """
     import jax
 
     if getattr(sharding, "is_fully_addressable", True):
         if getattr(sharding, "is_fully_replicated", False):
             return put_host_array(x, sharding)
+        if _goes_in_blocks(x):
+            return _put_row_shards(x, sharding)
         return jax.device_put(x, sharding)
     if getattr(x, "is_fully_addressable", True) is False:
         # already a global (multi-process) array: jax reshards it on
@@ -2335,12 +2453,17 @@ def _dispatch_iterative(backend, plan, spec, task_args, shared_args,
     chunk, chunk_basis, lanes_fit = sizing
     resident, transient, fixed, rows = _lane_footprint(plan, task_args)
     shared_bytes = int(backend.last_shared_bytes or 0)
+    # every byte count is of what ONE device holds: on a mesh with a
+    # ``data`` axis a row shard of the shared operands and of every
+    # value with an axis of the data's rows (``data_shards`` devices
+    # share them)
     stats = backend.last_round_stats = obs_metrics.new_round_stats(
         tasks=int(n_tasks), shared_bytes=shared_bytes,
         lane_bytes=int(resident + transient), logits_bytes=int(rows),
         round_bytes_estimate=int(
             shared_bytes + fixed + chunk * (resident + transient)),
         chunk_basis=chunk_basis, lanes_fit=lanes_fit,
+        data_shards=int(plan.data_shards),
     )
     # where device memory set the size, one round's carry is resident
     live_rounds = 1 if chunk_basis == "memory" else None
@@ -2508,6 +2631,7 @@ def _run_compacted(plan, spec, task_args, n_tasks, chunk, stats,
     put = plan.put
     shared = plan.shared
     shared_sig = plan._shared_sig
+    n_devices = plan.n_task_slots * plan.data_shards
 
     def make_exec(fn, book=None):
         if not hasattr(fn, "lower"):
@@ -2520,6 +2644,12 @@ def _run_compacted(plan, spec, task_args, n_tasks, chunk, stats,
             )
             if book is not None and book not in stats:
                 stats[book] = _program_bytes(comp)
+                # a program of one device holds none: its text is
+                # never read
+                (stats["collective_ops_compiled"],
+                 stats["collective_bytes_compiled"]) = (
+                     _program_collectives(comp) if n_devices > 1
+                     else (0, 0))
             return comp(shared, sl)
 
         return run
@@ -2527,7 +2657,8 @@ def _run_compacted(plan, spec, task_args, n_tasks, chunk, stats,
     init_exec = make_exec(plan.init_fn)
     # what the compiler says a round's step program holds, beside what
     # round sizing reckoned for it (``round_bytes_estimate``): the
-    # device's own peak counter does not see a program's temporaries
+    # device's own peak counter does not see a program's temporaries;
+    # and the collectives the partitioner put into it
     step_exec = make_exec(plan.step_fn, "round_bytes_compiled")
     fin_exec = make_exec(plan.fin_fn)
     score_exec = (
@@ -2567,6 +2698,48 @@ def _program_bytes(compiled):
     except Exception as exc:
         faults.log_suppressed("_program_bytes", exc, level=logging.DEBUG)
         return None
+
+
+#: result bytes of an HLO element type
+_HLO_ITEMSIZE = {"pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "f16": 2,
+                 "bf16": 2, "s32": 4, "u32": 4, "f32": 4, "s64": 8,
+                 "u64": 8, "f64": 8, "c64": 8, "c128": 16}
+_HLO_COLLECTIVE = re.compile(
+    r" = (.*?) (?:all-reduce|all-gather|reduce-scatter|collective-permute"
+    r"|all-to-all)(?:-done)?\(")
+_HLO_SHAPE = re.compile(r"\b([a-z]+\d+|pred)\[([\d,]*)\]")
+
+
+def hlo_collectives(hlo):
+    """``[(result shapes, result bytes)]`` of every ``all-reduce``,
+    ``all-gather``, ``reduce-scatter``, ``collective-permute`` and
+    ``all-to-all`` instruction of an HLO text, each once as written
+    (not times a loop's trips); of an asynchronous pair the ``-done``
+    half, whose result is the collective's. A shape is its tuple of
+    dimensions; the bytes are logical (no tile padding)."""
+    found = []
+    for match in _HLO_COLLECTIVE.finditer(hlo):
+        shapes = [
+            (dtype, tuple(int(n) for n in dims.split(",") if n))
+            for dtype, dims in _HLO_SHAPE.findall(match.group(1))]
+        found.append((
+            [dims for _, dims in shapes],
+            sum(_HLO_ITEMSIZE.get(dtype, 4) * math.prod(dims)
+                for dtype, dims in shapes)))
+    return found
+
+
+def _program_collectives(compiled):
+    """``(count, summed result bytes)`` of the collectives in a
+    compiled program of several devices (:func:`hlo_collectives`);
+    ``(None, None)`` where the program gives no text."""
+    try:
+        ops = hlo_collectives(compiled.as_text())
+    except Exception as exc:
+        faults.log_suppressed("_program_collectives", exc,
+                              level=logging.DEBUG)
+        return None, None
+    return len(ops), int(sum(size for _, size in ops))
 
 
 def _compacted_slice_loop(spec, put, init_exec, step_exec, score_exec,
@@ -2859,6 +3032,11 @@ def _lane_footprint(plan, task_args):
     holds at once of values shaped like the data's rows (logits and
     their kin: what makes a lane heavy where the weights are small).
 
+    On a mesh with a ``data`` axis (``plan.data_shards`` devices share
+    the data's rows) every count is of what ONE device holds: a value
+    with an axis of the data's rows counts a shard of it, weights and
+    history the whole.
+
     All but the task slice come from one abstract trace of the init
     program (it runs the same solver slice the step program does) at
     :data:`_PROBE_LANES` lanes a slot; no data moves and nothing is
@@ -2870,7 +3048,9 @@ def _lane_footprint(plan, task_args):
 
     task_sig = tuple((tuple(l.shape[1:]), str(l.dtype))
                      for l in jax.tree_util.tree_leaves(task_args))
-    key = (plan.init_fn, plan._shared_sig, task_sig, plan.n_task_slots)
+    shards = plan.data_shards
+    key = (plan.init_fn, plan._shared_sig, task_sig, plan.n_task_slots,
+           shards)
     found = _LANE_FOOTPRINTS.get(key)
     if found is None:
         task_bytes = tree_nbytes(task_args) // max(
@@ -2880,7 +3060,7 @@ def _lane_footprint(plan, task_args):
             try:
                 carry, live, shared_top, rows = _traced_lane_bytes(
                     plan.init_fn, plan.shared, task_args,
-                    _PROBE_LANES * plan.n_task_slots,
+                    _PROBE_LANES * plan.n_task_slots, shards,
                 )
                 found = (task_bytes + carry, live, shared_top, rows)
             except Exception as exc:
@@ -2900,8 +3080,10 @@ def _sample_rows(shared):
     return dims.most_common(1)[0][0] if dims else None
 
 
-def _traced_lane_bytes(init_fn, shared, task_args, width):
-    """One abstract trace of ``init_fn`` at ``width`` lanes: the bytes
+def _traced_lane_bytes(init_fn, shared, task_args, width, shards=1):
+    """One abstract trace of ``init_fn`` at ``width`` lanes (a value
+    with an axis of the data's row count at a ``shards``-th of its
+    bytes: what one of the devices that share the rows holds): the bytes
     of one lane's carry (the program's output); the most bytes of
     values with a lane axis (a dimension that is a multiple of
     ``width``) that the program computes and holds AT ONCE, per lane —
@@ -2916,7 +3098,8 @@ def _traced_lane_bytes(init_fn, shared, task_args, width):
     rows = _sample_rows(shared)
 
     def nbytes(aval):
-        return math.prod(aval.shape) * getattr(aval.dtype, "itemsize", 4)
+        whole = math.prod(aval.shape) * getattr(aval.dtype, "itemsize", 4)
+        return whole // shards if rows in aval.shape else whole
 
     def lane_bytes(aval):
         if any(n and n % width == 0 for n in aval.shape):
@@ -2988,7 +3171,11 @@ def _size_iterative_round(backend, plan, task_args, n_tasks, round_size,
     all rounds' task slices and carries stay on the device between
     slices, whatever the round size — over what the lanes of the
     rounds in flight add. None where the device reports no memory
-    stats (CPU): the shapes alone decide there.
+    stats (CPU): the shapes alone decide there. Every count is of ONE
+    device: what is free on the mesh's first, the shared bytes and the
+    lane's footprint as one device holds them (on a mesh with a
+    ``data`` axis a row shard of X and of a lane's logits), the lanes
+    of one slot of the task axis.
 
     Where that cap binds (basis ``memory``) the carries of the rounds
     that wait are what fills the device, so they stay off it: the loop

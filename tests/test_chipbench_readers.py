@@ -22,6 +22,12 @@ from chipbench.readers import (  # noqa: E402
 )
 from skdist_tpu.obs import trace as obs_trace  # noqa: E402
 
+#: the cell PR 35 adds: all of mnist8m, row-sharded over four chips
+FULL_CELL = "search-mnist8m-full-4chip"
+
+#: ... and the metric it brings
+FOUR_CHIP_METRICS = ("collective_mb_per_program.search",)
+
 NEW_METRICS = (
     "loss_evals_per_fit.search", "lbfgs_iters_per_fit.search",
     "live_lane_share_pct.search", "place_s_per_fit.search",
@@ -262,8 +268,10 @@ def test_new_metric_resolves_to_a_reader_and_an_entry(name):
     entry = {m["name"]: m for m in _bench()["per_layer"]}[name]
     assert set(entry) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
-    # the dense cells: the multiclass one joined them (PR 32)
-    assert entry["workloads"] == ["search-epsilon", "search-mnist8m"]
+    # the dense cells: the multiclass one joined them (PR 32), and the
+    # whole of it on four chips (PR 35)
+    assert entry["workloads"] == ["search-epsilon", "search-mnist8m",
+                                  FULL_CELL]
     assert entry["moves"] == "search_fits_per_s"
     assert entry["source"] == ("program_span" if reader is span_seconds
                                else "program_counter")
@@ -285,7 +293,8 @@ def test_every_metric_file_has_its_entry_and_reader():
         assert os.path.exists(os.path.join(
             REPO, "chipbench", "readers", spec["reader"] + ".py"))
     # the new entries were appended: the accepted ones keep their places
-    appended = NEW_METRICS + TEXT_METRICS + MNIST_METRICS
+    appended = (NEW_METRICS + TEXT_METRICS + MNIST_METRICS
+                + FOUR_CHIP_METRICS)
     assert [m["name"] for m in bench["per_layer"]][
         -len(appended):] == list(appended)
 
@@ -339,8 +348,10 @@ def test_dense_share_of_the_peak_does_not_read_the_text_cell():
     entry = {m["name"]: m for m in _bench()["per_layer"]}[
         "lbfgs_mfu_pct.search"]
     # the dense cells, binary and multinomial (its work function counts
-    # ``k`` columns), and never the packed one
-    assert entry["workloads"] == ["search-epsilon", "search-mnist8m"]
+    # ``k`` columns; on four chips the share is of four chips' peak:
+    # ``work_share`` divides by the chips used), and never the packed one
+    assert entry["workloads"] == ["search-epsilon", "search-mnist8m",
+                                  FULL_CELL]
 
 
 @pytest.mark.parametrize("stats, peak, want", [
@@ -399,10 +410,78 @@ def test_mnist_cell_share_metrics_read_the_booked_bytes(name, num, den):
     assert spec == {"reader": "round_counts",
                     "args": {"num": num, "den": den}}
     entry = {m["name"]: m for m in _bench()["per_layer"]}[name]
-    assert entry["workloads"] == ["search-mnist8m"]
+    assert entry["workloads"] == ["search-mnist8m", FULL_CELL]
     assert entry["moves"] == "search_fits_per_s"
     ctx = {"fits": [_fit({num: 416, den: 417})], "units_done": 50}
     assert round_counts.read(ctx, **spec["args"]) == pytest.approx(
         100 * 416 / 417)
     ctx = {"fits": [_fit({"rounds": 3})], "units_done": 50}
     assert round_counts.read(ctx, **spec["args"]) is None
+
+
+# ---------------------------------------------------------------------------
+# the four-chip cell's own (PR 35)
+# ---------------------------------------------------------------------------
+
+def _four_chip_entry(name):
+    from chipbench import run
+
+    entry = {m["name"]: m for m in _bench()["per_layer"]}[name]
+    assert entry["workloads"] == [FULL_CELL]
+    assert entry["moves"] == "search_fits_per_s"
+    return run.load_json("chipbench", "metrics", name + ".json"), entry
+
+
+@pytest.mark.parametrize("busy, want", [
+    ({"d0": 10.0, "d1": 9.0, "d2": 9.5, "d3": 10.0}, 10.0),
+    ({"d0": 8.0, "d1": 2.0}, 75.0),
+    ({"d0": 4.0, "d1": 4.0, "d2": 4.0, "d3": 4.0}, 0.0),
+    # one device: nothing to spread; no device busy; no trace at all
+    ({"d0": 10.0}, None),
+    ({"d0": 0.0, "d1": 0.0}, None),
+    (None, None),
+])
+def test_shard_busy_spread_reads_the_traced_devices(busy, want):
+    """The reader is here and no metric reads it yet: the cell's
+    traffic traces the first 12 s of a fit, which hold placement only,
+    so ``shard_busy_spread_pct.search`` waits for a traffic whose
+    trace begins past placement (PERF.md section 7)."""
+    from chipbench.readers import shard_busy_spread
+
+    assert "shard_busy_spread_pct.search" not in {
+        m["name"] for m in _bench()["per_layer"]}
+    trace = None if busy is None else {
+        "window_s": 12.0, "busy_s": 1.0, "busy_s_per_device": busy}
+    got = shard_busy_spread.read({"trace": trace})
+    assert got == (want if want is None else pytest.approx(want))
+    # a trace reduced by a harness that kept no per-device seconds
+    assert shard_busy_spread.read(
+        {"trace": {"window_s": 1.0, "busy_s": 0.5}}) is None
+
+
+@pytest.mark.parametrize("stats, want", [
+    ([{"collective_bytes_compiled": 408_304}], 0.408304),
+    # the mean over the fits that hold it: each books its step program's
+    ([{"collective_bytes_compiled": 400_000},
+      {"collective_bytes_compiled": 600_000}, {"rounds": 4}], 0.5),
+    ([{"collective_bytes_compiled": 0}], 0.0),
+    # a program without the counter (the parent), or one that could not
+    # read its program's text
+    ([{"rounds": 4, "round_bytes_compiled": 1 << 30}], None),
+    ([{"collective_bytes_compiled": None}], None),
+    ([None], None),
+])
+def test_collective_mb_reads_what_the_step_program_was_compiled_with(
+        stats, want):
+    from chipbench.readers import round_stat_mean
+
+    spec, entry = _four_chip_entry("collective_mb_per_program.search")
+    assert spec == {"reader": "round_stat_mean",
+                    "args": {"key": "collective_bytes_compiled",
+                             "scale": 1e-06}}
+    assert (entry["source"], entry["layer"], entry["unit"]) == (
+        "program_counter", "round dispatch", "MB")
+    got = round_stat_mean.read(
+        {"fits": [_fit(s) for s in stats], "units_done": 50},
+        **spec["args"])
+    assert got == (want if want is None else pytest.approx(want))

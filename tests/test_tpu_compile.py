@@ -20,6 +20,7 @@ names the engine (``hist_mode='matmul'``, ``interpret=False``) that the
 chip would resolve.
 """
 
+import math
 import os
 import re
 
@@ -129,10 +130,13 @@ def test_padded_pair_kernels_compile(sds, kernel):
 # the jitted steps of the main paths, at the smoke's widths
 # ---------------------------------------------------------------------------
 
-def _cv_step_program(n, d, k, n_lanes):
+def _cv_step_program(n, d, k, n_lanes, mesh=None):
     """The vmapped L-BFGS step slice DistGridSearchCV builds for the
     compacted path, as an un-sharded jit entry plus the shapes it
-    takes."""
+    takes — or, on a ``('tasks', 'data')`` ``mesh``, the entry the
+    backend builds there (the task axis on ``tasks``, the rows of the
+    shared operands on ``data``) and shapes that carry those
+    shardings."""
     from skdist_tpu.distribute.search import (
         _cached_cv_kernel, _cv_iterative_spec, _cv_kernel_key,
         _resolve_device_scoring,
@@ -156,16 +160,35 @@ def _cv_step_program(n, d, k, n_lanes):
     spec, _ = _cv_iterative_spec(
         type(est), meta, static, specs, False, resolve_slice_iters(30),
         fallback=classic, fallback_key=key)
+    task_sharding = shared_shardings = None
+    cut = {}
+    if mesh is not None:
+        from jax.sharding import NamedSharding, PartitionSpec as P
+
+        from skdist_tpu.distribute.search import _CV_SAMPLE_AXES
+
+        task_sharding = NamedSharding(mesh, P("tasks"))
+        shared_shardings = {
+            name: NamedSharding(
+                mesh, P() if name not in _CV_SAMPLE_AXES else
+                P(*([None] * _CV_SAMPLE_AXES[name]), "data"))
+            for name in ("X", "y", "sw", "aux", "train_masks",
+                         "test_masks")}
+        cut = {name: {"sharding": sh}
+               for name, sh in shared_shardings.items()}
     init_fn, step_fn, _, _ = _iterative_jit_entries(
-        spec, None, None, None, None)
-    f32 = jax.ShapeDtypeStruct
+        spec, None, task_sharding, shared_shardings, None)
+
+    def f32(shape, dtype, name=None):
+        return jax.ShapeDtypeStruct(shape, dtype, **cut.get(name, {}))
+
     shared = {
-        "X": f32((n, d), jnp.float32),
-        "y": f32((n,), data["y"].dtype),
-        "sw": f32((n,), jnp.float32),
+        "X": f32((n, d), jnp.float32, "X"),
+        "y": f32((n,), data["y"].dtype, "y"),
+        "sw": f32((n,), jnp.float32, "sw"),
         "aux": extract_aux(data),
-        "train_masks": f32((5, n), jnp.float32),
-        "test_masks": f32((5, n), jnp.float32),
+        "train_masks": f32((5, n), jnp.float32, "train_masks"),
+        "test_masks": f32((5, n), jnp.float32, "test_masks"),
     }
     task = {
         "hyper": {name: f32((n_lanes,), jnp.float32)
@@ -515,6 +538,70 @@ def test_dense_multinomial_step_holds_its_logits_rows_minor(sds):
                           shared), None), task)
     booked = Chip.last_shared_bytes + fixed + lanes * (resident + transient)
     assert 0.75 * _device_bytes(compiled) < booked < _device_bytes(compiled)
+
+
+def test_row_sharded_step_fits_a_chip_and_reduces_partial_sums_only(topo):
+    """``search-mnist8m-full-4chip``'s step program — ALL of mnist8m,
+    8,100,000 x 784, row-sharded over the four described chips on a
+    ``tasks`` 1 x ``data`` 4 mesh — at the round the backend picks
+    against a chip's 15.75 GiB beside its 6.45 GB of the shared
+    operands: 13 lanes by memory, as ``search-mnist8m`` (a device holds
+    the same rows there); the program fits a device, and every
+    collective in it is a sum of partial sums — a round's losses and
+    its ``(lanes, 785, 10)`` gradients — none with an axis of the
+    data's rows or of a shard's: the partitioner gathers neither X nor
+    the logits."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from skdist_tpu.parallel.backend import (
+        IterativePlan, _lane_footprint, _size_iterative_round,
+        hlo_collectives, tree_nbytes,
+    )
+
+    n, d, k, n_tasks, shards = 8_100_000, 784, 10, 50, 4
+    mesh = Mesh(np.array(topo.devices[:shards]).reshape(1, shards),
+                ("tasks", "data"))
+    step_fn, shared, task, carry, init_fn = _cv_step_program(
+        n, d, k, n_tasks, mesh)
+
+    class Chip:
+        last_shared_bytes = tree_nbytes(shared, per_device=True)
+
+        def _free_device_bytes(self):
+            return int(15.75 * 1024 ** 3) - self.last_shared_bytes
+
+    assert 6.4e9 < Chip.last_shared_bytes < 6.5e9
+    plan = IterativePlan(init_fn, step_fn, None, None, shared, None,
+                         data_shards=shards)
+    lanes, basis, lanes_fit = _size_iterative_round(
+        Chip(), plan, task, n_tasks, None)
+    # (``search-mnist8m``'s cap reads 21: its 2,000,000 rows are a few
+    # fewer than a shard's 2,025,000 here)
+    assert (lanes, basis, lanes_fit) == (13, "memory", 20)
+    resident, transient, fixed, rows = _lane_footprint(plan, task)
+    # a device's share of a lane: five values of (10, 2,025,000) floats
+    assert 5 * 4 * (n // shards) * k <= transient <= 5.3 * 4 * (
+        n // shards) * k
+    assert rows > 0.99 * (resident + transient)
+    tasks_on = NamedSharding(mesh, P("tasks"))
+    task, carry = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct((lanes,) + a.shape[1:], a.dtype,
+                                       sharding=tasks_on),
+        (task, carry))
+    compiled = step_fn.lower(
+        shared, {"task": task, "carry": carry}).compile()
+    # memory_analysis() of a partitioned program is one device's
+    assert _device_bytes(compiled) < 15.75 * 1024 ** 3
+    booked = Chip.last_shared_bytes + fixed + lanes * (resident + transient)
+    assert 0.7 * _device_bytes(compiled) < booked < 1.1 * _device_bytes(
+        compiled)
+    found = hlo_collectives(compiled.as_text())
+    assert 1 <= len(found) <= 8, found
+    for shapes, _ in found:
+        for dims in shapes:
+            assert n not in dims and n // shards not in dims, found
+            assert math.prod(dims) <= lanes * (d + 1) * k, found
+    assert sum(size for _, size in found) < 10e6
 
 
 def test_lbfgs_history_moves_no_row_lane_by_lane(sds):
