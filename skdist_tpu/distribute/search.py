@@ -490,6 +490,27 @@ _CV_SAMPLE_AXES = {
 }
 
 
+def _same_leaves(staged, operand):
+    """Whether ``staged`` — the X a family's ``_prep_fit_data`` hands
+    a dispatch — IS ``operand``, the X the search prepared: the same
+    array, or the same arrays in a packed tree (``host_stage`` rebuilds
+    the container around them)."""
+    import jax
+
+    a, tree_a = jax.tree_util.tree_flatten(staged)
+    b, tree_b = jax.tree_util.tree_flatten(operand)
+    return tree_a == tree_b and all(x is y for x, y in zip(a, b))
+
+
+def _is_placed(operand):
+    """Whether an X (an array or a packed tree of them) lies on
+    devices — ``place_shared``'s result — and not on the host."""
+    import jax
+
+    leaves = jax.tree_util.tree_leaves(operand)
+    return bool(leaves) and all(hasattr(leaf, "sharding") for leaf in leaves)
+
+
 def _cv_kernel_key(est_cls, meta, static, scorer_specs, return_train_score):
     """Structural compile-cache key of one CV kernel: estimator class
     qualname + static config + scorer names/kinds + meta signature
@@ -730,11 +751,15 @@ class DistBaseSearchCV(BaseEstimator):
         carries the fit's ``trace_id`` and its parent's id."""
         tracing = obs_trace.enabled()
         span_args = {} if tracing else None
-        with obs_trace.use_context(
-            obs_trace.new_context() if tracing else None
-        ), obs_trace.span("search_fit", span_args):
-            return self._fit(X, y, groups, checkpoint_dir, fit_params,
-                             span_args)
+        try:
+            with obs_trace.use_context(
+                obs_trace.new_context() if tracing else None
+            ), obs_trace.span("search_fit", span_args):
+                return self._fit(X, y, groups, checkpoint_dir, fit_params,
+                                 span_args)
+        finally:
+            # a fit that raised lets its placed operand go too
+            self.__dict__.pop("_rounds_X_", None)
 
     def _fit(self, X, y, groups, checkpoint_dir, fit_params, span_args):
         """The body of :meth:`fit`; ``span_args`` (None with tracing
@@ -755,13 +780,12 @@ class DistBaseSearchCV(BaseEstimator):
         # the artifact is finalized)
         self._adaptive_engaged_ = False
         self._rung_killed_gids_ = {}
-        # the packed form of X, where the batched path packed one: the
-        # refit takes it instead of packing the same matrix again
-        self._packed_X_ = None
-        # the dense X as the batched path placed it on a mesh with a
-        # ``data`` axis, a row shard a device: every bucket dispatches
-        # over it and the refit runs over it
-        self._mesh_X_ = None
+        # the X the batched path's rounds ran on, for the refit to run
+        # on too: as the search placed it on the backend's mesh (dense
+        # or packed, replicated or a row shard a device — a fit then
+        # sends its matrix to the devices once), or the packed host
+        # form where nothing was placed (it is not packed twice)
+        self._rounds_X_ = None
         check_estimator_backend(self, self.verbose)
         backend = resolve_backend(self.backend, n_jobs=self.n_jobs)
         estimator = self.estimator
@@ -851,8 +875,9 @@ class DistBaseSearchCV(BaseEstimator):
         if self.refit:
             best = clone(estimator).set_params(**self.best_params_)
             refit_start = time.perf_counter()
-            with obs_trace.span("refit"):
-                self._refit(backend, best, X, y, fit_params)
+            refit_args = {} if obs_trace.enabled() else None
+            with obs_trace.span("refit", refit_args):
+                self._refit(backend, best, X, y, fit_params, refit_args)
             self.refit_time_ = time.perf_counter() - refit_start
             self.best_estimator_ = best
             if self.preds:
@@ -864,22 +889,40 @@ class DistBaseSearchCV(BaseEstimator):
         # estimator.sc`, search.py:568-570 — a footgun we avoid: the
         # user's own estimator object keeps its backend)
         self.estimator = clone(self.estimator)
-        del self._packed_X_, self._mesh_X_
         strip_runtime(self)
         return self
 
-    def _refit(self, backend, best, X, y, fit_params):
-        """Fit ``best`` on all of the data. Where the batched path ran
-        on a mesh with a ``data`` axis, over the row shards it placed
-        (``_LinearModelBase._fit_on_mesh``): a standalone ``fit``
-        places X whole on one device, which an operand that NEEDS the
-        mesh does not fit. Everywhere else ``best.fit``, on the packed
-        X where the search packed one."""
+    def _refit(self, backend, best, X, y, fit_params, span_args=None):
+        """Fit ``best`` on all of the data: over the operand the
+        batched path placed (``_LinearModelBase._fit_placed``) wherever
+        ``best.fit`` would have placed X itself — its device fit, not
+        the float64 host engine that ``engine='auto'`` resolves to on a
+        CPU platform (packed X has no host form and always is a device
+        fit) — so the matrix does not cross to the devices a second
+        time, and an operand that NEEDS the mesh (a row shard a device)
+        is never put whole on one. Everything else — no placed operand,
+        an estimator family without the entry, fit params that are not
+        one full-length ``sample_weight`` — is ``best.fit``, a placed
+        operand let go first, on the caller's X or the form the search
+        packed. ``span_args``: the ``refit`` span's ``args`` where
+        tracing is on — ``x_placed``, and with it the ``bytes`` that
+        were still to place (labels and weights)."""
+        from ..sparse import is_packed
+
+        operand, self._rounds_X_ = self._rounds_X_, None
+        placed = _is_placed(operand)
         sw, sw_ok = full_length_sample_weight(fit_params, num_samples(X))
-        if self._mesh_X_ is not None and sw_ok and y is not None:
-            best._fit_on_mesh(backend, self._mesh_X_, y, sw)
+        x_placed = (
+            placed and sw_ok and y is not None
+            and hasattr(best, "_fit_placed")
+            and (is_packed(operand) or not best._resolve_host_engine()))
+        if span_args is not None:
+            span_args["x_placed"] = x_placed
+        if x_placed:
+            best._fit_placed(backend, operand, y, sw, span_args)
             return
-        refit_X = X if self._packed_X_ is None else self._packed_X_
+        refit_X = X if operand is None or placed else operand
+        del operand
         if y is not None:
             best.fit(refit_X, y, **fit_params)
         else:
@@ -1158,7 +1201,7 @@ class DistBaseSearchCV(BaseEstimator):
             except Exception:
                 return None
             if is_packed(X_arr):
-                self._packed_X_ = X_arr
+                self._rounds_X_ = X_arr
             n = X_arr.shape[0]
             train_masks = np.zeros((n_splits, n), dtype=np.float32)
             test_masks = np.zeros((n_splits, n), dtype=np.float32)
@@ -1205,10 +1248,9 @@ class DistBaseSearchCV(BaseEstimator):
                 est_cls, meta, static, scorer_specs,
                 self.return_train_score, key=kernel_key,
             )
-            # all leaves stay host-staged: batched_map performs the one
-            # sharded placement (through the reuse-broadcast cache when
-            # enabled — data["X"] is the SAME host array across buckets,
-            # so multi-bucket grids re-place it for free on cache hits)
+            # labels, weights and masks stay host-staged: the dispatch
+            # places them (through the reuse-broadcast cache when
+            # enabled). X is placed below, once a fit
             shared = {
                 "X": data["X"],
                 "y": data["y"],
@@ -1257,16 +1299,20 @@ class DistBaseSearchCV(BaseEstimator):
                 "split": np.asarray(split_ids, dtype=np.int32),
             }
             specs = row_sharded_specs(backend, shared, _CV_SAMPLE_AXES)
-            if (specs is not None and not is_packed(X_arr)
-                    and hasattr(est_cls, "_fit_on_mesh")):
-                # a mesh with a ``data`` axis: X is placed once, a row
-                # shard a device, and every bucket's dispatch and the
-                # refit take it placed (a dispatch leaves a placed leaf
-                # where it is)
-                if self._mesh_X_ is None:
-                    self._mesh_X_ = backend.place_shared(
-                        {"X": shared["X"]}, {"X": specs["X"]})["X"]
-                shared["X"] = self._mesh_X_
+            if (backend.is_device_backend and backend.elastic is None
+                    and _same_leaves(shared["X"], X_arr)):
+                # X is placed once a fit, as a dispatch would place it
+                # (replicated, or a row shard a device on a ``data``
+                # axis), and every bucket's dispatch and the refit take
+                # it placed: a dispatch leaves a placed leaf where it
+                # is. Not what a family stages of its own (binned
+                # trees), which its dispatch places; not for an elastic
+                # backend, whose mesh can change under the fit and
+                # whose recovery places every operand from the host
+                if not _is_placed(getattr(self, "_rounds_X_", None)):
+                    self._rounds_X_ = backend.place_shared(
+                        {"X": X_arr}, specs and {"X": specs["X"]})["X"]
+                shared["X"] = self._rounds_X_
             n_bucket = len(split_ids)
             # convergence-compacted path: iteration-sliced solvers +
             # live-task compaction, for families that support sliced
@@ -2025,6 +2071,8 @@ class DistMultiModelSearch(BaseEstimator):
                     shim, "_adaptive_engaged_", False
                 )
             per_model.append((index, name, cands, full))
+            # (and with the shim goes the X its rounds placed)
+            del shim
 
         if self.adaptive is not None and not adaptive_engaged:
             warn_not_engaged("the multi-model search")

@@ -278,7 +278,11 @@ def _annotate_x_meta(meta, X):
         # round stats (:func:`annotate_round_kernel_mode`)
         meta["x_format"] = "packed"
         if isinstance(X, PackedX):
-            meta["x_nnz"] = int(np.count_nonzero(np.asarray(X.val)))
+            # counted where the values lie: a refit over a placed pair
+            # (``_fit_placed``) brings nothing back to the host for it
+            meta["x_nnz"] = int(
+                (np if isinstance(X.val, np.ndarray) else jnp
+                 ).count_nonzero(X.val))
             meta["x_slots"] = int(np.prod(X.idx.shape))
         else:
             # both orientations of the buckets are placed, and counted
@@ -532,22 +536,35 @@ class _LinearModelBase(BaseEstimator):
         self._set_fitted(kernel(*args), meta)
         return self
 
-    def _fit_on_mesh(self, backend, X, y, sample_weight=None):
-        """:meth:`fit`'s device fit over operands ROW-SHARDED on
-        ``backend``'s mesh (one with a ``data`` axis): the same jitted
-        fit kernel, so the partitioner cuts it the way it cuts a
-        round's step program — logits stay with their rows, ``X̃ᵀr``
-        and the loss's row sums are reduced between the devices. ``X``
-        is dense and already placed, a row shard a device
-        (``TPUBackend.place_shared``: a search hands over the X its
-        dispatches ran on); labels and weights are placed here the same
-        way. No device ever holds X whole."""
-        from ..parallel.backend import row_sharded_specs
+    def _fit_placed(self, backend, X, y, sample_weight=None,
+                    span_args=None):
+        """:meth:`fit`'s device fit over an ``X`` that already lies on
+        ``backend``'s mesh, dense or packed
+        (``TPUBackend.place_shared``: a search hands over the operand
+        its dispatches ran on); labels and weights are placed here,
+        where X lies, and nothing else crosses. Row-sharded on a mesh
+        with a ``data`` axis, the same jitted fit kernel runs over the
+        shards, so the partitioner cuts it the way it cuts a round's
+        step program — logits stay with their rows, ``X̃ᵀr`` and the
+        loss's row sums are reduced between the devices — and no device
+        ever holds X whole. Replicated (no ``data`` axis), the kernel
+        runs over this process's first replica of each operand — a
+        view of the placed buffers, no copy — so it is the one-device
+        program of a standalone :meth:`fit`, to the bit, however many
+        devices hold a replica. ``span_args``: where the caller traces,
+        the dict that takes the ``bytes`` placed here."""
+        from ..parallel.backend import row_sharded_specs, tree_nbytes
 
         def place(data):
             rows = {"y": data["y"], "sw": data["sw"]}
-            return {"X": data["X"], **backend.place_shared(
-                rows, row_sharded_specs(backend, rows, {"y": 0, "sw": 0}))}
+            if span_args is not None:
+                span_args["bytes"] = tree_nbytes(rows)
+            specs = row_sharded_specs(backend, rows, {"y": 0, "sw": 0})
+            placed = {"X": data["X"], **backend.place_shared(rows, specs)}
+            if specs is None:
+                placed = jax.tree_util.tree_map(
+                    lambda a: a.addressable_shards[0].data, placed)
+            return placed
 
         return self._device_fit(X, y, sample_weight, place)
 

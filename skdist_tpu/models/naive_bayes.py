@@ -135,7 +135,9 @@ class MultinomialNB(_LinearClassifierBase):
         return kernel
 
     def _prep_fit_data(self, X, y, sample_weight=None):
-        if np.asarray(X).min() < 0:
+        # (a placed X — a search's refit, ``_fit_placed`` — answers
+        # where it lies and is not brought back to the host)
+        if (X if hasattr(X, "sharding") else np.asarray(X)).min() < 0:
             raise ValueError(
                 "Negative values in data passed to MultinomialNB "
                 "(input X must be non-negative counts)"

@@ -798,9 +798,11 @@ class TPUBackend(TaskBackend):
         sharded on it through a solver's loop and reduces partial sums
         only (``collective_ops_compiled`` / ``collective_bytes_compiled``
         of the round stats say what it inserted in the step program);
-        a search places its X once (:meth:`place_shared`) and refits
-        over those shards. The default 1D mesh replicates shared
-        data and gives every task one device. An explicit ``mesh``
+        a search's refit runs over those shards. The default 1D mesh
+        replicates shared data and gives every task one device. On
+        either, a search places its X once a fit
+        (:meth:`place_shared`), dispatches every bucket over it and
+        refits over it. An explicit ``mesh``
         (e.g. from ``parallel.mesh`` helpers) is used as-is; its leading
         axis is the task axis and a 'data' axis, if present, row-shards.
 
@@ -967,8 +969,9 @@ class TPUBackend(TaskBackend):
         (``shared_specs``: :func:`row_sharded_specs`), for a caller
         that holds an operand across dispatches — a placed leaf handed
         to a later dispatch stays where it is — or runs a program of
-        its own over it (a search on a mesh with a ``data`` axis does
-        both with its X)."""
+        its own over it (a search does both with its X: every bucket's
+        dispatch and the refit). ``shared_specs`` None, or a mesh
+        without a ``data`` axis: a replica a device."""
         return self._resolve_placement(shared_args, shared_specs)[2]
 
     def _resolve_placement(self, shared_args, shared_specs):
@@ -1812,10 +1815,12 @@ class BlockFeeder:
 # array is collected, and a FIFO bound caps pinned HBM regardless.
 _BCAST_CACHE = {}
 # must exceed the number of >= _BCAST_MIN_BYTES leaves ONE fit places
-# (a CV fit's shared tree has 5: X, y, sw, train/test masks) or the
-# fit's own placement pass FIFO-evicts X before any refit can hit it;
-# eviction is LRU (hits refresh recency) so long-lived X outlives
-# transient per-fit leaves
+# (a CV fit's shared tree has 5: X, y, sw, train/test masks; a packed
+# X a few dozen) or a fit's own placement pass evicts its X before the
+# NEXT fit on the same host array can hit it; eviction is LRU (hits
+# refresh recency) so long-lived X outlives transient per-fit leaves.
+# (Within a fit nothing relies on a hit: a search holds its placed X
+# and hands it to every dispatch and to the refit.)
 _BCAST_MAX = 16
 _BCAST_MIN_BYTES = 1 << 20  # caching tiny arrays is pure overhead
 _BCAST_HITS = 0  # diagnostics + test observability
@@ -1865,7 +1870,8 @@ def put_host_array(x, sharding=None):
     device where ``sharding`` is None) for ONE device or a replica on
     each — a host array of :data:`_BLOCK_PUT_BYTES` or more in row
     blocks of a quarter of that, each written into the whole on the
-    device, so that beside the whole only one block is ever held. (A
+    device and waited for, so that beside the whole only one block is
+    ever held. (A
     row-sharded array goes the same way shard by shard:
     :func:`_put_row_shards`.)"""
     import jax
@@ -1878,10 +1884,33 @@ def put_host_array(x, sharding=None):
         return put(x)
     import jax.numpy as jnp
 
-    whole = jnp.zeros(x.shape, x.dtype, device=sharding)
+    if sharding is None:
+        whole = jnp.zeros(x.shape, x.dtype)
+    else:
+        whole = jax.make_array_from_single_device_arrays(
+            x.shape, sharding, [
+                _zeros_on(d, x.shape, x.dtype) for d in
+                sharding.addressable_devices_indices_map(x.shape)])
     for at, rows in _row_blocks(x):
         whole = _write_rows()(whole, put(x[at:at + rows]), at)
+        # waited for, block by block: a block holds its device buffer
+        # from the moment it is enqueued, so a loop that runs ahead has
+        # EVERY block in flight beside the whole — seven of 1.05 GB
+        # beside 6.27: a peak of 13.59 GB where this reads 7.32, at the
+        # same 0.73-0.80 s (PERF.md, PR 36)
+        jax.block_until_ready(whole)
     return whole
+
+
+def _zeros_on(device, shape, dtype):
+    """Zeros made ON ``device``: ``jnp.zeros(..., device=)`` fills the
+    default device and copies from there, a second whole (or shard) on
+    device 0 (its peak 12.77 GB for a shard of 6.35: PERF.md, PR 35)."""
+    import jax
+    import jax.numpy as jnp
+
+    with jax.default_device(device):
+        return jax.device_put(jnp.zeros(shape, dtype), device)
 
 
 #: a SHARD's row blocks are a 64th of :data:`_BLOCK_PUT_BYTES`
@@ -1919,15 +1948,7 @@ def _put_row_shards(x, sharding):
         return jax.device_put(x, sharding)
     import jax.numpy as jnp
 
-    def zeros_on(d, part):
-        # made ON the device: ``jnp.zeros(..., device=)`` fills the
-        # default device and copies from there, a second shard on
-        # device 0 (its peak 12.77 GB for a shard of 6.35: PERF.md,
-        # PR 35)
-        with jax.default_device(d):
-            return jax.device_put(jnp.zeros(part.shape, part.dtype), d)
-
-    wholes = [zeros_on(d, part) for d, part in parts]
+    wholes = [_zeros_on(d, part.shape, part.dtype) for d, part in parts]
     blocks = [_row_blocks(part, _BLOCK_PUT_BYTES // _SHARD_BLOCK_SHARE)
               for _, part in parts]
     for b in range(max(len(bl) for bl in blocks)):

@@ -119,6 +119,86 @@ def test_a_data_axis_search_answers_as_the_1d_mesh_and_never_holds_x_whole(
     assert backend.last_round_stats["tasks"] == 25
 
 
+@pytest.mark.parametrize("n_devices", [1, 4])
+def test_on_a_1d_mesh_x_crosses_once_and_every_program_runs_over_it(
+        monkeypatch, tracing, n_devices):
+    """ISSUE 36: without a ``data`` axis too the search places X once
+    a fit — a replica a device — and the operand every bucket's
+    dispatch and the refit's kernel receive IS that array, same
+    buffers; the refit runs over the first replica, as the one-device
+    program a standalone ``fit`` runs, and places labels and weights
+    only."""
+    from skdist_tpu.models import linear
+
+    X, y = _blobs()
+    host, handed_on, refit_X = [], [], []
+    real_scoped, real_kernel = (backend_mod._put_mesh_scoped,
+                                linear.get_kernel)
+
+    def put_mesh_scoped(x, sharding):
+        out = real_scoped(x, sharding)
+        if getattr(x, "shape", None) == X.shape:
+            (host if isinstance(x, np.ndarray) else handed_on).append(
+                (x, out))
+        return out
+
+    def get_kernel(cls, which, meta, static):
+        kernel = real_kernel(cls, which, meta, static)
+        if which != "fit":
+            return kernel
+
+        def fit_kernel(X_, *rest):
+            refit_X.append(X_)
+            return kernel(X_, *rest)
+
+        return fit_kernel
+
+    monkeypatch.setattr(backend_mod, "_put_mesh_scoped", put_mesh_scoped)
+    real_put = backend_mod.put_host_array
+    monkeypatch.setattr(
+        backend_mod, "put_host_array",
+        lambda x, sharding=None: real_put(x, sharding)
+        if sharding is not None or getattr(x, "shape", None) != X.shape
+        else pytest.fail("X went to the default device: a second crossing"))
+    monkeypatch.setattr(linear, "get_kernel", get_kernel)
+    backend = TPUBackend(devices=jax.devices()[:n_devices])
+    # two static buckets: two dispatches over one placement
+    gs = DistGridSearchCV(
+        LogisticRegression(max_iter=300, tol=1e-6, engine="xla"),
+        {"C": [0.01, 1.0], "fit_intercept": [True, False]}, cv=5,
+        scoring="neg_log_loss", backend=backend).fit(X, y)
+    monkeypatch.undo()
+
+    (_, placed), = host
+    assert placed.sharding.is_fully_replicated
+    assert {s.device for s in placed.addressable_shards} == set(
+        jax.devices()[:n_devices])
+    assert len(handed_on) == 2 and all(
+        x is placed and _buffers(out) == _buffers(placed)
+        for x, out in handed_on)
+    (over,) = refit_X
+    assert over.shape == X.shape and len(over.devices()) == 1
+    assert _buffers(over) == _buffers(placed)[:1]
+    # the spans: X by itself, a dispatch a bucket with X by reference,
+    # and under the refit labels and weights only
+    spans = [e for e in obs_trace.events() if e[1] == "X"]
+    refit = next(e for e in spans if e[0] == "refit")
+    places = [e for e in spans if e[0] == "place_shared"]
+    assert [e[5]["bytes"] == X.nbytes for e in places] == [
+        True, False, False, False]
+    (under,) = [e for e in places
+                if e[5]["parent_id"] == refit[5]["span_id"]]
+    assert under[5]["bytes"] == 8 * len(y) == refit[5]["bytes"]
+    assert refit[5]["x_placed"] is True
+    # and the model is the standalone fit's, to the bit
+    alone = LogisticRegression(
+        max_iter=300, tol=1e-6, engine="xla", **gs.best_params_).fit(X, y)
+    np.testing.assert_array_equal(gs.best_estimator_.coef_, alone.coef_)
+    np.testing.assert_array_equal(gs.best_estimator_.intercept_,
+                                  alone.intercept_)
+    assert not hasattr(gs, "_rounds_X_")
+
+
 def test_the_spans_and_the_round_stats_say_what_a_device_holds(tracing):
     """``place_shared`` carries ``shards`` and ``bytes_per_device``;
     the round stats ``data_shards``, a device's share under
@@ -153,11 +233,13 @@ def test_the_spans_and_the_round_stats_say_what_a_device_holds(tracing):
     lanes = four["chunk"]
     assert 0 < four["collective_bytes_compiled"] <= (
         3 * lanes * (25 * 10 + 2) * 4)
-    # on the data axis the search places X by itself first (the refit
-    # is to run over it), and the dispatch's span then counts the whole
-    # tree, X by reference
-    (p1,), (x4, p4) = placed[1], placed[4]
-    assert (p1["shards"], x4["shards"], p4["shards"]) == (1, 4, 4)
+    # on every mesh the search places X by itself first (every bucket
+    # and the refit are to run over it), and the dispatch's span then
+    # counts the whole tree, X by reference
+    (x1, p1), (x4, p4) = placed[1], placed[4]
+    assert (x1["shards"], p1["shards"], x4["shards"], p4["shards"]) == (
+        1, 1, 4, 4)
+    assert x1["bytes"] == X.nbytes == x1["bytes_per_device"]
     assert x4["bytes"] == X.nbytes == 4 * x4["bytes_per_device"]
     assert p1["bytes_per_device"] == p1["bytes"] == p4["bytes"]
     assert p4["bytes_per_device"] * 4 == p4["bytes"]

@@ -192,15 +192,41 @@ def test_a_large_host_array_reaches_the_device_in_blocks(monkeypatch):
         backend_mod, "_write_rows",
         lambda: lambda whole, block, at: calls.append(
             (int(at), len(block))) or real(whole, block, at))
+    # every write is waited for before the next block is enqueued: a
+    # block holds its device buffer from then on, and a loop that ran
+    # ahead would hold them all beside the whole
+    real_wait, order = jax.block_until_ready, []
+    monkeypatch.setattr(jax, "block_until_ready",
+                        lambda t: order.append(len(calls)) or real_wait(t))
     placed = backend_mod.put_host_array(X)
+    monkeypatch.setattr(jax, "block_until_ready", real_wait)
+    assert order == list(range(1, len(calls) + 1))
     np.testing.assert_array_equal(np.asarray(placed), X)
     # blocks of under a quarter of the bound, the last over the end
     assert len(calls) >= 4 and max(m for _, m in calls) * 28 <= 1024 + 28
     assert calls[-1][0] + calls[-1][1] == 1003
-    mesh = Mesh(np.array(jax.devices()[:2]), ("tasks",))
+    # a replica on each device of a mesh: the whole that the blocks
+    # are written into is made ON each device — never as
+    # ``jnp.zeros(..., device=sharding)``, which fills the default
+    # device and copies from there (a second whole beside the first)
+    mesh = Mesh(np.array(jax.devices()[2:4]), ("tasks",))
+    real_zeros, made_on = jnp.zeros, []
+
+    def zeros(shape, dtype=None, **kw):
+        assert not kw, "zeros made on the default device and copied"
+        made_on.append(jax.config.jax_default_device)
+        return real_zeros(shape, dtype)
+
+    monkeypatch.setattr(jnp, "zeros", zeros)
     rep = backend_mod._put_mesh_scoped(X, NamedSharding(mesh, P()))
+    monkeypatch.setattr(jnp, "zeros", real_zeros)
+    assert sorted(made_on, key=lambda d: d.id) == jax.devices()[2:4]
     np.testing.assert_array_equal(np.asarray(rep), X)
-    assert rep.sharding.is_fully_replicated
+    assert rep.sharding == NamedSharding(mesh, P())
+    assert {s.device for s in rep.addressable_shards} == set(
+        jax.devices()[2:4])
+    for shard in rep.addressable_shards:
+        np.testing.assert_array_equal(np.asarray(shard.data), X)
     n_calls = len(calls)
     tree = _to_jnp({"X": X, "y": np.arange(5), "small": X[:10]})
     np.testing.assert_array_equal(np.asarray(tree["X"]), X)

@@ -638,3 +638,174 @@ def test_engine_grid_routes_to_generic_path(clf_data, monkeypatch):
     ).fit(X, y)
     assert {p["engine"] for p in gs.cv_results_["params"]} == {"host", "xla"}
     assert gs.best_score_ > 0.5
+
+
+# ---------------------------------------------------------------------------
+# the search owns its placed X: one crossing a fit, the refit over the
+# operand the rounds ran on (ISSUE 36; the buffers themselves are
+# followed in tests/test_data_axis.py)
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def tracing():
+    from skdist_tpu.obs import trace as obs_trace
+
+    obs_trace.clear()
+    obs_trace.set_enabled(True)
+    yield obs_trace
+    obs_trace.set_enabled(False)
+    obs_trace.clear()
+
+
+def _refit_and_places(obs_trace):
+    """The fit's ``refit`` span and its ``place_shared`` spans, those
+    under the refit apart."""
+    spans = [e for e in obs_trace.events() if e[1] == "X"]
+    refit = next(e for e in spans if e[0] == "refit")
+    places = [e for e in spans if e[0] == "place_shared"]
+    under = [e for e in places
+             if e[5]["parent_id"] == refit[5]["span_id"]]
+    return refit, places, under
+
+
+def _mesh(n_devices):
+    import jax
+
+    from skdist_tpu.parallel import TPUBackend
+
+    return TPUBackend(devices=jax.devices()[:n_devices])
+
+
+def _placed_refit_case(kind, clf_data, binary_data):
+    from skdist_tpu.models import GaussianNB, MultinomialNB
+
+    if kind == "binary":
+        X, y = binary_data
+        return (LogisticRegression(max_iter=60, engine="xla"),
+                {"C": [0.1, 1.0, 10.0]}, X, y)
+    X, y = clf_data
+    if kind == "multinomial":
+        return (LogisticRegression(max_iter=60, engine="xla"),
+                {"C": [0.1, 1.0, 10.0]}, X, y)
+    if kind == "svc":
+        return (LinearSVC(max_iter=60, engine="xla"),
+                {"C": [0.1, 1.0]}, X, y)
+    if kind == "gaussian_nb":
+        return GaussianNB(), {"var_smoothing": [1e-9, 1e-3]}, X, y
+    return MultinomialNB(), {"alpha": [0.1, 1.0]}, np.abs(X), y
+
+
+@pytest.mark.parametrize("n_devices", [1, 4])
+@pytest.mark.parametrize(
+    "kind", ["binary", "multinomial", "svc", "gaussian_nb", "multinomial_nb"])
+def test_placed_refit_is_the_standalone_fit_to_the_bit(
+        kind, n_devices, clf_data, binary_data, tracing):
+    """The refit over the placed operand runs the program a standalone
+    ``fit`` runs, over the same values: every fitted array is bitwise
+    that of ``clone(best).set_params(**best_params_).fit(X, y)``, on a
+    one-device mesh and over a replica on each of four."""
+    from skdist_tpu.base import clone
+
+    est, grid, X, y = _placed_refit_case(kind, clf_data, binary_data)
+    gs = DistGridSearchCV(est, grid, cv=3, backend=_mesh(n_devices)).fit(X, y)
+    refit, places, under = _refit_and_places(tracing)
+    assert refit[5]["x_placed"] is True and len(under) == 1
+    assert sum(e[5]["bytes"] == X.nbytes for e in places) == 1
+    alone = clone(est).set_params(**gs.best_params_).fit(X, y)
+    assert set(alone._params) == set(gs.best_estimator_._params)
+    for name, value in alone._params.items():
+        np.testing.assert_array_equal(
+            gs.best_estimator_._params[name], value, err_msg=name)
+    assert (gs.predict(X) == alone.predict(X)).all()
+
+
+def test_two_static_buckets_place_x_once(clf_data, tracing):
+    """Candidates that differ in a static parameter compile apart and
+    dispatch apart — over ONE placement of X."""
+    X, y = clf_data
+    DistGridSearchCV(
+        LogisticRegression(max_iter=40, engine="xla"),
+        {"C": [0.1, 1.0], "fit_intercept": [True, False]}, cv=3,
+        backend=_mesh(2)).fit(X, y)
+    refit, places, under = _refit_and_places(tracing)
+    alone = [e for e in places if e[5]["bytes"] == X.nbytes]
+    whole = [e for e in places if e[5]["bytes"] > X.nbytes]
+    assert (len(alone), len(whole), len(under)) == (1, 2, 1)
+    assert under[0][5]["bytes"] == 8 * len(y)
+
+
+def test_auto_engine_on_cpu_still_refits_on_the_host_engine(
+        clf_data, tracing):
+    """Under ``engine='auto'`` on a CPU platform ``fit`` takes the
+    float64 host engine; the hand-over must not turn that refit into a
+    device fit: nothing is placed under ``refit``."""
+    from skdist_tpu.models.host_linear import host_engine_available
+
+    if not host_engine_available():
+        pytest.skip("no host engine here")
+    X, y = clf_data
+    est = LogisticRegression(max_iter=60)
+    assert est.engine == "auto" and est._resolve_host_engine()
+    gs = DistGridSearchCV(
+        est, {"C": [0.1, 1.0]}, cv=3, backend=_mesh(1)).fit(X, y)
+    refit, places, under = _refit_and_places(tracing)
+    assert refit[5] == {**refit[5], "x_placed": False} and not under
+    assert "bytes" not in refit[5]
+    # the rounds did run over a placed X
+    assert sum(e[5]["bytes"] == X.nbytes for e in places) == 1
+    alone = LogisticRegression(max_iter=60, **gs.best_params_).fit(X, y)
+    np.testing.assert_array_equal(gs.best_estimator_.coef_, alone.coef_)
+
+
+@pytest.mark.parametrize("case", ["more_fit_params", "tree_family",
+                                  "local_backend", "elastic_backend"])
+def test_refit_falls_back_to_best_fit(case, clf_data, tracing):
+    """What the search cannot hand over keeps ``best.fit``: fit params
+    beyond one full-length ``sample_weight`` (no batched path), a
+    family without the placed-fit entry (its rounds do run over the
+    X the search placed, which is let go before ``best.fit`` places
+    its own), a backend without a mesh, and an elastic backend, whose
+    mesh may change under the fit."""
+    import jax
+
+    from skdist_tpu.models import DecisionTreeClassifier
+    from skdist_tpu.parallel import LocalBackend, TPUBackend
+
+    X, y = clf_data
+    est = LogisticRegression(max_iter=40, engine="xla")
+    grid, fit_params, backend = {"C": [0.1, 1.0]}, {}, _mesh(1)
+    if case == "more_fit_params":
+        fit_params = {"sample_weight": np.ones(len(y)),
+                      "intercept_init": None}
+    elif case == "tree_family":
+        est, grid = DecisionTreeClassifier(), {"max_depth": [2, 3]}
+    elif case == "local_backend":
+        backend = LocalBackend()
+    else:
+        backend = TPUBackend(devices=jax.devices()[:2], elastic=True)
+    gs = DistGridSearchCV(est, grid, cv=3, backend=backend).fit(
+        X, y, **fit_params)
+    refit, places, under = _refit_and_places(tracing)
+    assert refit[5]["x_placed"] is False and not under
+    assert sum(e[5]["bytes"] == X.nbytes for e in places) == (
+        case == "tree_family")
+    assert gs.predict(X).shape == y.shape
+
+
+def test_no_placed_operand_outlives_the_fit(clf_data):
+    """After ``fit`` — and after a ``fit`` that raised — the search
+    holds no operand, placed or packed, and pickles as before."""
+    X, y = clf_data
+    gs = DistGridSearchCV(
+        LogisticRegression(max_iter=40, engine="xla"), {"C": [0.1, 1.0]},
+        cv=3, backend=_mesh(2)).fit(X, y)
+    assert not hasattr(gs, "_rounds_X_") and gs.backend is None
+    loaded = pickle.loads(pickle.dumps(gs))
+    assert (loaded.predict(X) == gs.predict(X)).all()
+    failing = DistGridSearchCV(
+        LogisticRegression(max_iter=40, engine="xla"), {"C": [0.1, 1.0]},
+        cv=3, backend=_mesh(2), refit="no_such_metric",
+        scoring={"a": "accuracy"})
+    with pytest.raises(ValueError):
+        failing.fit(X, y)
+    assert not hasattr(failing, "_rounds_X_")

@@ -515,12 +515,87 @@ def test_search_refit_takes_the_packed_matrix(monkeypatch):
         est, {"C": [0.1, 1.0]}, cv=3, scoring="neg_log_loss",
         backend=TPUBackend(devices=jax.devices()[:1]),
         error_score="raise").fit(X, y)
-    assert len(packs) == 1 and not hasattr(gs, "_packed_X_")
+    assert len(packs) == 1 and not hasattr(gs, "_rounds_X_")
     alone = LogisticRegression(max_iter=15, tol=1e-4,
                                **gs.best_params_).fit(X, y)
     assert len(packs) == 2
     np.testing.assert_array_equal(gs.best_estimator_.coef_, alone.coef_)
     assert gs.best_estimator_.predict(X).shape == y.shape
+
+
+@pytest.mark.parametrize("n_devices", [1, 4])
+def test_search_refit_runs_over_the_placed_buckets(monkeypatch, n_devices):
+    """The packed tree crosses once a fit too: every leaf of the
+    bucketed X is placed by the search, the dispatch and the refit's
+    kernel are handed those buffers (the refit the first replica of
+    each), the placement under ``refit`` is labels and weights, and
+    the model is the standalone fit's to the bit."""
+    from skdist_tpu.distribute.search import DistGridSearchCV
+    from skdist_tpu.models import LogisticRegression, linear
+    from skdist_tpu.obs import trace as obs_trace
+    from skdist_tpu.parallel import TPUBackend, backend as backend_mod
+
+    X = skewed_csr(seed=4)
+    y = np.arange(X.shape[0]) % 3
+    crossed, kernel_X = [], []
+    real_scoped, real_kernel = (backend_mod._put_mesh_scoped,
+                                linear.get_kernel)
+
+    def put_mesh_scoped(x, sharding):
+        out = real_scoped(x, sharding)
+        if isinstance(x, np.ndarray):
+            # (read now: a round's task slices are donated)
+            crossed.append(pointers(out)[0][0])
+        return out
+
+    def get_kernel(cls, which, meta, static):
+        kernel = real_kernel(cls, which, meta, static)
+        if which != "fit":
+            return kernel
+        return lambda X_, *rest: kernel_X.append(X_) or kernel(X_, *rest)
+
+    def pointers(tree):
+        return [[s.data.unsafe_buffer_pointer()
+                 for s in leaf.addressable_shards]
+                for leaf in jax.tree_util.tree_leaves(tree)]
+
+    monkeypatch.setattr(backend_mod, "_put_mesh_scoped", put_mesh_scoped)
+    monkeypatch.setattr(linear, "get_kernel", get_kernel)
+    obs_trace.clear()
+    obs_trace.set_enabled(True)
+    try:
+        est = LogisticRegression(max_iter=15, tol=1e-4, engine="xla")
+        gs = DistGridSearchCV(
+            est, {"C": [0.1, 1.0]}, cv=3, scoring="neg_log_loss",
+            backend=TPUBackend(devices=jax.devices()[:n_devices]),
+            error_score="raise").fit(X, y)
+        spans = [e for e in obs_trace.events() if e[1] == "X"]
+    finally:
+        obs_trace.set_enabled(False)
+        obs_trace.clear()
+    monkeypatch.undo()
+    (over,) = kernel_X
+    assert isinstance(over, sx.BucketedX)
+    # every leaf the refit's kernel ran over is the first replica of
+    # a host array that crossed (once: the spans below)
+    leaves = [p[0] for p in pointers(over)]
+    assert set(leaves) <= set(crossed) and len(leaves) == len(set(leaves))
+    assert all(len(leaf.devices()) == 1
+               for leaf in jax.tree_util.tree_leaves(over))
+    packed_bytes = sum(
+        leaf.nbytes for leaf in jax.tree_util.tree_leaves(over))
+    refit = next(e for e in spans if e[0] == "refit")
+    places = [e for e in spans if e[0] == "place_shared"]
+    assert [e[5]["bytes"] == packed_bytes for e in places] == [
+        True, False, False]
+    assert refit[5]["x_placed"] is True
+    assert refit[5]["bytes"] == places[-1][5]["bytes"] == 8 * len(y)
+    assert places[-1][5]["parent_id"] == refit[5]["span_id"]
+    alone = LogisticRegression(max_iter=15, tol=1e-4, engine="xla",
+                               **gs.best_params_).fit(X, y)
+    np.testing.assert_array_equal(gs.best_estimator_.coef_, alone.coef_)
+    np.testing.assert_array_equal(gs.best_estimator_.intercept_,
+                                  alone.intercept_)
 
 
 def _products(jaxpr, min_size, count=0):
