@@ -16,7 +16,9 @@ allocation-free: ``span(name)`` returns a module-level no-op singleton
 (no object construction, no ring append, no clock read) — the
 ``SKDIST_TRACE=0`` hot-path contract ``tests/test_obs.py`` pins with
 an allocation spy. ``SKDIST_TRACE=1`` turns recording on; each span
-costs two ``perf_counter`` reads and one ring append at exit.
+costs two ``perf_counter`` reads and one ring append at exit
+(:func:`complete`, for an interval that is already over, the append
+alone).
 Instrumentation sites are per-ROUND / per-BLOCK / per-FLUSH — never
 per-task or per-row — so even traced overhead stays inside the
 obs-smoke's 5% gate.
@@ -65,6 +67,7 @@ __all__ = [
     "enabled",
     "set_enabled",
     "span",
+    "complete",
     "instant",
     "events",
     "clear",
@@ -314,6 +317,27 @@ def span(name, args=None):
     if not _ENABLED:
         return _NOOP
     return _Span(name, args)
+
+
+def complete(name, t0, dur, args=None):
+    """Record an interval that has ALREADY ended as one complete ('X')
+    event: ``t0`` on the ring's clock (``time.perf_counter``), ``dur``
+    in seconds. For work that reports itself when it is over (JAX
+    announces a trace, a lowering or a backend compile at its end:
+    ``parallel/compile_cache``'s listeners), where no context manager
+    can be entered. While a trace context is active the event gets a
+    span id of its own under the context's — it parents under whatever
+    span is open on the recording thread — as :class:`_Span` stamps
+    them. Such a span is over, so it is never a ``TraceAnnotation``;
+    the spans that enclose it are."""
+    if not _ENABLED:
+        return
+    ctx = getattr(_CTX, "ctx", None)
+    if ctx is not None:
+        args = dict(args) if args else {}
+        args.update(trace_id=ctx["trace_id"], span_id=_span_id(),
+                    parent_id=ctx["span_id"])
+    _append((name, "X", t0, dur, threading.get_ident(), args))
 
 
 def instant(name, args=None):

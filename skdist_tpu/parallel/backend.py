@@ -3078,14 +3078,16 @@ def _lane_footprint(plan, task_args):
             1, _leading_dim(task_args))
         found = (task_bytes, 0, 0, 0)
         if hasattr(plan.init_fn, "trace"):
-            try:
-                carry, live, shared_top, rows = _traced_lane_bytes(
-                    plan.init_fn, plan.shared, task_args,
-                    _PROBE_LANES * plan.n_task_slots, shards,
-                )
-                found = (task_bytes + carry, live, shared_top, rows)
-            except Exception as exc:
-                faults.log_suppressed("_lane_footprint", exc)
+            # the miss alone: seconds of Python tracing, once a shape
+            with obs_trace.span("lane_footprint"):
+                try:
+                    carry, live, shared_top, rows = _traced_lane_bytes(
+                        plan.init_fn, plan.shared, task_args,
+                        _PROBE_LANES * plan.n_task_slots, shards,
+                    )
+                    found = (task_bytes + carry, live, shared_top, rows)
+                except Exception as exc:
+                    faults.log_suppressed("_lane_footprint", exc)
         _LANE_FOOTPRINTS[key] = found
     return found
 

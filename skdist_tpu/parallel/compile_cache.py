@@ -35,8 +35,16 @@ This module concentrates every layer of compile reuse in one place:
    change the compiled program.
 
 3. **Counters** (``snapshot()``): hits/misses per tier plus cumulative
-   lowering/compile wall time, so benchmarks and tests can *see* the
-   cold-vs-warm gap instead of inferring it from wall clock.
+   wall time inside this module's own miss paths, so benchmarks and
+   tests can *see* the cold-vs-warm gap instead of inferring it from
+   wall clock — and what JAX itself reports of EVERY compile of the
+   process, whichever path asked for it (a plain ``jax.jit`` called
+   for the first time, an eager ``jnp`` op, this module's AOT tier
+   alike): one pair of ``jax.monitoring`` listeners (:func:`_listen`)
+   counts backend compiles and those XLA's persistent cache did not
+   serve, and with tracing on records each stage as a span
+   (``jax_trace``, ``jax_lower``, ``xla_compile``: the seconds live
+   there, once) under whatever span is open on the compiling thread.
 
 Thread safety: counters and memo insertion take a module lock; the
 underlying dicts are plain (reads are GIL-atomic, and double-building
@@ -44,6 +52,7 @@ a cache entry is benign — last writer wins, both entries are correct).
 """
 
 import os
+import sys
 import threading
 import time
 import warnings
@@ -95,6 +104,12 @@ _COUNTER_KINDS = (
     # tracing in warm-disk processes): file served / file written
     "aot_export_hits",
     "aot_export_writes",
+    # what JAX reports (``_listen``): every backend compile of the
+    # process — on a persistent-cache hit it is the read — and those
+    # XLA's persistent cache did not serve (a cold start; 0 in a
+    # process over a warm directory)
+    "backend_compiles",
+    "xla_cache_misses",
 )
 
 #: jit(vmap(kernel)) entries: (structural-or-identity key, static args,
@@ -165,6 +180,7 @@ def enable_disk_cache(path=None):
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         _DISK_DIR = target
+        _listen()
         return _DISK_DIR
 
 
@@ -252,6 +268,7 @@ def structural_key(family, est_cls, *parts):
 
 
 _FAMILIES = None
+_LISTENING = False
 
 
 def _families():
@@ -263,11 +280,16 @@ def _families():
 
         _FAMILIES = (
             obs_metrics.counter(
-                "compile.events", help="compile-cache tier hits/misses"
+                "compile.events",
+                help="compile-cache tier hits/misses; backend compiles "
+                     "and persistent-cache misses as JAX reports them",
             ),
             obs_metrics.counter(
                 "compile.lower_time_s",
-                help="wall seconds building/lowering/compiling on misses",
+                help="wall seconds inside compile_cache's own miss "
+                     "paths (closure build, jit wrap, export "
+                     "read/write + lower + compile), NOT JAX's "
+                     "lowering: that is the jax_lower span",
             ),
             obs_metrics.counter(
                 "compile.scoped_misses",
@@ -275,7 +297,87 @@ def _families():
                      "obs.metrics.compile_scope (serving engines)",
             ),
         )
+    if not _LISTENING:
+        _listen()
     return _FAMILIES
+
+
+# ---------------------------------------------------------------------------
+# what JAX reports of a compile
+# ---------------------------------------------------------------------------
+
+#: per compiling thread: how the backend compile in progress met XLA's
+#: persistent cache ("miss" once the request uses the cache, "hit" once
+#: it is served), read by the duration that follows on the same thread.
+#: JAX announces no failed compile, so the state is dropped at every
+#: lowering too: a "miss" left by a compile that raised survives only
+#: until the thread next lowers or compiles
+_COMPILING = threading.local()
+
+_JAX_LOWER = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+_JAX_COMPILE = "/jax/core/compile/backend_compile_duration"
+_JAX_CACHE_USED = "/jax/compilation_cache/compile_requests_use_cache"
+_JAX_CACHE_HIT = "/jax/compilation_cache/cache_hits"
+_JAX_CACHE_MISS = "/jax/compilation_cache/cache_misses"
+_STAGE_SPANS = {"/jax/core/compile/jaxpr_trace_duration": "jax_trace",
+                _JAX_LOWER: "jax_lower", _JAX_COMPILE: "xla_compile"}
+
+
+def _on_jax_event(event, **_kw):
+    if event == _JAX_CACHE_USED:
+        _COMPILING.cache = "miss"
+    elif event == _JAX_CACHE_HIT:
+        _COMPILING.cache = "hit"
+    elif event == _JAX_CACHE_MISS:
+        _families()[0].inc(1, kind="xla_cache_misses")
+
+
+def _on_jax_time_span(event, start, end, fun_name=None, **_kw):
+    name = _STAGE_SPANS.get(event)
+    if name is None:
+        return
+    args = {"fun": fun_name} if _trace.enabled() else None
+    if event == _JAX_COMPILE:
+        _families()[0].inc(1, kind="backend_compiles")
+        if args is not None:
+            args["cache"] = getattr(_COMPILING, "cache", None) or "off"
+        _COMPILING.cache = None
+    elif event == _JAX_LOWER:
+        _COMPILING.cache = None
+    if args is not None:
+        # JAX times a stage on time.time(); the ring's clock is
+        # perf_counter, and the stage ended a moment ago
+        t0 = time.perf_counter() - (time.time() - start)
+        _trace.complete(name, t0, end - start, args)
+
+
+def _listen():
+    """Register this module's ``jax.monitoring`` listeners, once a
+    process. Called where the module first touches JAX
+    (:func:`enable_disk_cache`) and from :func:`_families`; a process
+    that has not imported JAX compiles nothing and is left without it.
+    The listeners run only when JAX traces, lowers or compiles
+    something: a warm round's dispatch never reaches them. A JAX
+    without these hooks leaves the program as it was without them — no
+    ``jax_*`` / ``xla_compile`` span, the two counters at 0 — and says
+    so once."""
+    global _LISTENING
+    if _LISTENING or "jax" not in sys.modules:
+        return
+    with _LOCK:
+        if _LISTENING:
+            return
+        _LISTENING = True
+        try:
+            from jax import monitoring
+
+            monitoring.register_event_listener(_on_jax_event)
+            monitoring.register_event_time_span_listener(_on_jax_time_span)
+        except Exception as exc:  # observability never stops a backend
+            warnings.warn(
+                f"compile_cache: this JAX announces no compile events "
+                f"({type(exc).__name__}: {exc}); backend compiles go "
+                f"uncounted and unspanned", RuntimeWarning)
 
 
 def _record(counter, dt=0.0):
@@ -504,11 +606,12 @@ def aot_executable(fn, shared_args, task_like, n_chunk, shared_sig=None):
         warnings.filterwarnings(
             "ignore", message="Some donated buffers were not usable"
         )
-        with _trace.span("compile",
-                         {"tier": "aot", "chunk": int(n_chunk)}
-                         if _trace.enabled() else None):
+        span_args = ({"tier": "aot", "chunk": int(n_chunk), "export": "off"}
+                     if _trace.enabled() else None)
+        with _trace.span("compile", span_args):
             comp = _exported_executable(
-                fn, shared_args, structs, shared_sig, task_sig, n_chunk
+                fn, shared_args, structs, shared_sig, task_sig, n_chunk,
+                span_args,
             )
             if comp is None:
                 comp = fn.lower(shared_args, structs).compile()
@@ -597,7 +700,7 @@ def _export_path(keystr, shared_sig, task_sig, n_chunk):
 
 
 def _exported_executable(fn, shared_args, structs, shared_sig, task_sig,
-                         n_chunk):
+                         n_chunk, span_args=None):
     """The export disk layer: serialized AOT programs next to the XLA
     disk cache, so a warm-disk process skips PYTHON TRACING as well as
     XLA compilation — the two costs that dominate service cold-start.
@@ -614,6 +717,15 @@ def _exported_executable(fn, shared_args, structs, shared_sig, task_sig,
     (un-exportable program, version skew, disk trouble) returns None
     and the caller falls back to the direct lower+compile path; a
     failure of the COMPILE itself propagates.
+
+    With tracing on, the file's read (``export_read``: read +
+    ``deserialize``) or its making (``export_write``: ``jexport.export``
+    — Python tracing and StableHLO — serialize, write) is a span with
+    the file's ``bytes``, and ``span_args`` (the enclosing ``compile``
+    span's) says which way it went: ``export`` ``"hit"`` or ``"write"``
+    where the caller's ``"off"`` stood. The re-lowering of ``exp.call``
+    and the ``compile()`` report themselves (``jax_lower``,
+    ``xla_compile``: :func:`_listen`).
     """
     ent = _JIT_EXPORT_KEY.get(fn)
     if _DISK_DIR is None or ent is None:
@@ -626,21 +738,33 @@ def _exported_executable(fn, shared_args, structs, shared_sig, task_sig,
         if jax.process_count() > 1:
             return None
         path = _export_path(keystr, shared_sig, task_sig, n_chunk)
-        if os.path.exists(path):
-            with open(path, "rb") as f:
-                exp = jexport.deserialize(bytearray(f.read()))
+        how = "hit" if os.path.exists(path) else "write"
+        # a span keeps its dict until it closes: filled in inside it
+        io_args = {}
+        if how == "hit":
+            with _trace.span("export_read", io_args):
+                with open(path, "rb") as f:
+                    blob = f.read()
+                exp = jexport.deserialize(bytearray(blob))
+                io_args["bytes"] = len(blob)
             _record("aot_export_hits")
         else:
-            exp = jexport.export(fn)(shared_args, structs)
-            blob = exp.serialize()
-            os.makedirs(os.path.dirname(path), exist_ok=True)
-            tmp = path + f".tmp{os.getpid()}"
-            with open(tmp, "wb") as f:
-                f.write(blob)
-            os.replace(tmp, path)
+            with _trace.span("export_write", io_args):
+                exp = jexport.export(fn)(shared_args, structs)
+                blob = exp.serialize()
+                os.makedirs(os.path.dirname(path), exist_ok=True)
+                tmp = path + f".tmp{os.getpid()}"
+                with open(tmp, "wb") as f:
+                    f.write(blob)
+                os.replace(tmp, path)
+                io_args["bytes"] = len(blob)
             _record("aot_export_writes")
+        if span_args is not None:
+            span_args["export"] = how
         lowered = wrap(exp.call).lower(shared_args, structs)
     except Exception as exc:
+        if span_args is not None:
+            span_args["export"] = "off"
         warnings.warn(
             f"compile_cache export layer disabled for this program "
             f"({type(exc).__name__}: {exc}); falling back to direct "

@@ -1,9 +1,11 @@
 """
 The benchmark's readers of what the program measures inside a fit:
-``round_counts`` (the round loop's counters) and ``span_seconds`` (the
-program's span tree), on hand-built windows and hand-built ring events,
-and every metric file against the reader and the ``BENCHMARK.json``
-entry it needs.
+``round_counts`` (the round loop's counters), ``span_seconds`` (the
+program's span tree), ``fit_tree`` (the warm-up fit's tree beside
+the window's: what moves ``setup_s``) and ``compile_events`` (what JAX
+reports of the process's compiles, from the registry), on hand-built
+windows and hand-built ring events, and every metric file against the
+reader and the ``BENCHMARK.json`` entry it needs.
 """
 
 import glob
@@ -18,7 +20,7 @@ REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 sys.path.insert(0, REPO)
 
 from chipbench.readers import (  # noqa: E402
-    lanes_per_round, round_counts, span_seconds,
+    compile_events, fit_tree, lanes_per_round, round_counts, span_seconds,
 )
 from skdist_tpu.obs import trace as obs_trace  # noqa: E402
 
@@ -250,6 +252,291 @@ def test_span_seconds_on_a_real_traced_fit(ring):
 
 
 # ---------------------------------------------------------------------------
+# fit_tree: the warm-up fit's tree beside the window's (PR 37)
+# ---------------------------------------------------------------------------
+
+#: name -> (``what`` the reader is asked for, source, layer, moves), in
+#: the order the entries were appended
+SETUP_METRICS = {
+    "setup_outside_fit_s.setup":
+        ("outside_fit", "host_clock", "process start", "setup_s"),
+    "first_fit_extra_s.setup":
+        ("first_fit_extra", "program_span", "compile", "setup_s"),
+    "warmup_compile_s.setup":
+        ("warmup_compile", "program_span", "compile", "setup_s"),
+    "warmup_xla_s.setup":
+        ("warmup_xla", "program_span", "compile", "setup_s"),
+    "window_xla_compiles.search":
+        ("window_xla_compiles", "program_span", "compile",
+         "search_fits_per_s"),
+}
+
+
+def _first_tree(trace, t0):
+    """``_tree`` with what a process's first fit compiles, the root 16
+    long for it: under ``round_loop`` a ``compile`` of 4 (aot) holding
+    an ``export_write`` of 1.5 — itself holding a ``jax_trace`` of 1
+    with an inner ``jit``'s ``jax_trace`` of 0.4 inside that — a
+    ``jax_lower`` of 0.5 and an ``xla_compile`` of 2; under ``refit``,
+    outside every ``compile``, a ``jax_trace`` of 0.3, a ``jax_lower``
+    of 0.2 and an ``xla_compile`` of 1; a ``lane_footprint`` of 0.5
+    under the root with a ``jax_trace`` of 0.45 in it. Spans that JAX
+    reports carry their enclosing span's id as parent, nested or not.
+    Named and outermost: 4 + 0.3 + 0.2 + 1 + 0.5 = 6."""
+    root = trace + "-root"
+
+    def sp(name, at, dur, span, parent=root, **args):
+        e = _span(name, t0 + at, dur, trace, trace + span, parent)
+        e[5].update(args)
+        return e
+
+    loop, aot, write, refit = (trace + s for s in ("-c", "-k", "-w", "-e"))
+    return [
+        sp("cv_split", 0, 0.5, "-a"),
+        sp("jax_trace", 0.52, 0.45, "-f1", trace + "-f", fun="init"),
+        sp("lane_footprint", 0.5, 0.5, "-f"),
+        sp("place_shared", 1.0, 1, "-b"),
+        sp("jax_trace", 2.3, 0.4, "-w2", write, fun="inner"),
+        sp("jax_trace", 2.1, 1.0, "-w1", write, fun="step"),
+        sp("export_write", 2.0, 1.5, "-w", aot, bytes=1000),
+        sp("jax_lower", 3.5, 0.5, "-k1", aot, fun="jit(call)"),
+        sp("xla_compile", 4.0, 2.0, "-k2", aot, fun="jit(call)",
+           cache="miss"),
+        sp("compile", 2.0, 4.0, "-k", loop, tier="aot", export="write"),
+        sp("round_loop", 2.0, 9.0, "-c"),
+        sp("finalize", 11.0, 1, "-d"),
+        sp("jax_trace", 12.0, 0.3, "-e1", refit, fun="kernel"),
+        sp("jax_lower", 12.3, 0.2, "-e2", refit, fun="jit(kernel)"),
+        sp("xla_compile", 12.5, 1.0, "-e3", refit, fun="jit(kernel)",
+           cache="hit"),
+        sp("refit", 12.0, 3.5, "-e"),
+        _span("search_fit", t0, 16.0, trace, root, trace + "-caller"),
+    ]
+
+
+def _process(n_window=2):
+    """A process's ring: something JAX compiled before any fit (no
+    ids), the warm-up fit, then ``n_window`` steady fits of 10."""
+    loose = ("xla_compile", "X", 1.0, 7.0, 1,
+             {"fun": "jit(generate)", "cache": "miss"})
+    events = [loose] + _first_tree("warm", 20.0)
+    for i in range(n_window):
+        events += _tree(f"t{i}", 100.0 + 20 * i)
+    return events
+
+
+@pytest.mark.parametrize("what, want", [
+    ("outside_fit", 40.0 - 16.0),
+    ("first_fit_extra", 16.0 - 10.0),
+    ("warmup_compile", 6.0),
+    ("warmup_xla", 3.0),
+    ("window_xla_compiles", 0.0),
+])
+def test_fit_tree_readings(what, want):
+    assert fit_tree.reading(_process(), 2, what, setup_s=40.0) == \
+        pytest.approx(want)
+
+
+def test_fit_tree_finds_the_warm_up_root_before_the_windows():
+    events = _tree("older", 0.0, scale=5.0) + _process(3)
+    trees = fit_tree.fit_trees(events, 3)
+    assert [root[5]["trace_id"] for root, _ in trees] == [
+        "warm", "t0", "t1", "t2"]
+    assert all(e[5]["trace_id"] == "warm" for e in trees[0][1])
+    assert len(trees[0][1]) == len(_first_tree("warm", 0.0)) - 1
+    # the window's mean is over the window's roots alone
+    slow = _process(1) + _tree("t1", 200.0, scale=1.4)
+    assert fit_tree.reading(slow, 2, "first_fit_extra") == \
+        pytest.approx(16.0 - 12.0)
+
+
+@pytest.mark.parametrize("names, want", [
+    (("compile", "xla_compile"), 4.0 + 1.0),   # one inside, one outside
+    (("jax_trace",), 0.45 + 1.0 + 0.3),        # the inner jit's once
+    (("export_write", "jax_trace"), 0.45 + 1.5 + 0.3),
+    (("xla_compile",), 3.0),
+    (("pack_x",), 0.0),
+])
+def test_fit_tree_counts_a_nested_span_once(names, want):
+    tree = _first_tree("warm", 0.0)
+    assert fit_tree.outermost_seconds(tree, names) == pytest.approx(want)
+
+
+def test_fit_tree_counts_threads_apart():
+    """Two threads that compile at the same time each paid their
+    seconds; one thread's overlapping reports are one interval."""
+    a = ("xla_compile", "X", 0.0, 2.0, 1, {})
+    b = ("xla_compile", "X", 1.0, 2.0, 2, {})
+    c = ("jax_lower", "X", 1.5, 1.0, 1, {})
+    assert fit_tree.outermost_seconds([a, b, c]) == pytest.approx(2.5 + 2.0)
+
+
+def test_fit_tree_window_compiles_are_counted_per_fit():
+    events = _process(2)
+    recompiled = _span("xla_compile", 105.0, 0.2, "t0", "t0-x", "t0-e")
+    recompiled[5].update(fun="jit(kernel)", cache="hit")
+    assert fit_tree.reading(events + [recompiled], 2,
+                            "window_xla_compiles") == 0.5
+
+
+@pytest.mark.parametrize("what", [m[0] for m in SETUP_METRICS.values()])
+def test_fit_tree_too_few_roots(what):
+    events = _process(2)
+    assert fit_tree.reading(events, 3, what, setup_s=40.0) is None
+    assert fit_tree.reading(events, 0, what, setup_s=40.0) is None
+    assert fit_tree.reading([], 1, what, setup_s=40.0) is None
+
+
+@pytest.mark.parametrize("what, want", [
+    ("outside_fit", 30.0), ("first_fit_extra", 0.0),
+    ("warmup_compile", None), ("warmup_xla", None),
+    ("window_xla_compiles", None),
+])
+def test_fit_tree_on_a_program_that_does_not_report_its_compiles(
+        what, want):
+    """The parent's ring: roots and ``compile`` spans, nothing of what
+    JAX announces — the roots' two readings, and no number made up for
+    the rest."""
+    loose = ("compile", "X", 1.0, 0.1, 1, {"tier": "jit"})
+    events = [loose] + _tree("warm", 0.0) + _tree("t1", 100.0)
+    got = fit_tree.reading(events, 1, what, setup_s=40.0)
+    assert got == want if want is None else got == pytest.approx(want)
+
+
+@pytest.mark.parametrize("what", [m[0] for m in SETUP_METRICS.values()])
+def test_fit_tree_none_when_tracing_is_off_or_the_ring_dropped(ring, what):
+    for ev in _process(1):
+        ring(ev)
+    ctx = {"fits": [{}], "setup_s": 40.0}
+    assert fit_tree.read(ctx, what) is not None
+    obs_trace.set_enabled(False)
+    assert fit_tree.read(ctx, what) is None
+    obs_trace.set_enabled(True)
+    obs_trace.set_ring_size(len(_process(1)) - 1)
+    for ev in _process(1):
+        ring(ev)
+    assert obs_trace.dropped() == 1
+    assert fit_tree.read(ctx, what) is None
+
+
+def test_fit_tree_on_real_traced_fits(ring):
+    """The reader against the program itself: a first fit whose memos
+    were cleared, then a steady one."""
+    import jax
+    import numpy as np
+
+    from skdist_tpu.distribute.search import DistGridSearchCV
+    from skdist_tpu.models import LogisticRegression
+    from skdist_tpu.parallel import TPUBackend, compile_cache
+
+    rng = np.random.RandomState(1)
+    X = rng.normal(size=(300, 9)).astype(np.float32)
+    y = (X[:, 0] > 0).astype(np.int64)
+    backend = TPUBackend(devices=jax.devices()[:1])
+    compile_cache.clear_memos()
+    for _ in range(2):
+        DistGridSearchCV(
+            LogisticRegression(max_iter=40, engine="xla"),
+            {"C": [float(c) for c in np.logspace(-2, 2, 8)]},
+            backend=backend, cv=4, partitions=8,
+        ).fit(X, y)
+    compile_cache.clear_memos()
+    roots = [e for e in obs_trace.events() if e[0] == "search_fit"]
+    ctx = {"fits": [{}], "setup_s": roots[0][3] + 5.0}
+    got = {what: fit_tree.read(ctx, what)
+           for what, *_ in SETUP_METRICS.values()}
+    assert got["outside_fit"] == pytest.approx(5.0)
+    assert got["first_fit_extra"] == pytest.approx(roots[0][3] - roots[1][3])
+    assert 0 < got["warmup_xla"] <= got["warmup_compile"] <= roots[0][3]
+    assert got["warmup_compile"] <= got["first_fit_extra"] + roots[1][3]
+    assert got["window_xla_compiles"] == 0.0
+
+
+@pytest.mark.parametrize("name", sorted(SETUP_METRICS))
+def test_setup_metric_resolves_to_the_reader_and_an_entry(name):
+    what, source, layer, moves = SETUP_METRICS[name]
+    with open(os.path.join(REPO, "chipbench", "metrics",
+                           name + ".json")) as f:
+        spec = json.load(f)
+    assert spec == {"reader": "fit_tree", "args": {"what": what}}
+    entry = {m["name"]: m for m in _bench()["per_layer"]}[name]
+    # no ``workloads``: every cell reports them
+    assert entry == {"name": name, "unit": entry["unit"], "better": "lower",
+                     "source": source, "layer": layer, "moves": moves}
+    assert entry["unit"] == ("count" if what == "window_xla_compiles"
+                             else "s")
+    assert name.endswith(".setup" if moves == "setup_s" else ".search")
+    # the reader takes the file's arguments, and finds nothing to read
+    # on a ring without the roots
+    assert fit_tree.read({"fits": [{}], "setup_s": 1.0},
+                         **spec["args"]) is None
+
+
+# ---------------------------------------------------------------------------
+# compile_events: the registry's counts of what JAX reports (PR 37)
+# ---------------------------------------------------------------------------
+
+#: name -> the ``snapshot()`` key it reads, in the order appended
+COUNTER_METRICS = {
+    "backend_compiles.setup": "backend_compiles",
+    "xla_cache_misses.setup": "xla_cache_misses",
+}
+
+
+@pytest.mark.parametrize("name", sorted(COUNTER_METRICS))
+def test_counter_metric_resolves_to_the_reader_and_an_entry(name):
+    key = COUNTER_METRICS[name]
+    with open(os.path.join(REPO, "chipbench", "metrics",
+                           name + ".json")) as f:
+        spec = json.load(f)
+    assert spec == {"reader": "compile_events", "args": {"key": key}}
+    entry = {m["name"]: m for m in _bench()["per_layer"]}[name]
+    # no ``workloads``: every cell reports them, traced or not
+    assert entry == {"name": name, "unit": "count", "better": "lower",
+                     "source": "program_counter", "layer": "compile",
+                     "moves": "setup_s"}
+
+
+def test_compile_events_reads_the_process_total(monkeypatch):
+    """Untraced too: the registry counts whether the ring records or
+    not, and a compile XLA's cache serves is no miss."""
+    import jax
+    import numpy as np
+
+    from skdist_tpu.parallel import compile_cache
+
+    compile_cache.enable_disk_cache()
+    assert not obs_trace.enabled()
+    before = {k: compile_events.read({}, k)
+              for k in COUNTER_METRICS.values()}
+    assert all(isinstance(v, float) for v in before.values())
+
+    def fn(x):
+        return x * 7 - 3
+
+    jax.jit(fn)(np.float32(2)).block_until_ready()
+    assert compile_events.read({}, "backend_compiles") == \
+        before["backend_compiles"] + 1
+    jax.clear_caches()
+    jax.jit(fn)(np.float32(2)).block_until_ready()  # read from the disk
+    assert compile_events.read({}, "backend_compiles") == \
+        before["backend_compiles"] + 2
+    assert compile_events.read({}, "xla_cache_misses") <= \
+        before["xla_cache_misses"] + 1
+
+
+def test_compile_events_on_a_program_without_the_counters(monkeypatch):
+    """The parent's ``snapshot()`` has no such key: the metric is left
+    out, nothing raises."""
+    from skdist_tpu.parallel import compile_cache
+
+    monkeypatch.setattr(compile_cache, "snapshot",
+                        lambda: {"aot_misses": 3, "disk_cache_dir": "/x"})
+    for key in COUNTER_METRICS.values():
+        assert compile_events.read({}, key) is None
+
+
+# ---------------------------------------------------------------------------
 # the metric files
 # ---------------------------------------------------------------------------
 
@@ -294,7 +581,8 @@ def test_every_metric_file_has_its_entry_and_reader():
             REPO, "chipbench", "readers", spec["reader"] + ".py"))
     # the new entries were appended: the accepted ones keep their places
     appended = (NEW_METRICS + TEXT_METRICS + MNIST_METRICS
-                + FOUR_CHIP_METRICS)
+                + FOUR_CHIP_METRICS + tuple(SETUP_METRICS)
+                + tuple(COUNTER_METRICS))
     assert [m["name"] for m in bench["per_layer"]][
         -len(appended):] == list(appended)
 

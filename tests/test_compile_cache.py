@@ -218,11 +218,15 @@ def test_snapshot_and_reset():
     snap = compile_cache.snapshot()
     for key in ("kernel_hits", "kernel_misses", "jit_hits", "jit_misses",
                 "aot_hits", "aot_misses", "lower_time_s",
-                "disk_cache_dir"):
+                "disk_cache_dir",
+                # what JAX reports of every compile of the process
+                "backend_compiles", "xla_cache_misses"):
         assert key in snap
     compile_cache.reset_stats()
     snap2 = compile_cache.snapshot()
     assert snap2["jit_hits"] == 0 and snap2["kernel_misses"] == 0
+    assert snap2["backend_compiles"] == 0
+    assert snap2["xla_cache_misses"] == 0
     # disk config survives a counter reset
     assert snap2["disk_cache_dir"] == snap["disk_cache_dir"]
 

@@ -9,8 +9,13 @@ The measurement inside a search fit:
   lane slots;
 - one ``DistGridSearchCV.fit`` is one span tree from ``search_fit``
   down to the round loop, and leaves nothing behind with tracing off;
+- what JAX reports of a compile (trace, lowering, backend compile,
+  persistent-cache hit or miss) lands in the fit's tree and in the
+  registry, the export tier's reads and writes beside it;
 - the solver's phases are named in the compiled program.
 """
+
+import warnings
 
 import numpy as np
 import pytest
@@ -444,6 +449,250 @@ def test_untraced_fit_leaves_the_ring_empty(one_device_backend):
     assert obs_trace.events() == []
     assert obs_trace.span("search_fit") is obs_trace._NOOP
     assert obs_trace.current_context() is None
+
+
+# ---------------------------------------------------------------------------
+# (c2) the compile path reports itself
+# ---------------------------------------------------------------------------
+
+#: what JAX announces of a compile, as the program's listeners record it
+ANNOUNCED = ("jax_trace", "jax_lower", "xla_compile")
+#: ... and every span that names a part of a first fit's extra seconds
+COMPILE_SPANS = ANNOUNCED + ("compile", "export_read", "export_write",
+                             "lane_footprint")
+
+
+@pytest.fixture(scope="module")
+def fresh_cache_dir(tmp_path_factory):
+    """The persistent compile cache (XLA's entries and the export
+    tier) re-pointed at an empty directory for this module's compile
+    passes, and put back."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from skdist_tpu.parallel import compile_cache
+
+    fresh = str(tmp_path_factory.mktemp("compile_cache"))
+    was = compile_cache.enable_disk_cache()
+    jax.config.update("jax_compilation_cache_dir", fresh)
+    compilation_cache.reset_cache()
+    compile_cache._DISK_DIR = fresh
+    yield fresh
+    compile_cache._DISK_DIR = was
+    jax.config.update("jax_compilation_cache_dir", was)
+    compilation_cache.reset_cache()
+    compile_cache.clear_memos()
+
+
+@pytest.fixture(scope="module")
+def compile_passes(fresh_cache_dir):
+    """Four fits of one search on one device, and what each left in the
+    ring and moved in ``snapshot()``: ``cold`` (memos cleared, the cache
+    directory empty), ``second`` (the same process again), ``warm_disk``
+    (memos cleared, the directory kept: what a second process finds)
+    and ``untraced`` (memos cleared again, tracing off)."""
+    from skdist_tpu.parallel import compile_cache
+
+    X, y = _search_data(4)
+    Cs = [float(c) for c in np.logspace(-3, 3, 8)]
+    backend = TPUBackend(devices=jax.devices()[:1])
+    passes = {}
+    for name in ("cold", "second", "warm_disk", "untraced"):
+        if name != "second":
+            compile_cache.clear_memos()
+        obs_trace.clear()
+        obs_trace.set_enabled(name != "untraced")
+        before = compile_cache.snapshot()
+        try:
+            _small_search(backend, Cs).fit(X, y)
+        finally:
+            obs_trace.set_enabled(False)
+        after = compile_cache.snapshot()
+        passes[name] = {
+            "events": obs_trace.events(),
+            "moved": {k: after[k] - before[k] for k in after
+                      if k != "disk_cache_dir"},
+            "mode": backend.last_round_stats["mode"],
+        }
+        obs_trace.clear()
+    return passes
+
+
+def _spans(events, *names):
+    return [e for e in events if e[1] == "X" and e[0] in names]
+
+
+def _ancestors(events, e):
+    """Names of the spans that enclose ``e`` by their ids."""
+    by_id = {x[5]["span_id"]: x for x in events
+             if x[1] == "X" and x[5] and "span_id" in x[5]}
+    names = []
+    while e is not None:
+        e = by_id.get(e[5].get("parent_id"))
+        if e is not None:
+            names.append(e[0])
+    return names
+
+
+def test_cold_fit_holds_what_jax_reports_in_its_tree(compile_passes):
+    events = compile_passes["cold"]["events"]
+    assert compile_passes["cold"]["mode"] == "compacted"
+    (root,) = _spans(events, "search_fit")
+    compiles = _spans(events, "xla_compile")
+    assert compiles
+    for e in compiles + _spans(events, "jax_trace", "jax_lower"):
+        assert e[5]["trace_id"] == root[5]["trace_id"]
+        assert isinstance(e[5]["fun"], str) and e[5]["fun"]
+        assert e[3] >= 0 and "search_fit" in _ancestors(events, e)
+    assert all(e[5]["cache"] == "miss" for e in compiles)
+    assert _spans(events, "jax_trace") and _spans(events, "jax_lower")
+
+
+def test_cold_fit_compiles_outside_every_compile_span(compile_passes):
+    """The ``fit`` kernel is a ``jax.jit`` object built under
+    ``kernel_memo``: its trace, lowering and compile happen at its
+    first call, under ``refit``, where no ``compile`` span is open."""
+    events = compile_passes["cold"]["events"]
+    outside = [e for e in _spans(events, "xla_compile")
+               if "compile" not in _ancestors(events, e)]
+    assert outside
+    assert any("refit" in _ancestors(events, e) for e in outside)
+    assert any("kernel" in e[5]["fun"] for e in outside)
+    inside = [e for e in _spans(events, "xla_compile")
+              if "compile" in _ancestors(events, e)]
+    assert inside  # the round loop's programs, under the aot tier
+
+
+def test_cold_fit_writes_its_exports_under_the_aot_compile_span(
+        compile_passes, fresh_cache_dir):
+    import os
+
+    events = compile_passes["cold"]["events"]
+    writes = _spans(events, "export_write")
+    assert writes and not _spans(events, "export_read")
+    by_id = {e[5]["span_id"]: e for e in _spans(events, "compile")}
+    for w in writes:
+        parent = by_id[w[5]["parent_id"]]
+        assert parent[5]["tier"] == "aot"
+        assert parent[5]["export"] == "write"
+        assert w[5]["bytes"] > 0
+        assert parent[2] <= w[2] and w[3] <= parent[3]
+    aot = [e for e in by_id.values() if e[5].get("tier") == "aot"]
+    assert len(aot) == len(writes)
+    assert all("export" not in e[5] for e in by_id.values()
+               if e[5].get("tier") != "aot")
+    files = os.listdir(os.path.join(fresh_cache_dir, "aot_exports"))
+    assert sorted(w[5]["bytes"] for w in writes) == sorted(
+        os.path.getsize(os.path.join(fresh_cache_dir, "aot_exports", f))
+        for f in files)
+    moved = compile_passes["cold"]["moved"]
+    assert moved["aot_export_writes"] == len(writes)
+    assert moved["backend_compiles"] == len(_spans(events, "xla_compile"))
+    assert moved["xla_cache_misses"] == moved["backend_compiles"]
+
+
+def test_cold_fit_spans_the_lane_footprint_once(compile_passes):
+    events = compile_passes["cold"]["events"]
+    (probe,) = _spans(events, "lane_footprint")
+    assert "search_fit" in _ancestors(events, probe)
+    # the abstract trace reports itself inside it
+    assert any(probe[2] <= e[2] and e[2] + e[3] <= probe[2] + probe[3] + 1e-3
+               for e in _spans(events, "jax_trace"))
+
+
+@pytest.mark.parametrize("name", COMPILE_SPANS)
+def test_second_fit_of_a_process_holds_no_compile_span(
+        compile_passes, name):
+    events = compile_passes["second"]["events"]
+    assert _spans(events, "search_fit") and _spans(events, "round_dispatch")
+    assert not _spans(events, name)
+    moved = compile_passes["second"]["moved"]
+    assert moved["backend_compiles"] == 0
+
+
+def test_warm_disk_pass_reads_exports_and_hits_xlas_cache(compile_passes):
+    events = compile_passes["warm_disk"]["events"]
+    reads = _spans(events, "export_read")
+    assert reads and not _spans(events, "export_write")
+    assert all(r[5]["bytes"] > 0 for r in reads)
+    aot = [e for e in _spans(events, "compile")
+           if e[5].get("tier") == "aot"]
+    assert len(aot) == len(reads)
+    assert all(e[5]["export"] == "hit" for e in aot)
+    compiles = _spans(events, "xla_compile")
+    assert compiles and all(e[5]["cache"] == "hit" for e in compiles)
+    # the re-lowering of the deserialized program reports itself
+    assert any("compile" in _ancestors(events, e)
+               for e in _spans(events, "jax_lower"))
+    moved = compile_passes["warm_disk"]["moved"]
+    assert moved["aot_export_hits"] == len(reads)
+    assert moved["aot_export_writes"] == 0
+    assert moved["backend_compiles"] == len(compiles)
+    assert moved["xla_cache_misses"] == 0
+
+
+def test_untraced_compiles_bill_the_registry_and_leave_the_ring_empty(
+        compile_passes):
+    untraced = compile_passes["untraced"]
+    assert untraced["events"] == []
+    moved = untraced["moved"]
+    assert moved["backend_compiles"] >= 2
+    assert moved["xla_cache_misses"] == 0  # the directory is warm
+    # what was there keeps its meaning beside the new keys
+    assert moved["aot_misses"] >= 1 and moved["lower_time_s"] > 0
+
+
+def test_listeners_are_registered_once_a_process():
+    from jax._src import monitoring
+
+    from skdist_tpu.parallel import compile_cache
+
+    compile_cache.enable_disk_cache()
+    compile_cache._listen()
+    compile_cache.snapshot()
+    assert monitoring.get_event_listeners().count(
+        compile_cache._on_jax_event) == 1
+    assert monitoring.get_event_time_span_listeners().count(
+        compile_cache._on_jax_time_span) == 1
+    before = compile_cache.snapshot()["backend_compiles"]
+    jax.jit(lambda x: x * 3 + 1)(np.float32(2)).block_until_ready()
+    assert compile_cache.snapshot()["backend_compiles"] == before + 1
+
+
+def test_a_jax_without_the_hooks_warns_and_builds(monkeypatch):
+    """An observability hook never stops a backend from being built."""
+    from skdist_tpu.parallel import compile_cache
+
+    monkeypatch.setattr(compile_cache, "_LISTENING", False)
+    monkeypatch.delattr(jax.monitoring, "register_event_listener")
+    with pytest.warns(RuntimeWarning, match="announces no compile events"):
+        compile_cache.snapshot()
+    with warnings.catch_warnings():
+        warnings.simplefilter("error")  # said once, not at every call
+        compile_cache.snapshot()
+        TPUBackend(devices=jax.devices()[:1])
+
+
+@pytest.mark.parametrize("left", [None, "miss"])
+def test_compile_cache_off_reads_off(tracing, left):
+    """A compile that does not ask XLA's persistent cache says so —
+    also after a compile that raised left its ``miss`` on the thread:
+    the lowering before the next compile drops it."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    from skdist_tpu.parallel import compile_cache
+
+    compile_cache._COMPILING.cache = left
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        jax.jit(lambda x: x * 5 - 1)(np.float32(2)).block_until_ready()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was_on)
+        compilation_cache.reset_cache()
+    (compiled,) = _spans(obs_trace.events(), "xla_compile")
+    assert compiled[5]["cache"] == "off"
+    assert "trace_id" not in compiled[5]  # no fit's context was open
 
 
 # ---------------------------------------------------------------------------

@@ -14,6 +14,7 @@ Unified telemetry plane tests (``skdist_tpu.obs``):
 
 import json
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -26,6 +27,20 @@ from skdist_tpu.obs.metrics import (
     MetricsRegistry,
     new_round_stats,
 )
+
+
+def _span(name, args=None):
+    with obs_trace.span(name, args):
+        pass
+
+
+#: the three ways an event reaches the ring
+RECORDERS = {
+    "span": _span,
+    "instant": obs_trace.instant,
+    "complete": lambda name, args=None: obs_trace.complete(
+        name, 1.0, 0.5, args),
+}
 
 
 @pytest.fixture
@@ -257,13 +272,32 @@ class TestTrace:
         finally:
             obs_trace.set_ring_size(65536)
 
-    def test_disabled_records_nothing(self):
+    @pytest.mark.parametrize("record", sorted(RECORDERS))
+    def test_disabled_records_nothing(self, record):
         obs_trace.set_enabled(False)
         obs_trace.clear()
-        with obs_trace.span("x", {"k": 1}):
-            pass
-        obs_trace.instant("y")
+        RECORDERS[record]("x", {"k": 1})
         assert obs_trace.events() == []
+
+    @pytest.mark.parametrize("record", sorted(RECORDERS))
+    def test_enabled_records_one_event(self, tracing, record):
+        RECORDERS[record]("x", {"k": 1})
+        (ev,) = obs_trace.events()
+        assert ev[0] == "x" and ev[5] == {"k": 1}
+        assert ev[1] == ("i" if record == "instant" else "X")
+
+    def test_complete_keeps_the_interval_it_is_given(self, tracing):
+        """An interval that is over goes into the ring as it was
+        measured: the same tuple a span leaves, on the ring's clock."""
+        with obs_trace.span("outer"):
+            t0 = time.perf_counter()
+            obs_trace.complete("stage", t0, 0.25, {"fun": "f"})
+        stage, outer = obs_trace.events()
+        assert stage == ("stage", "X", t0, 0.25, threading.get_ident(),
+                         {"fun": "f"})
+        assert outer[2] <= stage[2]
+        doc = obs_trace.chrome_trace_events()
+        assert doc[0]["dur"] == 0.25e6 and doc[0]["ph"] == "X"
 
     def test_disabled_span_is_shared_noop(self):
         """The off path hands back ONE module-level singleton — no
@@ -295,6 +329,7 @@ class TestTrace:
                     with obs_trace.span("hot"):
                         pass
                     obs_trace.instant("hot")
+                    obs_trace.complete("hot", 0.0, 0.0)
 
             loop(64)  # warm up freelists/bytecode caches
             import gc
@@ -654,11 +689,33 @@ class TestTraceContext:
         # the thread context was restored on exit
         assert obs_trace.current_context() is None
 
-    def test_no_context_spans_carry_no_ids(self, tracing):
-        with obs_trace.span("bare"):
-            pass
+    @pytest.mark.parametrize("record", sorted(RECORDERS))
+    def test_no_context_spans_carry_no_ids(self, tracing, record):
+        RECORDERS[record]("bare")
         ev = obs_trace.chrome_trace_events()[-1]
         assert "args" not in ev or "trace_id" not in ev.get("args", {})
+
+    def test_complete_adopts_context(self, tracing):
+        """An interval recorded when it is over parents under whatever
+        span is open on its thread, with a span id of its own; the
+        caller's args are not written into."""
+        ctx = obs_trace.new_context()
+        args = {"fun": "jit(f)"}
+        with obs_trace.use_context(ctx):
+            with obs_trace.span("compile"):
+                obs_trace.complete("xla_compile", 1.0, 0.5, args)
+                obs_trace.complete("xla_compile", 2.0, 0.5)
+            obs_trace.complete("jax_trace", 3.0, 0.5)
+        first, second, outer, loose = obs_trace.chrome_trace_events()
+        assert first["args"]["trace_id"] == ctx["trace_id"]
+        assert first["args"]["parent_id"] == outer["args"]["span_id"]
+        assert second["args"]["parent_id"] == outer["args"]["span_id"]
+        assert first["args"]["fun"] == "jit(f)" and args == {"fun": "jit(f)"}
+        ids = {e["args"]["span_id"] for e in (first, second, outer, loose)}
+        assert len(ids) == 4
+        assert loose["args"]["parent_id"] == ctx["span_id"]
+        # ... and leaves the thread's context as it found it
+        assert obs_trace.current_context() is None
 
     def test_instant_adopts_context(self, tracing):
         ctx = obs_trace.new_context()
