@@ -503,10 +503,13 @@ def compaction_enabled():
 
 def resolve_slice_iters(max_iter):
     """Iterations per slice of the compacted path: ``SKDIST_SLICE_ITERS``
-    when set, else ~1/8 of the iteration budget (floor 4 — a slice
-    boundary costs a dispatch and a flags gather for every live round,
-    2.5 ms a round on the v5e, and shorter slices pay that more often
-    than lanes finish).
+    when set, else ~1/8 of the iteration budget (floor 4 — where a
+    round's flags are read in step, a slice boundary leaves the v5e
+    idle about 3 ms: the flags' D2H after the device's end, 0.1 ms of
+    host, and a dispatch of 1.2–1.5 ms whose program starts before it
+    returns; shorter slices pay that more often than lanes finish. A
+    lone round that reads its flags one slice behind pays it under
+    device work).
     """
     env = os.environ.get("SKDIST_SLICE_ITERS", "").strip()
     if env:
@@ -2425,10 +2428,14 @@ class _LiveRound:
     padding), its host task slice (placed once — ``dev_task`` caches
     the device copy across slices, safe because the iterative jit
     entries never donate), and its carry — device-resident between
-    slices, host-resident only across a compaction event."""
+    slices, host-resident only across a compaction event. ``done`` is
+    the flags of ``dev_carry`` once read; ``ahead`` is the carry of a
+    slice enqueued on ``dev_carry`` before those flags were read (the
+    look-ahead of a lone round), which becomes ``dev_carry`` when the
+    next slice is enqueued on it."""
 
     __slots__ = ("idx", "task_sl", "dev_task", "dev_carry", "host_carry",
-                 "done")
+                 "done", "ahead")
 
     def __init__(self, idx, task_sl):
         self.idx = idx
@@ -2437,6 +2444,7 @@ class _LiveRound:
         self.dev_carry = None
         self.host_carry = None
         self.done = None
+        self.ahead = None
 
 
 def _pad_tail(tree, pad):
@@ -2501,7 +2509,7 @@ def _dispatch_iterative(backend, plan, spec, task_args, shared_args,
             out = _run_compacted(
                 plan, spec, task_args, n_tasks, chunk, stats,
                 pipeline=not backend.sync_rounds, on_round=on_round,
-                rung=rung, live_rounds=live_rounds,
+                rung=rung, live_rounds=live_rounds, lanes_fit=lanes_fit,
             )
             stats["retries"] = retry.total
             obs_metrics.publish_round_stats(stats)
@@ -2611,7 +2619,7 @@ def _flags_only_gather(leaf):
 
 def _run_compacted(plan, spec, task_args, n_tasks, chunk, stats,
                    pipeline=True, on_round=None, rung=None,
-                   live_rounds=None):
+                   live_rounds=None, lanes_fit=None):
     """The convergence-compacted slice loop.
 
     Phase 1 (iterate): partition the task axis into chunk-shaped rounds
@@ -2647,6 +2655,18 @@ def _run_compacted(plan, spec, task_args, n_tasks, chunk, stats,
     carries outweigh the device — 50 lanes of 229 MB — the rounds past
     the cap wait their turn unstarted and enter as earlier ones retire,
     so a round runs its slices to its end before the next begins.
+
+    Where ONE round is live, no rung controller is attached, the loop
+    is pipelined and memory has room for a second slice's carry
+    (``lanes_fit`` None, or at least ``2 * chunk``), the host reads the
+    flags one slice behind: the round's next slice is enqueued on its
+    device-resident carry before the flags of the slice in flight are
+    read, so the flags' D2H, the retire test and the next dispatch go
+    under device work; a round found done is read back once the next
+    round's first slice is enqueued. Decisions still follow the carry
+    whose flags were read, so outputs and counts are those of the
+    in-step order; a round found done leaves one spare slice behind
+    it, which runs no iteration. Elsewhere the flags are read in step.
     """
     depth = _MAX_ROUNDS_IN_FLIGHT if pipeline else 1
     put = plan.put
@@ -2693,6 +2713,8 @@ def _run_compacted(plan, spec, task_args, n_tasks, chunk, stats,
             n_tasks, chunk, stats,
             depth if live_rounds is None else min(depth, live_rounds),
             rung, live_rounds,
+            look_ahead=(pipeline and rung is None
+                        and (lanes_fit is None or 2 * chunk <= lanes_fit)),
         )
     # phase 2: finalize everything in ORIGINAL task order through the
     # ordinary round loop (same chunk shape -> same compiled program
@@ -2765,11 +2787,14 @@ def _program_collectives(compiled):
 
 def _compacted_slice_loop(spec, put, init_exec, step_exec, score_exec,
                           task_args, n_tasks, chunk, stats, depth, rung,
-                          live_rounds=None):
+                          live_rounds=None, look_ahead=False):
     """Phase 1 of :func:`_run_compacted` (its ``round_loop`` span): the
     slices, the flags gathers, the rungs and the compactions. Books the
     loop's accounting into ``stats`` and returns the per-task store of
-    the ``finalize_keys`` carry leaves, in task order."""
+    the ``finalize_keys`` carry leaves, in task order. ``look_ahead``
+    lets a lone live round keep one slice enqueued beyond the one whose
+    flags the host reads (``slices_ahead`` counts those dispatches,
+    ``spare_slices`` the ones that found their round already done)."""
     import jax
 
     rounds = []
@@ -2792,7 +2817,7 @@ def _compacted_slice_loop(spec, put, init_exec, step_exec, score_exec,
         # retirement-reason split (satellite observability): totals by
         # cause plus the per-rung kill histogram the smoke asserts
         "retired_rung": 0, "retired_convergence": 0, "rung_history": [],
-        "rung_wait_s": 0.0,
+        "rung_wait_s": 0.0, "slices_ahead": 0, "spare_slices": 0,
     })
     counting = bool(spec.count_keys)
     if counting:
@@ -2833,58 +2858,95 @@ def _compacted_slice_loop(spec, put, init_exec, step_exec, score_exec,
                 fin_store[key] = arr
             arr[idx_arr] = leaf
 
+    # lone rounds found done whose leaves are read back once the next
+    # slice is enqueued: (task ids, kept lanes, the device leaves)
+    retiring = []
+
+    def read_retired():
+        while retiring:
+            idx_arr, keep, leaves = retiring.pop(0)
+            retire(idx_arr, {k: _flags_only_gather(v)[:keep]
+                             for k, v in leaves.items()})
+
+    def live_lanes(r):
+        keep = len(r.idx)
+        return keep - (0 if r.done is None
+                       else int(np.count_nonzero(r.done[:keep])))
+
+    def enqueue(r, ahead=False):
+        """One slice of ``r`` on its newest carry. ``ahead``: on a
+        ``dev_carry`` whose flags are not read yet (the slice goes to
+        ``r.ahead``)."""
+        t_d = time.perf_counter()
+        # a slice enqueued on a carry whose flags are unread books its
+        # live lanes when those flags are read (flags_pop)
+        unread = ahead or r.ahead is not None
+        if counting:
+            stats["lane_slots"] += int(chunk)
+            if not unread:
+                stats["live_lane_slots"] += live_lanes(r)
+        with obs_trace.span("round_dispatch"):
+            if r.dev_task is None:
+                # task args never change between slices: place once
+                # per round and reuse (keep masks at OvR scale are
+                # chunk x n_samples — re-uploading them every slice
+                # would undo the flags-only-D2H economy on the H2D
+                # side)
+                r.dev_task = put(r.task_sl)
+            newest = r.ahead if r.ahead is not None else r.dev_carry
+            if newest is None and r.host_carry is None:
+                dev = init_exec(r.dev_task)
+            else:
+                carry_in = (
+                    newest if newest is not None else put(r.host_carry)
+                )
+                r.host_carry = None
+                dev = step_exec({"task": r.dev_task, "carry": carry_in})
+        if r.ahead is not None:
+            # the look-ahead's flags are the next to read
+            r.dev_carry, r.ahead = r.ahead, dev
+        elif ahead:
+            r.ahead = dev
+        else:
+            r.dev_carry = dev
+        try:
+            leaf = dev[spec.done_key]
+            if getattr(leaf, "is_fully_addressable", True):
+                leaf.copy_to_host_async()
+        except Exception as exc:
+            # best-effort prefetch only; a real failure re-raises
+            # at the blocking flags gather where it is classified
+            faults.log_suppressed("_run_compacted.flags_prefetch",
+                                  exc, level=logging.DEBUG)
+        stats["rounds_per_slice"][-1] += 1
+        stats["slices_ahead"] += int(unread)
+        stats["dispatch_s"] += time.perf_counter() - t_d
+
     n_done_prev = 0
     while rounds:
         stats["slices"] += 1
-        stats["rounds_per_slice"].append(len(rounds))
+        stats["rounds_per_slice"].append(0)
         pending = []
+        # a lone round has no other round's program to hide its host
+        # turn under: it hides it under its own next slice
+        lone = look_ahead and len(rounds) == 1
 
         def flags_pop():
             r = pending.pop(0)
+            # a slice is enqueued by now: the read-back goes under it
+            read_retired()
             t_g = time.perf_counter()
             with obs_trace.span("flags_wait"):
                 r.done = _flags_only_gather(r.dev_carry[spec.done_key])
             stats["flags_wait_s"] += time.perf_counter() - t_g
+            if counting and r.ahead is not None:
+                stats["live_lane_slots"] += live_lanes(r)
 
         for r in rounds:
-            t_d = time.perf_counter()
-            if counting:
-                keep = len(r.idx)
-                stats["lane_slots"] += int(chunk)
-                stats["live_lane_slots"] += keep - (
-                    0 if r.done is None
-                    else int(np.count_nonzero(r.done[:keep]))
-                )
-            with obs_trace.span("round_dispatch"):
-                if r.dev_task is None:
-                    # task args never change between slices: place once
-                    # per round and reuse (keep masks at OvR scale are
-                    # chunk x n_samples — re-uploading them every slice
-                    # would undo the flags-only-D2H economy on the H2D
-                    # side)
-                    r.dev_task = put(r.task_sl)
-                if r.dev_carry is None and r.host_carry is None:
-                    dev = init_exec(r.dev_task)
-                else:
-                    carry_in = (
-                        r.dev_carry if r.dev_carry is not None
-                        else put(r.host_carry)
-                    )
-                    r.host_carry = None
-                    dev = step_exec({"task": r.dev_task,
-                                     "carry": carry_in})
-            r.dev_carry = dev
-            try:
-                leaf = dev[spec.done_key]
-                if getattr(leaf, "is_fully_addressable", True):
-                    leaf.copy_to_host_async()
-            except Exception as exc:
-                # best-effort prefetch only; a real failure re-raises
-                # at the blocking flags gather where it is classified
-                faults.log_suppressed("_run_compacted.flags_prefetch",
-                                      exc, level=logging.DEBUG)
+            enqueue(r)
+            if lone and r.ahead is None:
+                enqueue(r, ahead=True)
             pending.append(r)
-            stats["dispatch_s"] += time.perf_counter() - t_d
             while len(pending) >= depth:
                 flags_pop()
         while pending:
@@ -2945,11 +3007,23 @@ def _compacted_slice_loop(spec, put, init_exec, step_exec, score_exec,
             done_lanes = r.done[:keep].astype(bool)
             n_alive += int((~done_lanes).sum())
             if done_lanes.all():
-                retire(r.idx, {
-                    k: _flags_only_gather(r.dev_carry[k])[:keep]
-                    for k in retire_keys
-                })
+                if lone:
+                    # the leaves cross while the next round's first
+                    # slice runs, not before it is enqueued
+                    leaves = {k: r.dev_carry[k] for k in retire_keys}
+                    _start_host_copy(leaves)
+                    retiring.append((r.idx, keep, leaves))
+                else:
+                    retire(r.idx, {
+                        k: _flags_only_gather(r.dev_carry[k])[:keep]
+                        for k in retire_keys
+                    })
                 r.dev_carry = None
+                if r.ahead is not None:
+                    # enqueued on a carry now known done: it ran no
+                    # iteration, and nothing reads it
+                    stats["spare_slices"] += 1
+                    r.ahead = None
             else:
                 still.append(r)
         # newly-finished lanes this slice (lanes already compacted out
@@ -3011,9 +3085,11 @@ def _compacted_slice_loop(spec, put, init_exec, step_exec, score_exec,
         while waiting and len(rounds) < live_rounds:
             rounds.append(waiting.pop(0))
 
+    read_retired()
     # the converged schema's "rounds": the slice loop's actual device
-    # dispatches (one per live round per slice; the finalize phase's
-    # rounds are tallied separately under stats["finalize"])
+    # dispatches (one per live round per slice, spare slices included;
+    # the finalize phase's rounds are tallied separately under
+    # stats["finalize"])
     stats["rounds"] = int(sum(stats["rounds_per_slice"]))
     # retirement-reason accounting: every lane either converged (or hit
     # its iteration cap) or was killed by a rung — the quality/

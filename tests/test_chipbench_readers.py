@@ -88,6 +88,49 @@ def test_round_counts_nothing_to_read(stats):
         ctx, num="live_lane_slots", den="lane_slots") is None
 
 
+#: the look-ahead's share of the slice loop's dispatches (PR 38)
+AHEAD_METRICS = ("slices_ahead_pct.search",)
+
+
+def test_slices_ahead_metric_resolves_to_the_reader_and_an_entry():
+    name, = AHEAD_METRICS
+    with open(os.path.join(REPO, "chipbench", "metrics",
+                           name + ".json")) as f:
+        spec = json.load(f)
+    # the denominator is the slice loop's dispatches, which the
+    # finalize part does not book (its ``rounds`` would be summed in)
+    assert spec == {"reader": "round_counts",
+                    "args": {"num": "slices_ahead",
+                             "den": "rounds_per_slice"}}
+    entry = {m["name"]: m for m in _bench()["per_layer"]}[name]
+    # no ``workloads``: every cell reports it, 0 where the loop bypasses
+    assert entry == {"name": name, "unit": "%", "better": "higher",
+                     "source": "program_counter", "layer": "round dispatch",
+                     "moves": "search_fits_per_s"}
+
+
+@pytest.mark.parametrize("fits, want", [
+    # a lone round's 8 dispatches, 7 enqueued ahead; the finalize part's
+    # rounds are not the denominator's
+    ([dict(COUNTED, slices_ahead=7, rounds_per_slice=[2, 1, 1, 1, 1, 1, 1])],
+     100 * 7 / 8),
+    # summed over the fits: 13 of 16 dispatches
+    ([dict(COUNTED, slices_ahead=7, rounds_per_slice=[2, 1, 1, 1, 1, 1, 1]),
+      dict(COUNTED, slices_ahead=6, rounds_per_slice=[2, 2, 1, 1, 1, 1])],
+     100 * 13 / 16),
+    # the bypass: the flags read in step
+    ([dict(COUNTED, slices_ahead=0, rounds_per_slice=[1, 1, 1, 1])], 0.0),
+    # a program without the counter: the metric is left out
+    ([dict(COUNTED, rounds_per_slice=[1, 1, 1, 1])], None),
+    ([{"rounds": 3, "dispatch_s": 0.5}], None),
+    ([None], None),
+])
+def test_slices_ahead_pct(fits, want):
+    ctx = _ctx([_fit(stats) for stats in fits])
+    assert round_counts.read(ctx, num="slices_ahead",
+                             den="rounds_per_slice") == want
+
+
 @pytest.mark.parametrize("stats, want", [
     (dict(COUNTED, rounds=2), 8.0),            # finalize's round left out
     ({"lane_slots": 378, "rounds": 54}, 7.0),  # the parent: rounds of 7
@@ -582,7 +625,7 @@ def test_every_metric_file_has_its_entry_and_reader():
     # the new entries were appended: the accepted ones keep their places
     appended = (NEW_METRICS + TEXT_METRICS + MNIST_METRICS
                 + FOUR_CHIP_METRICS + tuple(SETUP_METRICS)
-                + tuple(COUNTER_METRICS))
+                + tuple(COUNTER_METRICS) + AHEAD_METRICS)
     assert [m["name"] for m in bench["per_layer"]][
         -len(appended):] == list(appended)
 

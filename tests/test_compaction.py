@@ -412,6 +412,177 @@ def test_iterative_no_recompile_after_warmup(tpu_backend):
 
 
 # ---------------------------------------------------------------------------
+# the lone round's look-ahead: its flags read one slice behind
+# ---------------------------------------------------------------------------
+
+_AHEAD_SPECS = {}
+
+
+def _ahead_spec(scored):
+    """The toy's L-BFGS slice kernels with their work counters and, for
+    a rung, a score: ``(spec, shared, tasks)``, built once a kind so
+    that the jit entries memoised under its cache key are its own."""
+    if scored not in _AHEAD_SPECS:
+        spec, _fallback, shared, tasks = _toy_spec_and_tasks(n_tasks=24)
+        score = (lambda sh, t, c: -jnp.sum(c["w"] ** 2)) if scored else None
+        _AHEAD_SPECS[scored] = (IterativeKernelSpec(
+            spec.init, spec.step, spec.finalize, spec.finalize_keys,
+            count_keys=("it", "nfev"), score=score), shared, tasks)
+    return _AHEAD_SPECS[scored]
+
+
+def _logged_run(spec, shared, tasks, chunk, monkeypatch, **kw):
+    """``_run_compacted`` over executors that log what they are asked
+    to run — ``init``, ``step`` (with the carry it takes and the one it
+    gives), ``score`` — beside every read of a round's done flags.
+    Returns ``(outputs, stats, log)``."""
+    from skdist_tpu.parallel import backend as backend_mod
+
+    log = []
+    plan = LocalBackend().prepare_batched_iterative(
+        spec, shared, cache_key=("tc-ahead", spec.score is not None))
+    real_gather = backend_mod._flags_only_gather
+
+    def gather(leaf):
+        out = real_gather(leaf)
+        if out.dtype == bool:
+            log.append(("flags",))
+        return out
+
+    def logged(name, fn):
+        def run(sh, sl):
+            out = fn(sh, sl)
+            log.append((name, sl["carry"], out) if name == "step"
+                       else (name,))
+            return out
+        return run
+
+    plan.init_fn = logged("init", plan.init_fn)
+    plan.step_fn = logged("step", plan.step_fn)
+    plan.fin_fn = logged("fin", plan.fin_fn)
+    if plan.score_fn is not None:
+        plan.score_fn = logged("score", plan.score_fn)
+    monkeypatch.setattr(backend_mod, "_flags_only_gather", gather)
+    stats = {}
+    out = backend_mod._run_compacted(
+        plan, spec, tasks, len(tasks["C"]), chunk, stats, **kw)
+    monkeypatch.setattr(backend_mod, "_flags_only_gather", real_gather)
+    return out, stats, log
+
+
+def _all_done(carry):
+    return bool(np.asarray(carry["done"]).all())
+
+
+@pytest.mark.parametrize("n_tasks, live_rounds, lanes_fit, scored, spares", [
+    # one round of 8, a device that reports no memory: ahead
+    (8, None, None, False, 1),
+    # three rounds run one at a time, room for two rounds' lanes: ahead
+    (24, 1, 16, False, 3),
+    # ... room for less than two: in step
+    (24, 1, 15, False, 0),
+    # two live rounds (lanes that finish together: they never compact
+    # to one): in step
+    (16, None, None, False, 0),
+    # a rung controller: in step, its kills before the next slice
+    (8, None, None, True, 0),
+], ids=["lone_round", "memory_room", "memory_tight", "two_live_rounds",
+        "rung"])
+def test_look_ahead_engages_on_what_the_loop_observes(
+        n_tasks, live_rounds, lanes_fit, scored, spares, monkeypatch):
+    """The slice loop enqueues a lone round's next slice before it reads
+    the flags of the slice in flight only where no rung is attached, one
+    round is live and ``lanes_fit`` holds two rounds; either way the
+    outputs, the counts and every decision are those of the in-step
+    order (``pipeline=False``), and a spare slice over a round already
+    done gives back the carry it took."""
+    from skdist_tpu.parallel import RungController
+
+    spec, shared, tasks = _ahead_spec(scored)
+    tasks = {k: v[:n_tasks] for k, v in tasks.items()}
+    if n_tasks == 16:
+        # every lane of the second round the first round's twin
+        tasks["C"] = np.full(n_tasks, 0.1, np.float32)
+    runs = {}
+    for side, pipeline in (("ahead", True), ("in_step", False)):
+        rung = RungController(eta=2.0) if scored else None
+        runs[side] = _logged_run(
+            spec, shared, tasks, 8, monkeypatch, pipeline=pipeline,
+            rung=rung, live_rounds=live_rounds, lanes_fit=lanes_fit) + (rung,)
+    out, stats, log, rung = runs["ahead"]
+    ref, ref_stats, _, ref_rung = runs["in_step"]
+
+    # the same answers, bit for bit, and the same decisions
+    for key in ref:
+        np.testing.assert_array_equal(np.asarray(out[key]),
+                                      np.asarray(ref[key]))
+    for key in ("iters", "fevals", "slices", "retired_per_slice",
+                "compactions", "live_lane_slots"):
+        assert stats[key] == ref_stats[key], key
+    if scored:
+        assert rung.killed and rung.killed == ref_rung.killed
+        assert rung.history == ref_rung.history
+
+    # the counters: spare slices are booked as dispatches of no live lane
+    assert stats["spare_slices"] == spares
+    assert stats["rounds"] == ref_stats["rounds"] + spares
+    assert stats["lane_slots"] == ref_stats["lane_slots"] + 8 * spares
+    assert ref_stats["slices_ahead"] == ref_stats["spare_slices"] == 0
+    kinds = [e[0] for e in log if e[0] != "fin"]
+    dispatches = [k for k in kinds if k in ("init", "step")]
+    if spares:
+        # every dispatch but each round's first was enqueued ahead
+        n_rounds = n_tasks // 8
+        assert stats["slices_ahead"] == len(dispatches) - n_rounds
+        assert kinds[:3] == ["init", "step", "flags"]
+    else:
+        assert stats["slices_ahead"] == 0
+        assert stats["rounds_per_slice"] == ref_stats["rounds_per_slice"]
+        # no slice is enqueued on a carry whose flags are unread: one
+        # dispatch a live round before the first read ...
+        first = kinds.index("flags")
+        live = n_tasks // 8 if live_rounds is None else live_rounds
+        assert kinds[:first] == ["init"] * live
+        if live == 1:
+            # ... and a lone round's dispatches and reads alternate
+            assert not any(a in ("init", "step") and b in ("init", "step")
+                           for a, b in zip(kinds, kinds[1:]))
+    # the spare slices ran over a round already done and gave back the
+    # carry they took, leaf for leaf
+    spare = [(c_in, c_out) for k, *rest in log if k == "step"
+             for c_in, c_out in [rest] if _all_done(c_in)]
+    assert len(spare) == spares
+    for c_in, c_out in spare:
+        for key in c_in:
+            np.testing.assert_array_equal(np.asarray(c_in[key]),
+                                          np.asarray(c_out[key]))
+
+
+def test_search_look_ahead_matches_in_step(clf_data):
+    """A search whose lone round reads its flags one slice behind
+    answers what the same search answers with every round in step."""
+    X, y = clf_data
+    one_device = jax.devices()[:1]
+    ahead_bk = TPUBackend(devices=one_device)
+    in_step_bk = TPUBackend(devices=one_device, sync_rounds=True)
+    ahead = _skewed_grid_search(ahead_bk, X, y, partitions=1)
+    in_step = _skewed_grid_search(in_step_bk, X, y, partitions=1)
+    stats, ref_stats = ahead_bk.last_round_stats, in_step_bk.last_round_stats
+    assert stats["mode"] == ref_stats["mode"] == "compacted"
+    assert stats["slices_ahead"] > 0 and stats["spare_slices"] == 1
+    assert ref_stats["slices_ahead"] == ref_stats["spare_slices"] == 0
+    assert (stats["iters"], stats["fevals"]) == (
+        ref_stats["iters"], ref_stats["fevals"])
+    for key, value in in_step.cv_results_.items():
+        if key.endswith("_time"):
+            continue
+        if isinstance(value, np.ndarray) and value.dtype.kind == "f":
+            np.testing.assert_array_equal(ahead.cv_results_[key], value)
+        else:
+            assert list(ahead.cv_results_[key]) == list(value), key
+
+
+# ---------------------------------------------------------------------------
 # scheduler integration: search path
 # ---------------------------------------------------------------------------
 
@@ -490,7 +661,12 @@ def test_search_one_round_matches_eight():
     assert stats["mode"] == "compacted"
     assert (stats["chunk"], stats["chunk_basis"]) == (24, "all_tasks")
     assert stats["lanes_fit"] is None  # the CPU reports no memory
-    assert stats["rounds"] == stats["slices"]
+    # the lone round reads its flags one slice behind: every dispatch
+    # but the first is enqueued ahead, and the last ran over a round
+    # already done
+    assert stats["rounds"] == stats["slices"] + 1
+    assert stats["slices_ahead"] == stats["rounds"] - 1
+    assert stats["spare_slices"] == 1
     assert stats["compactions"] == 0
     assert stats["lane_slots"] == stats["chunk"] * stats["rounds"]
     assert 0 < stats["live_lane_slots"] < stats["lane_slots"]
