@@ -1876,7 +1876,10 @@ def put_host_array(x, sharding=None):
     device and waited for, so that beside the whole only one block is
     ever held. (A
     row-sharded array goes the same way shard by shard:
-    :func:`_put_row_shards`.)"""
+    :func:`_put_row_shards`.) A device array is never cut: it goes as
+    ``jax.device_put`` moves it — where it already lies, as the same
+    buffer; onto other devices, device to device (a bucketed X's
+    head, which its pack builds on the device)."""
     import jax
 
     def put(a):

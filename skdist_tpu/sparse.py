@@ -38,7 +38,8 @@ batch prediction alike:
   and no scatter runs), the densest columns held as a dense head. The
   buckets' cost follows nnz; the head's is ``n x h`` dense floats,
   zeros and all (at 11,314 x 130,107 with 1.79 M stored elements:
-  15,229 columns, 689 MB, for the 78 % of the elements they hold). A
+  15,229 columns, 689 MB, for the 78 % of the elements they hold),
+  built on the device from its stored elements, never on the host. A
   batch of weight matrices (``vmap``) rides on the gathers' contiguous
   axis, not on an axis of its own.
 - :class:`LinearOperator` — ``[X | 1]`` behind the five contractions
@@ -220,9 +221,12 @@ class BucketedX:
     buckets: ``head (n, h) f32`` holds them dense, ``head_cols (h,)``
     says which they are, and both products add a matmul over them to
     the gathers over the rest (``head`` is None where no column is
-    that dense). The head is placed whole, zeros and all — ``n x h x
+    that dense). The head is held whole, zeros and all — ``n x h x
     4`` bytes, capped by :data:`HEAD_MAX_BYTES` — so it, not nnz, is
-    most of what such a matrix weighs on the device. The density at
+    most of what such a matrix weighs on the device; it is a device
+    array from the pack on (:func:`pack_csr_buckets` scatters its
+    stored elements there), the other leaves host arrays until they
+    are placed. The density at
     which a column joins it and that cap are one v5e's crossing, read
     at one shape (PERF.md, PR 28).
 
@@ -428,21 +432,69 @@ def head_columns(X):
     return np.sort(dense).astype(np.int32)
 
 
+def head_sent_slots(head_nnz):
+    """Elements :func:`pack_csr_buckets` sends to the device to build a
+    head of ``head_nnz`` stored elements: the next power of two (1024 at
+    least), padding that the build drops, so that a matrix of another
+    nnz but the same head shape rarely compiles another build."""
+    return max(1024, 1 << (int(head_nnz) - 1).bit_length())
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def _build_head(flat, val, n, h):
+    """The dense ``(n, h)`` head from its stored elements: ``flat``
+    (``row * h + column``, ascending and distinct, the padding past
+    ``n * h``) and their float32 values, scattered onto zeros."""
+    return jnp.zeros((n, h), jnp.float32).at[flat // h, flat % h].add(
+        val, mode="drop", indices_are_sorted=True, unique_indices=True)
+
+
+def _dense_head(X, head_cols, local):
+    """``X[:, head_cols].toarray()`` as float32, built on JAX's default
+    device from the head's stored elements: no ``(n, h)`` array is made
+    on the host, and what crosses is ``8 * head_sent_slots(head_nnz)``
+    bytes, not ``4 * n * h``. ``local`` is each stored element's column
+    in the head, -1 outside it. A canonical CSR's head elements, in
+    CSR order, are already ascending and distinct (``head_cols`` is
+    sorted); any other has its duplicates summed here first, in its own
+    dtype and CSR order, as ``toarray`` sums them — so the head is
+    ``toarray``'s to the bit either way."""
+    n, h = X.shape[0], head_cols.size
+    indptr = np.asarray(X.indptr)
+    in_head = local >= 0
+    rows = np.repeat(np.arange(n, dtype=np.int32), np.diff(indptr))
+    flat = rows[in_head] * np.int32(h) + local[in_head]
+    val = np.asarray(X.data)[in_head]
+    slots = head_sent_slots(flat.size)
+    if not X.has_canonical_format:
+        flat, at = np.unique(flat, return_inverse=True)
+        summed = np.zeros(flat.size, val.dtype)
+        np.add.at(summed, at, val)
+        val = summed
+    assert n * h + slots < 2 ** 31, "the head's flat index is int32"
+    pad = slots - flat.size
+    flat = np.concatenate([flat, n * h + np.arange(pad, dtype=np.int32)])
+    val = np.concatenate([val.astype(np.float32), np.zeros(pad, np.float32)])
+    return _build_head(flat, val, n, h)
+
+
 def pack_csr_buckets(X):
     """CSR → :class:`BucketedX`: the densest columns dense, the rest in
     both orientations bucketed by length (``indptr`` and the column
-    counts alone decide)."""
+    counts alone decide). The head is built on JAX's default device
+    (:func:`_dense_head`); the buckets are host arrays, placed later."""
     import scipy.sparse as sp
 
     X = X.tocsr()
     head_cols = head_columns(X)
     head, head_nnz, tail = None, 0, X
     if head_cols.size:
-        in_head = np.zeros(X.shape[1], bool)
-        in_head[head_cols] = True
-        keep = ~in_head[np.asarray(X.indices)]
+        lookup = np.full(X.shape[1], -1, np.int32)
+        lookup[head_cols] = np.arange(head_cols.size, dtype=np.int32)
+        local = lookup[np.asarray(X.indices)]
+        keep = local < 0
         head_nnz = int(X.nnz - keep.sum())
-        head = np.asarray(X[:, head_cols].toarray(), np.float32)
+        head = _dense_head(X, head_cols, local)
         kept = np.concatenate([[0], np.cumsum(keep)])[np.asarray(X.indptr)]
         tail = sp.csr_matrix(
             (np.asarray(X.data)[keep], np.asarray(X.indices)[keep], kept),
@@ -530,7 +582,8 @@ def pack_for_fit(X):
         return None
     from .obs import trace as obs_trace
 
-    # host seconds to bucket and pack; the counts are filled in at the
+    # host seconds to bucket and pack (the head's build on the device
+    # is enqueued, not waited for); the counts are filled in at the
     # span's end
     args = {} if obs_trace.enabled() else None
     with obs_trace.span("pack_x", args):
@@ -538,13 +591,18 @@ def pack_for_fit(X):
         if pack_decision(X)[1] == "bucketed":
             packed = pack_csr_buckets(X)
             slots, buckets = packed.slots, len(packed.rows + packed.cols)
-            if args is not None and packed.head is not None:
-                args.update(head_cols=int(packed.head.shape[1]))
         else:
             packed = PackedX(*pack_csr_rows(X), X.shape[1])
             slots, buckets = int(np.prod(packed.idx.shape)), 1
         if args is not None:
-            args.update(nnz=int(X.nnz), slots=slots, buckets=buckets)
+            head = getattr(packed, "head", None)
+            args.update(nnz=int(X.nnz), slots=slots, buckets=buckets,
+                        head_on_device=isinstance(head, jax.Array),
+                        head_sent_bytes=0)
+            if head is not None:
+                args.update(head_cols=int(head.shape[1]),
+                            head_sent_bytes=8 * head_sent_slots(
+                                packed.head_nnz))
     return packed
 
 

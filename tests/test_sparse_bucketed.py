@@ -2,7 +2,9 @@
 The bucketed packed representation (``sparse.BucketedX``): a CSR whose
 row lengths are heavy-tailed packs at a cost that follows nnz, its
 products equal the dense ones whatever its structure (no head, a head
-alone, one bucket, empty rows), a matrix of even rows still packs to
+alone, one bucket, empty rows), its dense head is built on the device
+from the stored elements, ``toarray``'s to the bit and with no dense
+copy on the host, a matrix of even rows still packs to
 the one padded pair it always did, and a grid search
 over a skewed 20-class CSR runs packed end to end and agrees with the
 benchmark's plain reference for sparse inputs.
@@ -138,6 +140,219 @@ def test_bucketed_products_equal_dense(structure):
     v_ref, gr_ref = batched(lanes, jnp.asarray(Xd))
     np.testing.assert_allclose(v, v_ref, rtol=2e-5)
     np.testing.assert_allclose(gr, gr_ref, atol=5e-5)
+
+
+def _head_case(case):
+    """A CSR whose head :func:`sx.pack_csr_buckets` builds on the
+    device from its stored elements, as scipy may hand it over."""
+    X = skewed_csr(seed=9, n=200, d=1500, heavy=(600, 400))
+    rng = np.random.RandomState(4)
+    if case == "canonical":
+        assert X.has_canonical_format
+        return X
+    if case == "no_head":
+        return _structured("no_head")[0]
+    coo = X.tocoo()
+    rows, cols, vals = coo.row, coo.col, coo.data
+    if case == "explicit_zeros":
+        # stored zeros of both signs, in the head's columns and out
+        vals = vals.copy()
+        vals[rng.rand(vals.size) < 0.2] = 0.0
+        vals[rng.rand(vals.size) < 0.05] = -0.0
+        X = sp.csr_matrix((vals, (rows, cols)), shape=X.shape)
+        assert (X.data == 0).sum() > 100
+        return X
+    if case == "empty_rows":
+        keep = ~np.isin(rows, [5, 6, 7, 150, 199])
+        X = sp.csr_matrix((vals[keep], (rows[keep], cols[keep])),
+                          shape=X.shape)
+        assert (np.diff(X.indptr) == 0).sum() == 5
+        return X
+    assert case.startswith("duplicates")
+    # a third of the elements stored again and again, every row's
+    # elements in no order: scipy keeps each copy until summed
+    again = rng.rand(vals.size) < 0.3
+    rows = np.r_[rows, rows[again], rows[again]]
+    cols = np.r_[cols, cols[again], cols[again]]
+    vals = np.r_[vals, rng.rand(2 * again.sum())].astype(
+        np.float64 if case.endswith("float64") else np.float32)
+    if case.endswith("float64"):
+        # values float32 cannot hold, whose sums round once in float64
+        vals = vals * (1 + 1e-9) + 1e-12
+    order = np.lexsort((rng.rand(rows.size), rows))
+    indptr = np.r_[0, np.cumsum(np.bincount(rows, minlength=X.shape[0]))]
+    X = sp.csr_matrix((vals[order], cols[order], indptr), shape=X.shape)
+    assert not X.has_canonical_format and X.nnz > 1.5 * coo.nnz
+    return X
+
+
+@pytest.mark.parametrize("case", [
+    "canonical", "duplicates", "duplicates_float64", "explicit_zeros",
+    "empty_rows", "no_head"])
+def test_head_built_on_the_device_is_toarray_to_the_bit(case):
+    """The dense head that ``pack_csr_buckets`` scatters on the device
+    from the CSR's stored elements is ``X[:, head_cols].toarray()`` in
+    float32 bit for bit — duplicates summed as scipy sums them, stored
+    zeros of either sign, rows with no element — and the products over
+    the bucketed matrix stay the dense ones."""
+    X = _head_case(case)
+    n, d = X.shape
+    B = sx.pack_csr_buckets(X)
+    if case == "no_head":
+        assert B.head is None and B.head_cols is None
+    else:
+        assert isinstance(B.head, jax.Array)
+        assert B.head.dtype == jnp.float32
+        want = np.asarray(X[:, B.head_cols].toarray(), np.float32)
+        got = np.asarray(B.head)
+        assert got.shape == want.shape == (n, B.head_cols.size)
+        assert np.array_equal(got.view(np.int32), want.view(np.int32))
+        assert B.head_nnz == np.isin(X.indices, B.head_cols).sum()
+    B = jax.tree_util.tree_map(jnp.asarray, B)
+    Xd = np.asarray(X.toarray(), np.float32)
+    # the buckets hold a duplicate's copies apart, each in float32, and
+    # their sum is the device's: within a rounding of scipy's
+    np.testing.assert_allclose(jax.jit(sx.bucketed_to_dense)(B), Xd,
+                               rtol=1e-6)
+    rng = np.random.RandomState(1)
+    W = rng.randn(d + 1, 4).astype(np.float32)
+    r = rng.randn(n, 4).astype(np.float32)
+    Xa = np.hstack([Xd, np.ones((n, 1), np.float32)])
+    op = sx.LinearOperator(B, True)
+    np.testing.assert_allclose(op.matvec(W), Xa @ W, atol=5e-5)
+    np.testing.assert_allclose(op.rmatvec(r), Xa.T @ r, atol=5e-5)
+
+
+def test_pack_builds_no_dense_head_on_the_host(monkeypatch):
+    """At the text cell's proportions (a generated corpus of 2,000
+    documents: 70 % of its elements in a head of an eighth of the
+    columns), ``pack_for_fit`` never densifies on the host — scipy's
+    ``toarray`` / ``todense`` are not called, and the pack's host peak
+    is a fraction of the head's ``n * h * 4`` bytes — and its
+    ``pack_x`` span says the head was built on the device from under a
+    twentieth of those bytes."""
+    import tracemalloc
+
+    from chipbench import datagen_text
+    from skdist_tpu.obs import trace as obs_trace
+
+    X, _ = datagen_text.bag_of_words(3, 2000, 30000, 20, 120000,
+                                     len_sigma=1.6, len_cap=3000)
+    assert sx.pack_decision(X)[1] == "bucketed"
+    h = sx.head_columns(X).size
+    dense_bytes = X.shape[0] * h * 4
+
+    def densified(*a, **k):
+        raise AssertionError("the head was densified on the host")
+
+    for cls in (sp.csr_matrix, sp.csc_matrix, sp.coo_matrix):
+        monkeypatch.setattr(cls, "toarray", densified)
+        monkeypatch.setattr(cls, "todense", densified)
+    sx.pack_for_fit(X)  # the build's program compiles outside the count
+    obs_trace.clear()
+    obs_trace.set_enabled(True)
+    tracemalloc.start()
+    try:
+        B = sx.pack_for_fit(X)
+        _, peak = tracemalloc.get_traced_memory()
+        spans = [e for e in obs_trace.events()
+                 if e[1] == "X" and e[0] == "pack_x"]
+    finally:
+        tracemalloc.stop()
+        obs_trace.set_enabled(False)
+        obs_trace.clear()
+    assert isinstance(B, sx.BucketedX) and B.head.shape == (X.shape[0], h)
+    assert B.head_nnz > 0.6 * X.nnz
+    assert peak < dense_bytes / 4, (peak, dense_bytes)
+    (span,) = spans
+    args = span[5]
+    assert args["head_on_device"] is True and args["head_cols"] == h
+    assert args["head_sent_bytes"] == 8 * sx.head_sent_slots(B.head_nnz)
+    assert 8 * B.head_nnz <= args["head_sent_bytes"] < dense_bytes / 20
+
+
+def test_pack_span_of_a_matrix_with_no_head():
+    """A padded pair and a bucketed matrix without a head send nothing
+    for one: ``head_on_device`` False, ``head_sent_bytes`` 0."""
+    from skdist_tpu.obs import trace as obs_trace
+
+    even = sp.random(300, 4096, density=0.01, format="csr",
+                     dtype=np.float32, random_state=np.random.RandomState(3))
+    headless = _head_case("no_head")
+    assert sx.pack_decision(headless)[1] == "bucketed"
+    obs_trace.clear()
+    obs_trace.set_enabled(True)
+    try:
+        packed = [sx.pack_for_fit(X) for X in (even, headless)]
+        spans = [e[5] for e in obs_trace.events()
+                 if e[1] == "X" and e[0] == "pack_x"]
+    finally:
+        obs_trace.set_enabled(False)
+        obs_trace.clear()
+    assert type(packed[0]) is sx.PackedX and packed[1].head is None
+    assert [(a["head_on_device"], a["head_sent_bytes"]) for a in spans] == [
+        (False, 0), (False, 0)]
+
+
+def test_search_over_a_device_head_matches_the_dense_search():
+    """``DistGridSearchCV`` over a skewed CSR with a head — built on
+    the device, placed without a copy on a one-device mesh — scores
+    as the search over the dense matrix does, and a second search of
+    the same shapes compiles nothing: the head's build is one program
+    a shape, as every round's is."""
+    from test_sparse_fit import _skewed_problem
+
+    from skdist_tpu.distribute.search import DistGridSearchCV
+    from skdist_tpu.models import LogisticRegression
+    from skdist_tpu.parallel import TPUBackend, compile_cache
+    from skdist_tpu.parallel import backend as backend_mod
+
+    X, y = _skewed_problem(seed=17, n=240, d=3000, k=3)
+    head_shape = (240, sx.head_columns(X).size)
+    assert head_shape[1]
+    grid = {"C": [0.05, 0.5, 5.0]}
+
+    def search(M):
+        return DistGridSearchCV(
+            LogisticRegression(max_iter=60, engine="xla"), grid,
+            backend=TPUBackend(devices=jax.devices()[:1]), cv=3,
+            scoring="accuracy", error_score="raise").fit(M, y)
+
+    heads, placed = [], []
+    real_head, real_scoped = sx._dense_head, backend_mod._put_mesh_scoped
+
+    def dense_head(*a):
+        out = real_head(*a)
+        heads.append(out.unsafe_buffer_pointer())
+        return out
+
+    def put_mesh_scoped(x, sharding):
+        out = real_scoped(x, sharding)
+        if isinstance(x, jax.Array) and x.shape == head_shape:
+            placed.append(out.addressable_shards[0].data
+                          .unsafe_buffer_pointer())
+        return out
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(sx, "_dense_head", dense_head)
+        mp.setattr(backend_mod, "_put_mesh_scoped", put_mesh_scoped)
+        packed = search(X)
+    # the head went to the mesh as it lay, and every later placement
+    # (the dispatch's of the placed X) left it there: the same buffer
+    assert len(heads) == 1 and placed and set(placed) == set(heads)
+    dense = search(np.asarray(X.toarray(), np.float32))
+    for key in ("mean_test_score", "split0_test_score", "split2_test_score"):
+        np.testing.assert_allclose(
+            np.asarray(packed.cv_results_[key]),
+            np.asarray(dense.cv_results_[key]), atol=1e-5)
+    before = compile_cache.snapshot()["backend_compiles"]
+    again = search(X)
+    assert compile_cache.snapshot()["backend_compiles"] == before
+    np.testing.assert_array_equal(
+        np.asarray(again.cv_results_["mean_test_score"]),
+        np.asarray(packed.cv_results_["mean_test_score"]))
+    np.testing.assert_array_equal(again.best_estimator_.coef_,
+                                  packed.best_estimator_.coef_)
 
 
 def _representation(name, d=600):
@@ -529,7 +744,9 @@ def test_search_refit_runs_over_the_placed_buckets(monkeypatch, n_devices):
     bucketed X is placed by the search, the dispatch and the refit's
     kernel are handed those buffers (the refit the first replica of
     each), the placement under ``refit`` is labels and weights, and
-    the model is the standalone fit's to the bit."""
+    the model is the standalone fit's to the bit. The head does not
+    cross from the host at all: the pack builds it on the device, and
+    its first replica is the buffer the pack built."""
     from skdist_tpu.distribute.search import DistGridSearchCV
     from skdist_tpu.models import LogisticRegression, linear
     from skdist_tpu.obs import trace as obs_trace
@@ -537,9 +754,14 @@ def test_search_refit_runs_over_the_placed_buckets(monkeypatch, n_devices):
 
     X = skewed_csr(seed=4)
     y = np.arange(X.shape[0]) % 3
-    crossed, kernel_X = [], []
-    real_scoped, real_kernel = (backend_mod._put_mesh_scoped,
-                                linear.get_kernel)
+    crossed, kernel_X, heads = [], [], []
+    real_scoped, real_kernel, real_head = (
+        backend_mod._put_mesh_scoped, linear.get_kernel, sx._dense_head)
+
+    def dense_head(*a):
+        out = real_head(*a)
+        heads.append(out.unsafe_buffer_pointer())
+        return out
 
     def put_mesh_scoped(x, sharding):
         out = real_scoped(x, sharding)
@@ -561,6 +783,7 @@ def test_search_refit_runs_over_the_placed_buckets(monkeypatch, n_devices):
 
     monkeypatch.setattr(backend_mod, "_put_mesh_scoped", put_mesh_scoped)
     monkeypatch.setattr(linear, "get_kernel", get_kernel)
+    monkeypatch.setattr(sx, "_dense_head", dense_head)
     obs_trace.clear()
     obs_trace.set_enabled(True)
     try:
@@ -577,9 +800,12 @@ def test_search_refit_runs_over_the_placed_buckets(monkeypatch, n_devices):
     (over,) = kernel_X
     assert isinstance(over, sx.BucketedX)
     # every leaf the refit's kernel ran over is the first replica of
-    # a host array that crossed (once: the spans below)
+    # a host array that crossed (once: the spans below), but the head,
+    # which is the one the pack built
     leaves = [p[0] for p in pointers(over)]
-    assert set(leaves) <= set(crossed) and len(leaves) == len(set(leaves))
+    assert heads == [pointers(over.head)[0][0]]
+    assert set(leaves) - set(heads) <= set(crossed)
+    assert len(leaves) == len(set(leaves))
     assert all(len(leaf.devices()) == 1
                for leaf in jax.tree_util.tree_leaves(over))
     packed_bytes = sum(
